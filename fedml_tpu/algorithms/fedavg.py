@@ -146,9 +146,10 @@ class FedAvgAPI:
         validate_accum_steps(cfg, dataset.train_data_local_num_dict)
         self._local_train = make_local_train(module, task, cfg)
         self._vmapped_body = make_vmapped_body(self._local_train)
+        from fedml_tpu.utils import on_tpu
         if aggregate_hook is not None:
             hook = aggregate_hook
-        elif jax.default_backend() == "tpu":
+        elif on_tpu():
             # fused single-pass kernel over the whole [clients, params] stack
             # instead of one reduction per leaf (fedml_tpu/ops/aggregate.py)
             from fedml_tpu.ops import tree_weighted_mean_pallas
@@ -650,8 +651,6 @@ class FusedRounds:
             lowered = self._run.lower(self._init_carry(), *self._data,
                                       jnp.uint32(r0), rounds)
         analysis = lowered.compile().cost_analysis()
-        if isinstance(analysis, (list, tuple)):  # older jax returns [dict]
-            analysis = analysis[0] if analysis else {}
         return dict(analysis or {})
 
     def train(self, max_rounds_per_dispatch: Optional[int] = None) -> Dict:

@@ -44,23 +44,30 @@ from pathlib import Path
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
+from jax.extend import core as _jcore
 
 from fedml_tpu.analysis.finding import Finding, audit_finding
 from fedml_tpu.analysis.registry import AuditSpec, load_entry_points
-
-try:  # jax >= 0.4.x exposes the stable aliases under jax.extend
-    from jax.extend import core as _jcore
-except ImportError:  # pragma: no cover - very old jax
-    from jax import core as _jcore  # type: ignore
 
 LOOP_PRIMITIVES = frozenset({"scan", "while"})
 CALLBACK_PRIMITIVES = frozenset(
     {"pure_callback", "io_callback", "debug_callback"})
 
-#: cross-device communication primitives (the collective signature)
-COLLECTIVE_PRIMITIVES = frozenset({
-    "psum", "pmax", "pmin", "ppermute", "pshuffle", "all_gather",
-    "all_to_all", "reduce_scatter", "psum_scatter", "pgather"})
+#: cross-device communication primitives (the collective signature),
+#: keyed by traced primitive name -> the public name the baseline records.
+#: Inside ``shard_map`` JAX 0.9 traces ``lax.psum`` as ``psum_invariant``
+#: (and the typed all_gather/reduce_scatter forms as the siblings below);
+#: they are the same wire operation, so they report under the public name.
+COLLECTIVE_PRIMITIVES = {
+    **{name: name for name in (
+        "psum", "pmax", "pmin", "ppermute", "pshuffle", "all_gather",
+        "all_to_all", "reduce_scatter", "psum_scatter", "pgather")},
+    "psum_invariant": "psum",
+    "unreduced_psum": "psum",
+    "all_gather_invariant": "all_gather",
+    "all_gather_reduced": "all_gather",
+    "unreduced_reduce_scatter": "reduce_scatter",
+}
 
 #: FT106 fires when an entry's per-(op, axes) bytes estimate grows or
 #: shrinks beyond this factor vs the baseline (shape-tolerant: model or
@@ -168,7 +175,7 @@ def audit_spec(name: str, spec: AuditSpec) -> Tuple[List[Finding], Dict]:
     def visit(eqn, in_loop: bool) -> None:
         prim = eqn.primitive.name
         if prim in COLLECTIVE_PRIMITIVES and _first_walk[0]:
-            key = (prim, _collective_axes(eqn))
+            key = (COLLECTIVE_PRIMITIVES[prim], _collective_axes(eqn))
             entry = collectives.setdefault(key, [0, 0])
             entry[0] += 1
             for v in eqn.outvars:

@@ -37,15 +37,16 @@ from fedml_tpu.ops.quantize import dequantize_tree, quantize_tree
 from fedml_tpu.ops.sparsify import (k_for, topk_densify, topk_dequantize,
                                     topk_quantize, topk_quantize_donated,
                                     topk_sparsify, topk_sparsify_donated)
+from fedml_tpu.utils import on_tpu
 
 COMPRESSED_FLAG = "__delta_int8__"
 TOPK_FLAG = "__topk_ef__"
 
 
 def _resolve_interpret(interpret: Optional[bool]) -> bool:
-    # the kernels carry TPU tiling; anything else runs the interpreter
+    # compiled kernels on tpu, the Pallas interpreter on cpu (utils.on_tpu)
     if interpret is None:
-        return jax.devices()[0].platform != "tpu"
+        return not on_tpu()
     return interpret
 
 

@@ -118,11 +118,6 @@ def add_federated_args(parser: argparse.ArgumentParser):
                              "endpoint keeps serving its last good "
                              "model either way — a bounded-stale answer "
                              "beats a refused one)")
-    parser.add_argument("--compile_cache_dir", type=str, default=None,
-                        help="persistent XLA compilation cache dir "
-                             "(default: $FEDML_TPU_COMPILE_CACHE; unset = "
-                             "off) — saves cold-launch recompiles of "
-                             "already-compiled round programs")
     parser.add_argument("--use_wandb", action="store_true")
     parser.add_argument("--checkpoint_dir", type=str, default=None)
     parser.add_argument("--resume", action="store_true")

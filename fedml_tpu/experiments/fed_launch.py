@@ -587,17 +587,15 @@ def run_algo(args):
 
 
 def main(argv=None):
-    from fedml_tpu.utils import (enable_persistent_compilation_cache,
-                                 force_platform_from_env)
-    force_platform_from_env()
     from fedml_tpu.experiments.main_fedavg import apply_ci_truncation
+    from fedml_tpu.utils import enable_persistent_compilation_cache
 
     parser = argparse.ArgumentParser("fedml_tpu fed_launch")
     parser.add_argument("--algo", type=str, default="fedavg", choices=ALGOS)
     add_federated_args(parser)
     add_algo_args(parser)
     args = apply_ci_truncation(parser.parse_args(argv))
-    enable_persistent_compilation_cache(args.compile_cache_dir)
+    enable_persistent_compilation_cache()
     logging.basicConfig(level=logging.INFO)
     from fedml_tpu.utils.tracing import profile
     with profile(getattr(args, "profile_dir", None)):

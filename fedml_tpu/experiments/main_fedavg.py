@@ -177,13 +177,11 @@ BACKEND_RUNNERS = {"simulation": run_simulation, "spmd": run_spmd,
 
 
 def main(argv=None):
-    from fedml_tpu.utils import (enable_persistent_compilation_cache,
-                                 force_platform_from_env)
-    force_platform_from_env()
+    from fedml_tpu.utils import enable_persistent_compilation_cache
     parser = argparse.ArgumentParser("fedml_tpu fedavg")
     add_federated_args(parser)
     args = apply_ci_truncation(parser.parse_args(argv))
-    enable_persistent_compilation_cache(args.compile_cache_dir)
+    enable_persistent_compilation_cache()
     logging.basicConfig(level=logging.INFO)
     ds, model, task = build_dataset_and_model(args)
     sink = MetricsSink(args.run_dir, config=vars(args),

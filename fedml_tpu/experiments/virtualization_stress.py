@@ -5,7 +5,7 @@ clients with 50 sampled per round (stackoverflow_nwp/data_loader.py,
 benchmark/README.md:57). What this stresses is not FLOPs but the
 *virtualization machinery*: seeded cohort sampling over ~342k clients,
 per-cohort gather/pack at a padded bucket, dispatch, and memory residency
-of a multi-GB federation across rounds (VERDICT r4 #4).
+of a multi-GB federation across rounds.
 
 This runner drives raw rounds through the sim (vmapped) and optionally
 mesh drivers, BLOCKING after each round so every record carries an honest
@@ -45,16 +45,11 @@ def main(argv=None):
     p.add_argument("--drivers", type=str, default="sim")
     p.add_argument("--eval_subsample", type=int, default=1000,
                    help="one final eval over a seeded subsample (0 = skip)")
-    p.add_argument("--compile_cache_dir", type=str, default=None,
-                   help="persistent XLA compilation cache dir (default: "
-                        "$FEDML_TPU_COMPILE_CACHE; unset = off)")
     p.add_argument("--out", type=str, required=True)
     args = p.parse_args(argv)
 
-    from fedml_tpu.utils import (enable_persistent_compilation_cache,
-                                 force_platform_from_env)
-    force_platform_from_env()
-    enable_persistent_compilation_cache(args.compile_cache_dir)
+    from fedml_tpu.utils import enable_persistent_compilation_cache
+    enable_persistent_compilation_cache()
     import jax
 
     from fedml_tpu.data.registry import DEFAULT_MODEL_AND_TASK, load_data

@@ -189,6 +189,18 @@ def load_packer() -> ctypes.CDLL:
     return lib
 
 
+def packer_status() -> str:
+    """Which packer has served this process so far: ``"native"`` once the
+    C++ library is loaded, ``"python"`` with the reason after a failed
+    build, or — when no cohort was large enough to try — the numpy
+    loop."""
+    if isinstance(_packer_handle, NativeUnavailable):
+        return f"python (native unavailable: {_packer_handle})"
+    if _packer_handle is None:
+        return "python (no cohort reached the native packer's size floor)"
+    return "native"
+
+
 def pack_arrays_native(srcs, dst, mask=None,
                        n_threads: Optional[int] = None) -> None:
     """Gather ragged per-client arrays into ``dst [P, n_pad, ...]`` with

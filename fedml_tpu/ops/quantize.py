@@ -28,8 +28,12 @@ def _quant_kernel(x_ref, rand_ref, vals_ref, scales_ref):
     absmax = jnp.max(jnp.abs(x), axis=1, keepdims=True)
     scale = jnp.maximum(absmax, 1e-12) / 127.0
     scaled = x / scale
-    # stochastic rounding: floor + Bernoulli(frac) using uniform [0,1) bits
-    u = (rand_ref[:] >> jnp.uint32(8)).astype(jnp.float32) * (2.0 ** -24)
+    # stochastic rounding: floor + Bernoulli(frac) using uniform [0,1) bits.
+    # Mosaic has no uint32 -> f32 cast; the 24 surviving bits are
+    # non-negative as int32, so the bitcast changes no value
+    bits = jax.lax.bitcast_convert_type(rand_ref[:] >> jnp.uint32(8),
+                                        jnp.int32)
+    u = bits.astype(jnp.float32) * (2.0 ** -24)
     low = jnp.floor(scaled)
     q = low + (u < (scaled - low)).astype(jnp.float32)
     q = jnp.clip(q, -127.0, 127.0)

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from fedml_tpu.ops.quantize import dequantize_int8, quantize_int8
+from fedml_tpu.utils import on_tpu
 
 
 def k_for(d: int, frac: float) -> int:
@@ -69,9 +70,9 @@ def topk_sparsify_reference(x, k: int):
 
 def _donate_flat_input() -> bool:
     """Donate the flat delta buffer only where XLA implements donation
-    (tpu/gpu aliasing); the CPU backend warns-and-copies, so tests under
+    (tpu aliasing); the CPU backend warns-and-copies, so tests under
     JAX_PLATFORMS=cpu run the identical program without the donation."""
-    return jax.default_backend() in ("tpu", "gpu")
+    return on_tpu()
 
 
 @functools.lru_cache(maxsize=None)
@@ -83,7 +84,7 @@ def _donated_topk_sparsify(k: int, donate: bool):
 
 def topk_sparsify_donated(x: jax.Array, k: int):
     """:func:`topk_sparsify` with the input buffer donated to the
-    computation (the residual reuses the delta's memory on tpu/gpu —
+    computation (the residual reuses the delta's memory on tpu —
     the flat delta is a freshly built temporary at every call site, so
     the aliasing is free bandwidth). Same compiled program otherwise:
     bit-exact with :func:`topk_sparsify` and the numpy reference."""

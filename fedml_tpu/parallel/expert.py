@@ -25,10 +25,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from fedml_tpu.utils.jax_compat import install_jax_compat
-
-install_jax_compat()
-
 
 def init_moe_params(key, n_experts: int, width: int, hidden: int):
     """Stacked expert FFN params: w_up [E, w, h], w_dn [E, h, w], and the

@@ -7,7 +7,7 @@ largest-axis rule (parallel/fsdp.py), and Megatron TP on a ``('tp',)``
 mesh with its own name rules (parallel/tensor.py). They could not
 compose: a federated round was either data-parallel OR model-sharded,
 and every measured bench row ran one chip while the multichip story
-lived in a dryrun artifact (``MULTICHIP_r*.json``).
+was a dry run (``__graft_entry__.dryrun_multichip``).
 
 This module promotes all of it to one canonical named mesh:
 
@@ -498,10 +498,11 @@ def _bench_workload(workload: str, mesh_shape: Dict[str, int],
 
     from fedml_tpu.parallel.spmd import (DistributedFedAvgAPI,
                                          DistributedFedAvgConfig)
+    from fedml_tpu.utils import on_tpu
     from fedml_tpu.utils.flops import analytic_flops
 
     n_dev = int(np.prod(list(mesh_shape.values())))
-    tpu = jax.default_backend() == "tpu"
+    tpu = on_tpu()
     if workload == "transformer_flash_s2048":
         from fedml_tpu.data.synthetic import make_token_federated
         from fedml_tpu.models.transformer import TransformerLM
@@ -679,12 +680,14 @@ def _run_smoke(out_dir: str) -> int:
     baseline = os.path.join(os.path.dirname(os.path.dirname(
         os.path.dirname(os.path.abspath(__file__)))), "ci",
         "collective_baseline.json")
+    # reports cover the mesh entries only, so every baseline finding is
+    # about one of them (or the baseline file itself); baseline entries
+    # for other programs come back as stale names, ignored here
     base_findings, _stale = check_collective_baseline(reports, baseline)
-    findings += [f for f in base_findings if f.where in mesh_entries
-                 or f.where == "<baseline>"]
+    findings += base_findings
     if findings:
         for f in findings:
-            print(f"mesh smoke: {f.rule} {f.where}: {f.message}")
+            print(f"mesh smoke: {f.format_text()}")
         return 1
 
     # flight log merged with the ledger — rc 0 is the lane's contract
@@ -722,12 +725,9 @@ def _cli(argv=None) -> int:
                         help="fused rounds per dispatch")
     parser.add_argument("--dispatches", type=int, default=2,
                         help="timed dispatches (after one warmup)")
-    parser.add_argument("--force-host", action="store_true",
-                        help="pin the CPU platform (the caller sets "
-                             "XLA_FLAGS for the virtual device count)")
     args = parser.parse_args(argv)
-    if args.force_host:
-        jax.config.update("jax_platforms", "cpu")
+    from fedml_tpu.utils import enable_persistent_compilation_cache
+    enable_persistent_compilation_cache()
     if args.bench_worker:
         row = _bench_workload(args.workload, parse_mesh_shape(args.mesh),
                               args.rounds, args.dispatches)

@@ -35,9 +35,6 @@ from fedml_tpu.core.sampling import (eval_subsample, round_keys,
 from fedml_tpu.data.base import FederatedDataset
 from fedml_tpu.trainer.functional import (TrainConfig, make_eval,
                                           make_local_train, round_lr_scale)
-from fedml_tpu.utils.jax_compat import install_jax_compat
-
-install_jax_compat()
 
 
 def build_mesh(axis_sizes: Dict[str, int],
@@ -45,19 +42,12 @@ def build_mesh(axis_sizes: Dict[str, int],
     """Build a named mesh, e.g. {'clients': 8} or {'group': 2, 'clients': 4}."""
     shape = tuple(axis_sizes.values())
     names = tuple(axis_sizes.keys())
-    # Auto axis types where the API has them: arrays don't get
-    # mesh-committed shardings-in-types (Explicit mode pins inputs to one
-    # mesh and breaks multi-mesh programs). Pre-AxisType jax is all-Auto
-    # already, so omitting the kwarg is the same semantics.
-    if hasattr(jax.sharding, "AxisType"):
-        types = tuple(jax.sharding.AxisType.Auto for _ in names)
-        if devices is None:
-            return jax.make_mesh(shape, names, axis_types=types)
-        return Mesh(np.asarray(devices).reshape(shape), names,
-                    axis_types=types)
+    # Auto axis types: arrays don't get mesh-committed shardings-in-types
+    # (Explicit mode pins inputs to one mesh and breaks multi-mesh programs)
+    types = tuple(jax.sharding.AxisType.Auto for _ in names)
     if devices is None:
-        return jax.make_mesh(shape, names)
-    return Mesh(np.asarray(devices).reshape(shape), names)
+        return jax.make_mesh(shape, names, axis_types=types)
+    return Mesh(np.asarray(devices).reshape(shape), names, axis_types=types)
 
 
 def _pvary(tree, axes: Tuple[str, ...]):
@@ -67,17 +57,20 @@ def _pvary(tree, axes: Tuple[str, ...]):
     shard_map body transposes the broadcast into an implicit ``psum`` — every
     client would receive the SUM of all clients' gradients instead of its own
     (caught by the sim==distributed parity test)."""
-    if hasattr(jax.lax, "pcast"):
-        return jax.tree.map(lambda v: jax.lax.pcast(v, axes, to="varying"), tree)
-    return jax.tree.map(lambda v: jax.lax.pvary(v, axes), tree)
+    return jax.tree.map(lambda v: jax.lax.pcast(v, axes, to="varying"), tree)
 
 
 def _weighted_psum_mean(stacked, weights, axes: Tuple[str, ...]):
     """sum_i w_i * leaf_i over the local client axis, psum over mesh axes,
     divide by the global weight total — the FedAvg aggregation rule
-    (FedAVGAggregator.py:58-87) as two collectives."""
+    (FedAVGAggregator.py:58-87) as two collectives. The local contraction
+    runs at HIGHEST precision: the TPU's default multiplies f32 operands
+    in one bf16 pass, which would round the new global model to ~3 digits
+    every round."""
     wsum = jax.tree.map(
-        lambda s: jnp.tensordot(weights.astype(s.dtype), s, axes=1), stacked)
+        lambda s: jnp.tensordot(weights.astype(s.dtype), s, axes=1,
+                                precision=jax.lax.Precision.HIGHEST),
+        stacked)
     wsum = jax.lax.psum(wsum, axes)
     wtot = jax.lax.psum(jnp.sum(weights), axes)
     return jax.tree.map(lambda s: s / wtot.astype(s.dtype), wsum)
@@ -469,6 +462,14 @@ class DistributedFedAvgAPI:
                                      jnp.asarray(sample_x), train=False)
         if self._shard_params is not None:  # place into the TP/FSDP layout
             self.variables = self._shard_params(self.variables)
+        else:
+            # commit the fresh init to the mesh the way every round's
+            # output comes back (replicated): jit caches on input
+            # sharding, so an uncommitted round-0 model would compile the
+            # round once for round 0 and again for round 1 (the first
+            # chip run paid 33.8 s, then 24.7 s)
+            self.variables = jax.device_put(
+                self.variables, NamedSharding(self.mesh, P()))
         self.history: List[Dict] = []
         from fedml_tpu.utils.tracing import RoundTimer
         self.timer = RoundTimer()  # pack/dispatch means, as FedAvgAPI
@@ -934,6 +935,7 @@ class DistributedFedAvgAPI:
                     test_stats = self._eval_global()
                 if test_stats is not None:
                     rec.update(_normalized(test_stats, "test"))
+                rec["wall_s"] = time.time() - t0  # as FedAvgAPI.train
                 self.history.append(rec)
             if checkpoint_mgr is not None:
                 checkpoint_mgr.save(round_idx + 1,

@@ -76,8 +76,8 @@ def _cmd_smoke(args) -> int:
 
 def main(argv: Optional[List[str]] = None) -> int:
     logging.basicConfig(level=logging.INFO)
-    from fedml_tpu.utils import force_platform_from_env
-    force_platform_from_env()
+    from fedml_tpu.utils import enable_persistent_compilation_cache
+    enable_persistent_compilation_cache()
     parser = argparse.ArgumentParser(
         prog="python -m fedml_tpu.sched",
         description="federation scheduler: multi-job tenancy tools")

@@ -108,8 +108,8 @@ def run_smoke(rounds: int = 4, workers: int = 3, requests: int = 50,
 
 
 def main(argv=None) -> int:
-    from fedml_tpu.utils import force_platform_from_env
-    force_platform_from_env()
+    from fedml_tpu.utils import enable_persistent_compilation_cache
+    enable_persistent_compilation_cache()
     logging.basicConfig(level=logging.WARNING)
     parser = argparse.ArgumentParser(
         "python -m fedml_tpu.serve",

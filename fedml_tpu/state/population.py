@@ -528,8 +528,8 @@ def _run_population_leg(population: int, rounds: int, cohort: int,
 def main(argv=None) -> int:
     import argparse
 
-    from fedml_tpu.utils import force_platform_from_env
-    force_platform_from_env()
+    from fedml_tpu.utils import enable_persistent_compilation_cache
+    enable_persistent_compilation_cache()
 
     p = argparse.ArgumentParser("python -m fedml_tpu.state.population")
     p.add_argument("--population", type=int, default=100_000)

@@ -204,6 +204,12 @@ def make_local_train(module, task: str, cfg: TrainConfig,
     clients): per-step loss terms and gradients are psum'd over them so
     every shard takes the identical optimizer step.
     """
+    from fedml_tpu.utils import on_tpu
+
+    # every driver (sim, spmd, cross-silo, mesh) builds its trainer here,
+    # so here the one backend rule refuses a backend nobody chose — a cpu
+    # JAX fell back to must not train and exit 0
+    on_tpu()
     head: TaskHead = TASK_HEADS[task]
     forward = make_forward(module)
     tx = make_optimizer(cfg)

@@ -112,10 +112,9 @@ def analytic_flops(fn, *args, **kwargs) -> float:
     the traced jaxpr, i.e. the backward convs/matmuls count as the real
     ops XLA will run, not a 3x-forward heuristic.
 
-    Use when the XLA cost model is unavailable — some TPU plugin paths
-    return no ``cost_analysis`` for conv round programs (BENCH_r05's
-    ``resnet18_gn_fedcifar100`` serialized ``round_flops: null``); the
-    jaxpr count stands in so MFU evidence never silently drops."""
+    Use when the XLA cost model returns no ``flops`` for a program (conv
+    round programs have come back without them); the jaxpr count stands
+    in so MFU evidence never silently drops."""
     closed = jax.make_jaxpr(fn)(*args, **kwargs)
     return _jaxpr_flops(closed.jaxpr)
 
@@ -125,8 +124,6 @@ def cost_analysis(fn, *args) -> Dict[str, float]:
     lowered = jax.jit(fn).lower(*args)
     compiled = lowered.compile()
     analysis = compiled.cost_analysis()
-    if isinstance(analysis, (list, tuple)):  # older jax returns [dict]
-        analysis = analysis[0] if analysis else {}
     return dict(analysis or {})
 
 

@@ -217,8 +217,6 @@ def main(argv=None) -> int:
     p.add_argument("--workers", type=int, default=SMOKE_WORKERS)
     p.add_argument("--population", type=int, default=SMOKE_POPULATION)
     args = p.parse_args(argv)
-    from fedml_tpu.utils import force_platform_from_env
-    force_platform_from_env()
     if args.mode == "curve":
         return curve(args.trace, args.rounds, args.round_s, args.workers,
                      args.population)

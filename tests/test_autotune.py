@@ -360,51 +360,3 @@ class TestSequenceParallelWiring:
             np.asarray(fn(q, k, v)),
             np.asarray(reference_attention(q, k, v, causal=True)),
             rtol=1e-5, atol=1e-5)
-
-
-class TestCompilationCacheHelper:
-    @pytest.fixture
-    def restore_cfg(self):
-        prev = jax.config.jax_compilation_cache_dir
-        yield
-        jax.config.update("jax_compilation_cache_dir", prev)
-
-    def test_explicit_dir_is_applied(self, tmp_path, restore_cfg):
-        from fedml_tpu.utils import enable_persistent_compilation_cache
-
-        target = str(tmp_path / "xla_cache")
-        assert enable_persistent_compilation_cache(target) == target
-        assert jax.config.jax_compilation_cache_dir == target
-        import os
-        assert os.path.isdir(target)
-
-    def test_env_var_is_applied(self, tmp_path, monkeypatch, restore_cfg):
-        from fedml_tpu.utils import enable_persistent_compilation_cache
-
-        target = str(tmp_path / "xla_cache_env")
-        monkeypatch.setenv("FEDML_TPU_COMPILE_CACHE", target)
-        assert enable_persistent_compilation_cache() == target
-        assert jax.config.jax_compilation_cache_dir == target
-
-    def test_unset_is_a_no_op(self, monkeypatch):
-        from fedml_tpu.utils import enable_persistent_compilation_cache
-
-        monkeypatch.delenv("FEDML_TPU_COMPILE_CACHE", raising=False)
-        prev = jax.config.jax_compilation_cache_dir
-        assert enable_persistent_compilation_cache() is None
-        assert jax.config.jax_compilation_cache_dir == prev
-
-    def test_all_five_launchers_enable_the_cache(self):
-        """Source-level wiring guard: every launcher (and bench) routes
-        through the ONE shared helper, so the knob can't drift."""
-        import os
-        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-        launchers = [
-            os.path.join(root, "fedml_tpu", "experiments", p)
-            for p in ("fed_launch.py", "main_fedavg.py",
-                      "flagship_scale.py", "virtualization_stress.py")
-        ] + [os.path.join(root, "bench.py")]
-        for path in launchers:
-            with open(path) as f:
-                src = f.read()
-            assert "enable_persistent_compilation_cache(" in src, path

@@ -53,7 +53,7 @@ class TestCohortPaddedLen:
         assert len(shapes) <= int(np.log2(max_nb)) + 2, shapes
 
     def test_powerlaw_padded_rows_reduced_3x(self):
-        """The VERDICT contract: at the reference MNIST scale (1000 clients,
+        """The contract: at the reference MNIST scale (1000 clients,
         power-law sizes, 10 sampled/round) cohort packing does ≥3x fewer
         padded rows — a direct proxy for per-round FLOPs, which are linear
         in rows through the whole train scan."""

@@ -73,8 +73,8 @@ class TestFlops:
 
 class TestAnalyticFlops:
     """The conv/GroupNorm jaxpr cost model (utils/flops.analytic_flops) —
-    the bench's fallback when the chip plugin's XLA cost analysis returns
-    nothing for conv round programs (BENCH_r05 resnet nulls)."""
+    the bench's fallback when XLA's cost analysis returns nothing for conv
+    round programs."""
 
     def test_matmul_exact(self):
         import jax.numpy as jnp

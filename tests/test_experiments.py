@@ -172,7 +172,7 @@ class TestFedLaunch:
 
     def test_fedavg_async_quorum(self, tmp_path):
         # straggler-tolerant federation through the CLI: quorum rounds on
-        # the in-proc actor protocol (VERDICT r3 #8)
+        # the in-proc actor protocol
         final = fed_launch.main(self._common(tmp_path, "fedavg_async") +
                                 ["--async_mode", "quorum", "--quorum", "2",
                                  "--round_deadline_s", "30"])

@@ -191,8 +191,8 @@ class TestMeshFusedRounds:
         assert num / den < 1e-6, (num, den)
 
     def test_fused_mesh_sampled_block_matches_host_loop(self):
-        """Sampled cohorts on the mesh run as host-drawn fused blocks
-        (VERDICT r3 #2): 4-of-12 at 8 devices — cohorts pad to the mesh
+        """Sampled cohorts on the mesh run as host-drawn fused blocks:
+        4-of-12 at 8 devices — cohorts pad to the mesh
         multiple with zero-weight slots, block packs at the cohort bucket,
         trajectory equals R run_round calls."""
         from fedml_tpu.parallel.spmd import (DistributedFedAvgAPI,
@@ -322,7 +322,7 @@ class TestMeshFusedRounds:
 class TestFusedBlockSampling:
     """Block mode (default for partial cohorts): host-presampled R-cohort
     blocks packed at the block's cohort bucket — BOTH throughput levers in
-    one dispatch, trajectory-identical to the host loop (VERDICT r3 #1)."""
+    one dispatch, trajectory-identical to the host loop."""
 
     def test_block_matches_host_loop_trajectory(self):
         # 4-of-12 sampling: same cohorts (sample_clients stream), same

@@ -1,5 +1,5 @@
 """Raw-format ingestion: ImageFolder tree, hdf5 streaming, converters,
-fetch registry (VERDICT round-1 item 4)."""
+fetch registry."""
 
 import os
 import subprocess

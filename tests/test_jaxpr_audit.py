@@ -70,8 +70,7 @@ class TestPlantedViolations:
         assert findings == [] and report["n_lowering_keys"] == 1
 
     def test_f64_result_is_flagged(self):
-        from jax.experimental import enable_x64
-        with enable_x64():
+        with jax.enable_x64(True):
             spec = AuditSpec(
                 fn=lambda x: x.astype("float64") * 2,
                 sweep=[(jnp.ones(3, jnp.float32),)])
@@ -222,8 +221,12 @@ class TestCollectiveSignature:
             return jax.lax.psum(x, ("clients",)) + g.sum()
 
         n = 8 * len(jax.devices())
+        # check_vma off: a gathered sum is typed device-varying, which
+        # out_specs=P() would reject; off, psum also traces under its
+        # plain name, so both spellings must land on one baseline key
         rogue = AuditSpec(fn=jax.jit(jax.shard_map(
-            rogue_body, mesh=mesh, in_specs=P("clients"), out_specs=P())),
+            rogue_body, mesh=mesh, in_specs=P("clients"), out_specs=P(),
+            check_vma=False)),
             sweep=[(jnp.ones(n, jnp.float32),)])
         _, rep = audit_spec("planted.entry", rogue)
         findings, _ = check_collective_baseline([rep], bl)

@@ -1,4 +1,4 @@
-"""Real 2-process jax.distributed rendezvous (VERDICT round-1 item 9).
+"""Real 2-process jax.distributed rendezvous.
 
 tests/test_multihost.py covers the multihost helpers single-process; this
 exercises the actual coordinator handshake: 2 subprocesses × 4 virtual CPU

@@ -69,7 +69,7 @@ def test_clients_x_seq_round_matches_single_device():
 @pytest.mark.slow
 class TestSeqVsTpRatioGuard:
     """Regression guards for the r5 bench's 577.8 tokens/s seq row
-    (VERDICT #5): the seq round's jit caches on input *sharding* — the
+    (a record predating this installation): the seq round's jit caches on input *sharding* — the
     first call (uncommitted lm.init params) compiles one signature, its
     mesh-committed output makes the second call a cache miss, and that
     second compile landed inside the bench's timed region. The tp twin
@@ -122,7 +122,7 @@ class TestSeqVsTpRatioGuard:
         jax.block_until_ready(v)
         assert seq_fn._cache_size() == warmed, (
             "seq round recompiled after both warmup signatures — a compile "
-            "is back inside what bench_parallel_axes times (VERDICT r5 #5)")
+            "is back inside what bench_parallel_axes times")
 
     def test_seq_vs_tp_ratio_at_cpu_shapes(self):
         seq_fn, tp_fn, shard_params, variables, args = self._build()

@@ -43,6 +43,9 @@ class TestSpmdRound:
             dist.run_round(r)
         diff = float(pt.tree_norm(pt.tree_sub(sim.variables, dist.variables)))
         assert diff < 1e-5, diff
+        # the fresh init goes in committed to the mesh like every round's
+        # output, so round 0 and the rest share ONE compiled program
+        assert dist._round_fn._cache_size() == 1
 
     def test_round_padding_to_mesh_multiple(self, mesh8):
         # 5 clients/round on an 8-device mesh: 3 zero-weight pad slots
@@ -196,7 +199,7 @@ class TestRnnOnMesh:
     jax's varying-manual-axes checker rejected it — check_vma=False on the
     spmd programs with correctness held by the sim==mesh parity below).
     Found by running the stackoverflow_nwp stress through the mesh
-    driver (VERDICT r4 #4 'through both drivers')."""
+    driver."""
 
     def test_lstm_round_matches_vmapped_simulation(self, mesh8):
         from fedml_tpu.data.base import FederatedDataset

@@ -159,11 +159,6 @@ class TestBenchWiring:
         import bench
         assert bench._trend_metrics({"error": "x",
                                      "rounds_per_sec": 1.0}) is None
-        assert bench._trend_metrics({"skipped": "x"}) is None
-        assert bench._trend_metrics({"rounds_per_sec": 1.0,
-                                     "resumed": True}) is None
-        assert bench._trend_metrics({"rounds_per_sec": 1.0,
-                                     "rerun_failed": {}}) is None
         assert bench._trend_metrics({"tokens_per_sec": 1.0}) is None
 
     def test_append_trend_row_first_passes_then_regression_fails(
