@@ -1,0 +1,20 @@
+"""Tests of the benchmark itself, run by hand on the CPU:
+
+    JAX_PLATFORMS=cpu python -m pytest benchmark/tests -q
+
+They are not part of the repository's tier-1 tests (``tests/``). Four
+virtual CPU devices stand in for the four-chip host."""
+
+import os
+import sys
+
+os.environ["JAX_PLATFORMS"] = "cpu"
+if "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", ""):
+    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
+                               + " --xla_force_host_platform_device_count=4"
+                               ).strip()
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
