@@ -245,13 +245,14 @@ class FedAvgAPI:
         arrays inside one payload (the stale payload is then discarded by
         the caller's identity check)."""
         ds = self.dataset
-        idxs = sample_clients(round_idx, ds.client_num,
-                              self.config.client_num_per_round,
-                              delete_client=self.delete_client)
-        xd, yd, maskd, wd = self._pack_cohort(idxs, dataset=ds)
-        _, keys, agg_key = round_keys(
-            self._base_key, round_idx,
-            jnp.asarray(np.asarray(idxs), dtype=jnp.uint32))
+        with self.timer.phase("produce"):
+            idxs = sample_clients(round_idx, ds.client_num,
+                                  self.config.client_num_per_round,
+                                  delete_client=self.delete_client)
+            xd, yd, maskd, wd = self._pack_cohort(idxs, dataset=ds)
+            _, keys, agg_key = round_keys(
+                self._base_key, round_idx,
+                jnp.asarray(np.asarray(idxs), dtype=jnp.uint32))
         return ds, idxs, (xd, yd, maskd, keys, wd, agg_key)
 
     def _prepare_round(self, round_idx: int):
@@ -312,8 +313,9 @@ class FedAvgAPI:
         return self._prefetch[0]
 
     def prefetch_stats(self):
-        """Prefetcher counters (hits/misses/wait_s/hidden_s) or None when
-        the serial path ran — evidence hook for bench/tests."""
+        """Prefetcher counters (hits/misses/invalidated) or None when the
+        serial path ran — evidence hook for bench/tests; the durations are
+        the timer's ``prefetch_wait`` and ``produce``."""
         return self._prefetch[0].stats() if self._prefetch else None
 
     def release_prefetch(self):
@@ -340,9 +342,9 @@ class FedAvgAPI:
             # so instead of leaving an empty timeline to be discovered
             logging.warning(
                 "observability is on but the fused multi-round driver "
-                "dispatches whole round BLOCKS — the flight log gets no "
-                "per-round records (and the slow-round detector no "
-                "durations) for fused spans; use the host round loop "
+                "dispatches whole round BLOCKS — the flight log gets one "
+                "record a block, not a round (and the slow-round detector "
+                "no durations) for fused spans; use the host round loop "
                 "for per-round timelines")
         return self._fused_driver_cls(self, device_sampling)
 
@@ -356,7 +358,8 @@ class FedAvgAPI:
         packed slots pinning HBM."""
         pf = self._round_prefetcher()
         if pf is None:
-            out = self._prepare_round(round_idx)
+            with self.timer.phase("produce"):
+                out = self._prepare_round(round_idx)
             self.timer.update_rss()  # consume() samples it on the
             return out               # pipelined path; mirror it here
         from fedml_tpu.parallel.prefetch import consume
@@ -371,8 +374,12 @@ class FedAvgAPI:
         self.timer.begin_round(round_idx)
         if self._obs is not None:
             self._obs.round_begin(round_idx)
-        idxs, (x, y, mask, keys, weights, agg_key) = \
-            self._host_round_inputs(round_idx)
+        # the previous round's output is a future until the device has run
+        # everything queued: was it starved while the host made this
+        # round's inputs?
+        with self.timer.starved_probe(jax.tree.leaves(self.variables)[0]):
+            idxs, (x, y, mask, keys, weights, agg_key) = \
+                self._host_round_inputs(round_idx)
         if self._obs is not None:
             # one-shot roofline probe (obs/perf.py): the analytic FLOP
             # count of THE round program about to dispatch, traced from
@@ -385,6 +392,8 @@ class FedAvgAPI:
                                        keys, weights, agg_key,
                                        jnp.uint32(round_idx)),
                 source="analytic_conv_gn_jaxpr")
+        # the slots the round program runs, padding and all
+        self.timer.count("rows_dispatched", x.shape[0] * x.shape[1])
         with self.timer.phase("dispatch"):
             self.variables, stats = self._round_fn(self.variables, x, y,
                                                    mask, keys, weights,
@@ -419,8 +428,7 @@ class FedAvgAPI:
                 with self.timer.phase("device_wait"):
                     # ft: allow[FT003] eval-boundary sync: one measured drain per test interval, by design
                     jax.block_until_ready(self.variables)
-                with self.timer.phase("eval"):
-                    rec = self.evaluate(round_idx)
+                rec = self.evaluate(round_idx)
                 # mean local-optimization loss this round (distinct from the
                 # post-aggregation train_loss evaluate() reports)
                 rec["train_loss_local"] = float(train_stats["loss_sum"]) / max(
@@ -460,12 +468,13 @@ class FedAvgAPI:
         means over the global train/test unions (equal to the reference's
         per-client weighted sums in _local_test_on_all_clients)."""
         rec = {"round": round_idx}
-        train, test = self._eval_arrays()
-        rec.update(_normalized(self._eval_fn(self.variables, *train),
-                               "train"))
-        if test is not None:
-            rec.update(_normalized(self._eval_fn(self.variables, *test),
-                                   "test"))
+        with self.timer.phase("eval"):
+            train, test = self._eval_arrays()
+            rec.update(_normalized(self._eval_fn(self.variables, *train),
+                                   "train"))
+            if test is not None:
+                rec.update(_normalized(
+                    self._eval_fn(self.variables, *test), "test"))
         return rec
 
 
@@ -622,6 +631,7 @@ class FusedRounds:
         """Advance the api's model by ``rounds`` fused rounds starting at
         round index ``r0``; returns stacked per-round stat totals."""
         api = self.api
+        api.timer.begin_round(r0)  # one span and one record a block
         if self.mode == "block":
             with api.timer.phase("pack"):
                 inputs = self._block_inputs(r0, rounds)
@@ -633,6 +643,7 @@ class FusedRounds:
                 carry, stats = self._run(
                     self._init_carry(), *self._data, jnp.uint32(r0), rounds)
         self._store_carry(carry)
+        api.timer.end_round(r0, extra={"rounds": rounds})
         return stats
 
     def cost_analysis(self, r0: int = 0, rounds: int = 1) -> Dict:
@@ -682,8 +693,7 @@ class FusedRounds:
             with api.timer.phase("device_wait"):
                 # ft: allow[FT003] eval-boundary sync after a fused chunk
                 jax.block_until_ready(api.variables)
-            with api.timer.phase("eval"):
-                rec = api.evaluate(r - 1)
+            rec = api.evaluate(r - 1)
             rec["train_loss_local"] = (
                 float(stats["loss_sum"][-1])
                 / max(1.0, float(stats["count"][-1])))

@@ -2001,7 +2001,7 @@ class FedAvgClientManager(ClientManager):
             # keyed on the ACTUAL (round, client): a mispredicted slot
             # simply misses and this same get() packs the right shard
             # inline — never two packs for one round
-            (ds, payload), _, _ = self._prefetch.get(
+            (ds, payload), _ = self._prefetch.get(
                 (round_idx, int(client_idx)))
             if ds is self.dataset:
                 packed = payload

@@ -55,6 +55,27 @@ METRICS: Dict[str, Dict[str, str]] = {
     "prefetch_wait": _m(KIND_PHASE, "prefetch",
                         "caller time blocked on an in-flight prefetch "
                         "slot (pack latency NOT hidden by the pipeline)"),
+    "produce": _m(KIND_PHASE, "round pipeline",
+                  "the whole host side of one round's inputs as the "
+                  "prefetch worker (or the serial path) runs it: "
+                  "sampling, pack, upload, per-client keys; produce - "
+                  "pack - upload is the unaccounted rest"),
+    "device_starved": _m(KIND_PHASE, "round pipeline",
+                         "host-input half of a round (up to dispatch) "
+                         "that began with the device already idle: a "
+                         "lower bound on the device time the host cost"),
+    "device_starved_max": _m(KIND_PHASE, "round pipeline",
+                             "host-input half of a round during which "
+                             "the device ran dry; device_starved + this "
+                             "is the upper bound"),
+    "starved_rounds": _m(KIND_COUNTER, "round pipeline",
+                         "rounds whose host-input half left the device "
+                         "idle (charged to device_starved or "
+                         "device_starved_max)"),
+    "rows_dispatched": _m(KIND_COUNTER, "round pipeline",
+                          "client slots x padded length of every round "
+                          "program dispatched: the rows the device runs, "
+                          "mesh and length padding included"),
     # -- prefetch counters (parallel/prefetch.py) --------------------------
     "prefetch_hit": _m(KIND_COUNTER, "prefetch",
                        "round consumed a speculatively packed cohort"),

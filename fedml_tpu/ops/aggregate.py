@@ -78,6 +78,7 @@ def weighted_mean_flat(stacked: jax.Array, weights: jax.Array,
     return out[0, :d]
 
 
+@jax.named_scope("fedml.aggregate")
 def tree_weighted_mean_pallas(stacked_tree, weights, *,
                               interpret: bool = False):
     """Pytree front-end: ravel all leaves into one ``[C, D]`` matrix, run the

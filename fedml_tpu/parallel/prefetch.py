@@ -36,10 +36,10 @@ bound is what caps HBM growth. Correctness contract:
 
 from __future__ import annotations
 
+import contextlib
 import os
 import queue
 import threading
-import time
 import weakref
 from typing import Any, Callable, Dict, Optional, Tuple
 
@@ -75,12 +75,10 @@ def _worker(ref: "weakref.ref", requests: "queue.SimpleQueue") -> None:
         if item is _SHUTDOWN:
             return
         key, gen, produce = item
-        t0 = time.perf_counter()
         try:
             payload, exc = produce(key), None
         except BaseException as e:  # noqa: BLE001 — re-raised at get()
             payload, exc = None, e
-        dt = time.perf_counter() - t0
         pf = ref()
         if pf is None:
             return
@@ -88,7 +86,7 @@ def _worker(ref: "weakref.ref", requests: "queue.SimpleQueue") -> None:
             if pf._inflight.get(key) == gen:
                 del pf._inflight[key]
             if gen == pf._gen and key in pf._window:
-                pf._ready[key] = (payload, exc, dt)
+                pf._ready[key] = (payload, exc)
             else:  # invalidated or mispredicted past: drop the stale slot
                 pf._stats["invalidated"] += 1
             pf._cond.notify_all()
@@ -115,30 +113,29 @@ class RoundPrefetcher:
         self.next_key = next_key or (lambda k: k + 1)
         self.name = name
         self._cond = threading.Condition()
-        self._ready: Dict[Any, Tuple[Any, Optional[BaseException],
-                                     float]] = {}
+        self._ready: Dict[Any, Tuple[Any, Optional[BaseException]]] = {}
         self._inflight: Dict[Any, int] = {}  # key -> generation
         self._window: set = set()  # keys speculation currently expects
         self._gen = 0
         self._requests: "queue.SimpleQueue" = queue.SimpleQueue()
         self._thread: Optional[threading.Thread] = None
         self._closed = False
-        self._stats = {"hits": 0, "misses": 0, "invalidated": 0,
-                       "wait_s": 0.0, "hidden_s": 0.0}
+        self._stats = {"hits": 0, "misses": 0, "invalidated": 0}
         # GC of the prefetcher (or interpreter exit) stops the worker
         self._finalizer = weakref.finalize(self, self._requests.put,
                                            _SHUTDOWN)
 
     # -- caller side -------------------------------------------------------
-    def get(self, key, upcoming=None) -> Tuple[Any, float, bool]:
-        """Payload for ``key``: ``(payload, waited_s, hit)``.
+    def get(self, key, upcoming=None, timer=None) -> Tuple[Any, bool]:
+        """Payload for ``key``: ``(payload, hit)``.
 
         Hit = the slot was produced (or is being produced) by the worker;
-        ``waited_s`` is the time this call blocked on an in-flight slot
-        (``prefetch_wait``). Miss = produced inline on this thread (the
-        serial path, charged to the producer's own timer phases). Either
-        way the speculation stream is re-aimed before any inline work, so
-        the worker packs ahead while a miss packs here.
+        the time this call blocks on an in-flight slot is ``timer``'s
+        ``prefetch_wait`` span, one a call, empty when the slot was ready.
+        Miss = produced inline on this thread (the serial path, charged to
+        the producer's own timer phases, never to the wait). Either way the
+        speculation stream is re-aimed before any inline work, so the
+        worker packs ahead while a miss packs here.
 
         ``upcoming`` — when the caller KNOWS its future key sequence
         (a driver's chunked schedule, a round loop that ends at
@@ -149,28 +146,26 @@ class RoundPrefetcher:
         ``None`` falls back to ``next_key`` prediction."""
         if self.depth <= 0 or self._closed:
             self._stats["misses"] += 1
-            return self.produce(key), 0.0, False
-        waited = 0.0
+            return self.produce(key), False
+        wait_span = (timer.phase("prefetch_wait") if timer is not None
+                     else contextlib.nullcontext())
         with self._cond:
             gen = self._gen
-            if key not in self._ready and self._inflight.get(key) == gen:
-                t0 = time.perf_counter()
-                while (self._gen == gen and key not in self._ready
-                       and key in self._inflight):
-                    self._cond.wait()
-                waited = time.perf_counter() - t0
-                self._stats["wait_s"] += waited
+            with wait_span:
+                if key not in self._ready and self._inflight.get(key) == gen:
+                    while (self._gen == gen and key not in self._ready
+                           and key in self._inflight):
+                        self._cond.wait()
             slot = self._ready.pop(key, None)
             self._schedule_locked(key, upcoming)
         if slot is not None:
-            payload, exc, dt = slot
+            payload, exc = slot
             if exc is not None:
                 raise exc
             self._stats["hits"] += 1
-            self._stats["hidden_s"] += max(0.0, dt - waited)
-            return payload, waited, True
+            return payload, True
         self._stats["misses"] += 1
-        return self.produce(key), waited, False
+        return self.produce(key), False
 
     def _schedule_locked(self, key, upcoming=None) -> None:
         """Queue the next speculation window — ``upcoming[:depth]`` when
@@ -229,11 +224,10 @@ class RoundPrefetcher:
         if self._thread is not None:
             self._thread.join(timeout=5.0)
 
-    def stats(self) -> Dict[str, float]:
-        """Counters for evidence rows: ``hits``/``misses``/``invalidated``
-        plus ``wait_s`` (caller time blocked on in-flight slots) and
-        ``hidden_s`` (worker produce time that overlapped device compute —
-        the pack+upload latency removed from the critical path)."""
+    def stats(self) -> Dict[str, int]:
+        """Counters for evidence rows: ``hits``/``misses``/``invalidated``.
+        The durations are the round timer's: ``prefetch_wait`` is what the
+        caller waited, ``produce`` less that what the pipeline hid."""
         with self._cond:
             return dict(self._stats)
 
@@ -258,9 +252,9 @@ def consume(pf: RoundPrefetcher, key, timer, dataset, repack,
     fused-block paths cannot drift: ``get`` the slot, verify its payload
     was packed against the CURRENT dataset (``repack(key)`` serially and
     drop everything speculative if a produce raced a swap), and charge
-    ``prefetch_wait`` + hit/miss counters to the round timer. The payload
-    contract is ``(dataset, ...)`` — produce snapshots the dataset it
-    packed from as element 0.
+    the ``prefetch_wait`` span + hit/miss counters to the round timer. The
+    payload contract is ``(dataset, ...)`` — produce snapshots the dataset
+    it packed from as element 0.
 
     With a store-backed virtual population (fedml_tpu/state/), the
     ``produce`` running on the worker IS the streaming cohort
@@ -277,12 +271,11 @@ def consume(pf: RoundPrefetcher, key, timer, dataset, repack,
     if round_bound is not None:
         upcoming = [r for r in range(key + 1, key + 1 + pf.depth)
                     if r < round_bound]
-    payload, waited, hit = pf.get(key, upcoming=upcoming)
+    payload, hit = pf.get(key, upcoming=upcoming, timer=timer)
     if payload[0] is not dataset:
         pf.invalidate()
         hit = False
         payload = repack(key)
-    timer.add("prefetch_wait", waited)
     timer.count("prefetch_hit" if hit else "prefetch_miss")
     timer.update_rss()
     return payload

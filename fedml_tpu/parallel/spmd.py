@@ -60,6 +60,7 @@ def _pvary(tree, axes: Tuple[str, ...]):
     return jax.tree.map(lambda v: jax.lax.pcast(v, axes, to="varying"), tree)
 
 
+@jax.named_scope("fedml.aggregate")
 def _weighted_psum_mean(stacked, weights, axes: Tuple[str, ...]):
     """sum_i w_i * leaf_i over the local client axis, psum over mesh axes,
     divide by the global weight total — the FedAvg aggregation rule
@@ -507,23 +508,30 @@ class DistributedFedAvgAPI:
         xt, yt = self.dataset.test_data_global
         if not len(xt):
             return None
-        if (self._eval_cache is None
-                or self._eval_cache[0] is not self.dataset):
-            xt, yt = eval_subsample(xt, yt,
-                                    self.config.eval_test_subsample,
-                                    self.config.seed)
-            n = len(xt)
-            n_pad = ((n + self.n_dev - 1) // self.n_dev) * self.n_dev
-            pad = n_pad - n
-            x = np.pad(np.asarray(xt), [(0, pad)] + [(0, 0)] * (xt.ndim - 1))
-            y = np.pad(np.asarray(yt), [(0, pad)] + [(0, 0)] * (yt.ndim - 1))
-            m = np.concatenate([np.ones(n, np.float32),
-                                np.zeros(pad, np.float32)])
-            put = lambda a: jax.device_put(jnp.asarray(a),
-                                           self._data_sharding)
-            self._eval_cache = (self.dataset, (put(x), put(y), put(m)))
-        x, y, m = self._eval_cache[1]
-        return self._eval_fn(self.variables, x, y, m)
+        with self.timer.phase("eval"):
+            if (self._eval_cache is None
+                    or self._eval_cache[0] is not self.dataset):
+                xt, yt = eval_subsample(xt, yt,
+                                        self.config.eval_test_subsample,
+                                        self.config.seed)
+                n = len(xt)
+                n_pad = ((n + self.n_dev - 1) // self.n_dev) * self.n_dev
+                pad = n_pad - n
+                x = np.pad(np.asarray(xt),
+                           [(0, pad)] + [(0, 0)] * (xt.ndim - 1))
+                y = np.pad(np.asarray(yt),
+                           [(0, pad)] + [(0, 0)] * (yt.ndim - 1))
+                m = np.concatenate([np.ones(n, np.float32),
+                                    np.zeros(pad, np.float32)])
+                put = lambda a: jax.device_put(jnp.asarray(a),
+                                               self._data_sharding)
+                self._eval_cache = (self.dataset, (put(x), put(y), put(m)))
+            x, y, m = self._eval_cache[1]
+            # every caller reads the sums as host floats next; waiting here
+            # makes the span the evaluation and not its enqueue
+            # ft: allow[FT003] eval-boundary sync, inside the eval phase
+            return jax.block_until_ready(
+                self._eval_fn(self.variables, x, y, m))
 
     def _pad_round(self, idxs: np.ndarray):
         """Pad the sampled-client list to a mesh-size multiple with
@@ -562,13 +570,15 @@ class DistributedFedAvgAPI:
         so a concurrent swap can't mix arrays; the payload carries it for
         the caller's identity check."""
         ds = self.dataset
-        idxs = sample_clients(round_idx, ds.client_num,
-                              self.config.client_num_per_round)
-        padded, (xd, yd, maskd, wd) = self._pack_cohort(idxs, dataset=ds)
-        _, keys, _ = round_keys(
-            self._base_key, round_idx,
-            jnp.asarray(np.asarray(padded), dtype=jnp.uint32))
-        keysd = jax.device_put(keys, self._data_sharding)
+        with self.timer.phase("produce"):
+            idxs = sample_clients(round_idx, ds.client_num,
+                                  self.config.client_num_per_round)
+            padded, (xd, yd, maskd, wd) = self._pack_cohort(idxs,
+                                                            dataset=ds)
+            _, keys, _ = round_keys(
+                self._base_key, round_idx,
+                jnp.asarray(np.asarray(padded), dtype=jnp.uint32))
+            keysd = jax.device_put(keys, self._data_sharding)
         return ds, idxs, (xd, yd, maskd, keysd, wd)
 
     def _round_prefetcher(self):
@@ -594,8 +604,9 @@ class DistributedFedAvgAPI:
         return self._prefetch[0]
 
     def prefetch_stats(self):
-        """Merged cohort + block prefetcher counters, or None when every
-        round ran the serial path — evidence hook for bench/tests."""
+        """Merged cohort + block prefetcher counters (hits/misses/
+        invalidated), or None when every round ran the serial path —
+        evidence hook for bench/tests."""
         out = None
         for pf in (self._prefetch, self._block_prefetch):
             if pf is None:
@@ -620,20 +631,19 @@ class DistributedFedAvgAPI:
             if pf is not None:
                 pf[0].invalidate()
 
-    def run_round(self, round_idx: int):
-        # flight-recorder round boundary (fedml_tpu/obs) — same pure-
-        # observer wiring as FedAvgAPI.run_round
-        self.timer.begin_round(round_idx)
-        if self._obs is not None:
-            self._obs.round_begin(round_idx)
+    def _host_round_inputs(self, round_idx: int):
+        """Pipelined-or-serial host inputs for one round, as
+        ``FedAvgAPI._host_round_inputs``: the prefetcher's slot, or (depth
+        0, full participation) the serial pack with its resident
+        ``_pack_cache`` cohort."""
         pf = self._round_prefetcher()
         if pf is not None:
             from fedml_tpu.parallel.prefetch import consume
             _, idxs, args = consume(pf, round_idx, self.timer,
                                     self.dataset, self._pack_round,
                                     round_bound=self.config.comm_round)
-            xd, yd, maskd, keysd, wd = args
-        else:
+            return idxs, args
+        with self.timer.phase("produce"):
             cfg = self.config
             idxs = sample_clients(round_idx, self.dataset.client_num,
                                   cfg.client_num_per_round)
@@ -652,6 +662,18 @@ class DistributedFedAvgAPI:
                 self._base_key, round_idx,
                 jnp.asarray(np.asarray(padded), dtype=jnp.uint32))
             keysd = jax.device_put(keys, self._data_sharding)
+        return idxs, (xd, yd, maskd, keysd, wd)
+
+    def run_round(self, round_idx: int):
+        # flight-recorder round boundary (fedml_tpu/obs) — same pure-
+        # observer wiring as FedAvgAPI.run_round
+        self.timer.begin_round(round_idx)
+        if self._obs is not None:
+            self._obs.round_begin(round_idx)
+        # as FedAvgAPI.run_round: was the device starved meanwhile?
+        with self.timer.starved_probe(jax.tree.leaves(self.variables)[0]):
+            idxs, (xd, yd, maskd, keysd, wd) = self._host_round_inputs(
+                round_idx)
         decayed = self.config.train.lr_decay_round != 1.0
         if self._obs is not None:
             # one-shot roofline probe (obs/perf.py): trace the sharded
@@ -666,6 +688,8 @@ class DistributedFedAvgAPI:
             self._obs.probe_round_flops(
                 lambda: analytic_flops(self._round_fn, *args),
                 source="analytic_conv_gn_jaxpr")
+        # the slots the round program runs, mesh and length padding and all
+        self.timer.count("rows_dispatched", xd.shape[0] * xd.shape[1])
         with self.timer.phase("dispatch"):
             if decayed:
                 # decayed builder takes the replicated round index as its
@@ -711,12 +735,23 @@ class DistributedFedAvgAPI:
                 "fused mesh rounds support the flat 'clients' mesh or a "
                 "named mesh_shape mesh; legacy model_parallel does not "
                 "compose with the fused scan")
+        self.timer.begin_round(r0)  # one span and one record a block
         if self._layout is not None or cfg.client_num_per_round != N:
             # named mesh: the GSPMD block scan serves full AND sampled
-            # participation (the resident full-federation fast path below
-            # is a shard_map program on the 'clients' axis only)
-            return self._run_block_fused(r0, rounds,
-                                         next_window=next_window)
+            # participation (the resident full-federation fast path is a
+            # shard_map program on the 'clients' axis only)
+            stats = self._run_block_fused(r0, rounds,
+                                          next_window=next_window)
+        else:
+            stats = self._run_resident_fused(r0, rounds)
+        self.timer.end_round(r0, extra={"rounds": rounds})
+        return stats
+
+    def _run_resident_fused(self, r0: int, rounds: int):
+        """Full participation on the flat mesh: the federation packed and
+        uploaded once, per-round keys derived in-scan."""
+        cfg = self.config
+        N = self.dataset.client_num
         if (getattr(self, "_fused_data", None) is None
                 or self._fused_data[0] is not self.dataset):
             padded, alive = self._pad_round(np.arange(N))
@@ -849,8 +884,8 @@ class DistributedFedAvgAPI:
             # per-round host boundary to record
             logging.warning(
                 "observability is on but train_fused dispatches whole "
-                "round blocks — no per-round flight records for fused "
-                "spans; use train() for per-round timelines")
+                "round blocks — one flight record a block, not a round, "
+                "for fused spans; use train() for per-round timelines")
         if cfg.comm_round <= 0:
             return self.history[-1] if self.history else {}
         freq = cfg.frequency_of_the_test
@@ -884,8 +919,7 @@ class DistributedFedAvgAPI:
             with self.timer.phase("device_wait"):
                 # ft: allow[FT003] eval-boundary sync, by design
                 jax.block_until_ready(self.variables)
-            with self.timer.phase("eval"):
-                test_stats = self._eval_global()
+            test_stats = self._eval_global()
             if test_stats is not None:
                 rec.update(_normalized(test_stats, "test"))
             self.history.append(rec)
@@ -931,8 +965,7 @@ class DistributedFedAvgAPI:
                 with self.timer.phase("device_wait"):
                     # ft: allow[FT003] eval-boundary sync, by design
                     jax.block_until_ready(self.variables)
-                with self.timer.phase("eval"):
-                    test_stats = self._eval_global()
+                test_stats = self._eval_global()
                 if test_stats is not None:
                     rec.update(_normalized(test_stats, "test"))
                 rec["wall_s"] = time.time() - t0  # as FedAvgAPI.train

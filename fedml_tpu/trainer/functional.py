@@ -225,6 +225,9 @@ def make_local_train(module, task: str, cfg: TrainConfig,
             lambda a: a.astype(jnp.float32)
             if jnp.issubdtype(a.dtype, jnp.floating) else a, tree)
 
+    # the scope names the trainer's operations in a device trace; it is
+    # location metadata and changes no instruction
+    @jax.named_scope("fedml.local_train")
     def local_train(variables, x, y, mask, rng, lr_scale=None):
         n_pad = x.shape[0]
         bsz = cfg.batch_size or n_pad
@@ -327,6 +330,7 @@ def make_eval(module, task: str, eval_batch_size: int = 512):
     head: TaskHead = TASK_HEADS[task]
     forward = make_forward(module)
 
+    @jax.named_scope("fedml.eval")
     def evaluate(variables, x, y, mask):
         n = x.shape[0]
         if n == 0:

@@ -27,6 +27,21 @@ The reference's only tracing is wall-clock log lines
   ``Observability.round_end(record=...)`` and the per-round ``perf``
   record (MFU, overlap frac, wire bytes/s) derives from exactly these
   deltas — the derivation never reads the live timer.
+- **Spans**: every ``phase()`` is also kept as an interval —
+  ``(name, thread, round open when it closed, t0, t1)`` on
+  ``time.perf_counter_ns()`` — in a second bounded ring (``spans()``, and
+  ``recent_spans()`` for every live timer of the process), listed in the
+  per-round record, and written as a ``jax.profiler.TraceAnnotation``
+  ``fedml.<name>``: under a profiler (``--profile_dir``, the anomaly
+  profiles, the benchmark's tracer) the program's host timeline sits on
+  the trace's own clock beside the device's operations, on the thread that
+  ran it. ``begin_round`` … ``end_round`` is the ``round`` span. Without a
+  profiler an annotation costs a fraction of a microsecond.
+- **The starved-device probe** (``starved_probe``): two non-blocking
+  ``is_ready()`` queries around a round's host-input half say whether the
+  device ran out of queued work while the host prepared the next round —
+  phases ``device_starved`` (lower bound) and ``device_starved_max``, with
+  no profiler, in every run.
 - ``profile`` — context manager around ``jax.profiler.trace`` emitting a
   TensorBoard-loadable trace directory when enabled, a no-op otherwise.
 """
@@ -34,10 +49,45 @@ The reference's only tracing is wall-clock log lines
 from __future__ import annotations
 
 import contextlib
+import itertools
 import threading
 import time
+import weakref
 from collections import defaultdict, deque
-from typing import Dict, Iterator, List, Optional
+from typing import Dict, Iterator, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
+
+#: ``(name, thread name, round index open when the span closed or None,
+#: t0, t1)``, the times on ``time.perf_counter_ns()``
+Span = Tuple[str, str, Optional[int], int, int]
+
+#: what a round closes (round, prefetch_wait, dispatch, produce, pack,
+#: upload, a starved interval; device_wait and eval every few rounds): the
+#: span ring holds ``ring_capacity`` rounds of them
+SPANS_PER_ROUND = 8
+
+#: the process's live timers, for ``recent_spans``
+_timers: "weakref.WeakSet[RoundTimer]" = weakref.WeakSet()
+_timers_lock = threading.Lock()
+
+
+def recent_spans() -> List[Span]:
+    """The spans still in the ring of every live ``RoundTimer`` of this
+    process, by start: the program's host timeline for a reader that has no
+    handle on the driver (the benchmark's ``idle_by_program_span``)."""
+    with _timers_lock:
+        timers = list(_timers)
+    return sorted((s for t in timers for s in t.spans()),
+                  key=lambda s: s[3])
+
+
+def _is_ready(leaf) -> bool:
+    """Whether the device has finished everything enqueued up to ``leaf``.
+    A host array (a model restored from a checkpoint) has nothing behind
+    it."""
+    ready = getattr(leaf, "is_ready", None)
+    return True if ready is None else ready()
 
 
 class RoundTimer:
@@ -53,25 +103,71 @@ class RoundTimer:
         #: schedules must not grow host memory; the flight log is the
         #: durable copy)
         self._rounds: deque = deque(maxlen=max(1, int(ring_capacity)))
-        #: (round_idx, t0, phase-totals snapshot, phase-counts snapshot,
-        #: counter snapshot) for the open round
+        #: closed spans, newest last, and how many were ever closed
+        self._spans: deque = deque(
+            maxlen=max(1, int(ring_capacity)) * SPANS_PER_ROUND)
+        self._spans_closed = 0
+        #: (round_idx, t0_ns, phase-totals snapshot, phase-counts snapshot,
+        #: counter snapshot, spans closed so far, the round's annotation)
+        #: for the open round
         self._open_round = None
         self._flight = None
+        with _timers_lock:
+            _timers.add(self)
 
     @contextlib.contextmanager
     def phase(self, name: str) -> Iterator[None]:
-        t0 = time.perf_counter()
+        """Time the block as phase ``name`` and keep it as a span."""
+        t0 = time.perf_counter_ns()
         try:
-            yield
+            with TraceAnnotation("fedml." + name):
+                yield
         finally:
-            self.add(name, time.perf_counter() - t0)
+            self._close_span(name, t0, time.perf_counter_ns())
+
+    def _close_span(self, name: str, t0: int, t1: int) -> None:
+        thread = threading.current_thread().name
+        with self._lock:
+            self.totals[name] += (t1 - t0) * 1e-9
+            self.counts[name] += 1
+            open_round = self._open_round
+            self._spans.append((name, thread,
+                                open_round[0] if open_round else None,
+                                t0, t1))
+            self._spans_closed += 1
 
     def add(self, name: str, seconds: float) -> None:
-        """Charge ``seconds`` to a phase directly (pre-measured time, e.g.
-        the prefetcher's ``prefetch_wait``)."""
+        """Charge ``seconds`` to a phase directly (time measured elsewhere,
+        e.g. the scheduler's gate waits): totals only, no span."""
         with self._lock:
             self.totals[name] += seconds
             self.counts[name] += 1
+
+    @contextlib.contextmanager
+    def starved_probe(self, leaf) -> Iterator[None]:
+        """Around the host-input half of a round (``_host_round_inputs``).
+        ``leaf`` is one array of the previous round's output: not ready
+        until the device has run everything enqueued so far. Ready when the
+        block opens: the device had nothing queued for the whole of it,
+        charged to ``device_starved``. Ready only when it closes: the device
+        ran dry somewhere inside, charged to ``device_starved_max``. Not
+        ready then either: the host's work was hidden, nothing charged.
+        ``device_starved`` is a lower bound and the two together an upper
+        bound on the device time this block cost; ``starved_rounds`` counts
+        the rounds charged either way."""
+        idle_at_open = _is_ready(leaf)
+        t0 = time.perf_counter_ns()
+        yield
+        t1 = time.perf_counter_ns()
+        if _is_ready(leaf):
+            self._close_span("device_starved" if idle_at_open
+                             else "device_starved_max", t0, t1)
+            self.count("starved_rounds")
+
+    def spans(self) -> List[Span]:
+        """The span ring, oldest first."""
+        with self._lock:
+            return list(self._spans)
 
     def count(self, name: str, n: int = 1) -> None:
         """Bump an event counter (e.g. ``prefetch_hit``/``prefetch_miss``,
@@ -130,10 +226,15 @@ class RoundTimer:
         ``end_round`` can attribute the deltas to this round. An
         already-open round is silently superseded (a crashed server's
         unfinished round must not poison its successor's record)."""
+        t0 = time.perf_counter_ns()
+        annotation = TraceAnnotation("fedml.round")
+        annotation.__enter__()
         with self._lock:
-            self._open_round = (int(round_idx), time.perf_counter(),
-                                dict(self.totals), dict(self.counts),
-                                dict(self.counters))
+            superseded, self._open_round = self._open_round, (
+                int(round_idx), t0, dict(self.totals), dict(self.counts),
+                dict(self.counters), self._spans_closed, annotation)
+        if superseded is not None:
+            superseded[-1].__exit__(None, None, None)
 
     def end_round(self, round_idx: int,
                   extra: Optional[Dict] = None) -> Optional[Dict]:
@@ -145,14 +246,15 @@ class RoundTimer:
         partially-wired drivers degrade to no record instead of a wrong
         one. ``extra`` keys (cohort, reported, partial, ...) are merged
         into the record."""
+        thread = threading.current_thread().name
         with self._lock:
-            if self._open_round is None or self._open_round[0] != int(
-                    round_idx):
-                self._open_round = None
+            open_round, self._open_round = self._open_round, None
+            if open_round is None:
                 return None
-            _, t0, tot0, cnt0, ctr0 = self._open_round
-            self._open_round = None
-            duration = time.perf_counter() - t0
+            idx, t0, tot0, cnt0, ctr0, closed0, annotation = open_round
+            if idx != int(round_idx):
+                annotation.__exit__(None, None, None)
+                return None
             phases = {}
             for k in sorted(self.totals):
                 ds = self.totals[k] - tot0.get(k, 0.0)
@@ -164,9 +266,21 @@ class RoundTimer:
                 d = self.counters[k] - ctr0.get(k, 0)
                 if d:
                     counters[k] = d
-            rec = {"kind": "round", "round": int(round_idx),
-                   "duration_s": round(duration, 6), "phases": phases,
-                   "counters": counters,
+            # what closed while the round was open, on any thread, oldest
+            # first, from the round's start in ns; the round itself is
+            # [0, duration_s]
+            inside = min(self._spans_closed - closed0, len(self._spans))
+            spans = [[name, who, s0 - t0, s1 - t0] for name, who, _, s0, s1
+                     in itertools.islice(reversed(self._spans), inside)]
+            spans.reverse()
+            # the round span closes here, so it holds this bookkeeping too
+            annotation.__exit__(None, None, None)
+            t1 = time.perf_counter_ns()
+            self._spans.append(("round", thread, idx, t0, t1))
+            self._spans_closed += 1
+            rec = {"kind": "round", "round": idx,
+                   "duration_s": round((t1 - t0) * 1e-9, 6),
+                   "phases": phases, "spans": spans, "counters": counters,
                    "gauges": {k: self.gauges[k]
                               for k in sorted(self.gauges)}}
             if extra:

@@ -247,6 +247,14 @@ class Router {
       // open==true the instant it is stored) cannot overtake the buffered
       // frames — per-sender FIFO is preserved across the reconnect
       std::unique_lock<std::mutex> lk(mu_);
+      if (!running_.load()) {
+        // Stop() has cleared running_ and may already have walked
+        // clients_: a connection registered now would never be shut down
+        // and Stop() would wait on this reader forever
+        lk.unlock();
+        ::close(fd);
+        return;
+      }
       auto& slot = clients_[rank];
       if (!slot) slot = std::make_shared<Client>();
       if (slot->open.load()) {  // duplicate rank: refuse the newcomer

@@ -58,10 +58,10 @@ class TestRoundPrefetcher:
 
         pf = RoundPrefetcher(produce, depth=2, name="t-seq")
         try:
-            out0, _, hit0 = pf.get(0)
+            out0, hit0 = pf.get(0)
             assert (out0, hit0) == (0, False)  # nothing speculated yet
             for r in (1, 2, 3):
-                out, _, hit = pf.get(r)
+                out, hit = pf.get(r)
                 assert out == r * 10 and hit
             stats = pf.stats()
             assert stats["hits"] == 3 and stats["misses"] == 1
@@ -75,9 +75,9 @@ class TestRoundPrefetcher:
         pf = RoundPrefetcher(lambda r: r, depth=2)
         try:
             pf.get(0)
-            out, _, hit = pf.get(7)  # resume at an arbitrary round
+            out, hit = pf.get(7)  # resume at an arbitrary round
             assert out == 7 and not hit
-            out, _, hit = pf.get(8)  # stream re-aimed at 7's successors
+            out, hit = pf.get(8)  # stream re-aimed at 7's successors
             assert out == 8 and hit
         finally:
             pf.close()
@@ -111,7 +111,7 @@ class TestRoundPrefetcher:
             while len(produced) < 3 and time.time() < deadline:
                 time.sleep(0.01)
             pf.invalidate()
-            out, _, hit = pf.get(1)
+            out, hit = pf.get(1)
             assert out == 1 and not hit  # slot was dropped, not reused
             assert pf.stats()["invalidated"] >= 1
         finally:
@@ -136,7 +136,7 @@ class TestRoundPrefetcher:
         pf = RoundPrefetcher(lambda r: r * 2, depth=2)
         pf.get(0)
         pf.close()
-        out, _, hit = pf.get(1)
+        out, hit = pf.get(1)
         assert out == 2 and not hit
 
     def test_upcoming_hint_overrides_prediction(self):
@@ -144,7 +144,7 @@ class TestRoundPrefetcher:
         pf = RoundPrefetcher(lambda r: r, depth=2)
         try:
             pf.get(0, upcoming=[7])
-            out, _, hit = pf.get(7)
+            out, hit = pf.get(7)
             assert out == 7 and hit
         finally:
             pf.close()
