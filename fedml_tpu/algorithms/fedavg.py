@@ -37,27 +37,78 @@ from fedml_tpu.core.sampling import (DEVICE_SAMPLE_SENTINEL, eval_subsample,
                                      round_keys, sample_clients)
 from fedml_tpu.data.base import FederatedDataset
 from fedml_tpu.trainer.functional import (TrainConfig, make_eval,
-                                          make_local_train, round_lr_scale)
+                                          make_local_train, real_batches,
+                                          round_lr_scale)
 
 #: per-round heartbeat for long host loops (the eval records land only every
 #: frequency_of_the_test rounds, which leaves multi-minute CPU rounds
 #: invisible); scoped to its own logger so callers can silence it alone
 _progress_log = logging.getLogger("fedml_tpu.progress")
-def make_vmapped_body(local_train):
+
+#: clients a tier of a ragged cohort holds (see ``make_vmapped_body``);
+#: chosen once on the chip, PERF.md section 6 "PR 27" has the sweep
+TIER_CLIENTS = 32
+
+
+def cohort_tiers(clients: int, tier_clients: Optional[int]) -> int:
+    """How many tiers the round body cuts a cohort of ``clients`` into: as
+    many whole tiers of ``tier_clients`` as it holds if those are at least
+    two and leave nobody over, else 1 (the untiered body)."""
+    if not tier_clients or clients % tier_clients:
+        return 1
+    return max(1, clients // tier_clients)  # an empty cohort: one, too
+
+
+def make_vmapped_body(local_train, tier_clients: Optional[int] = None,
+                      train: Optional[TrainConfig] = None):
     """vmap local training over the client axis and sum stats — the shared
     round body every FedAvg-family algorithm composes with its own
     aggregation rule. ``lr_scale`` (optional scalar, broadcast to every
     client) applies TrainConfig.lr_decay_round's per-round schedule; None
-    traces the identical constant-LR program as before."""
+    traces the identical constant-LR program as before.
 
-    def body(variables, x, y, mask, keys, lr_scale=None):
+    ``tier_clients`` (with ``train``, the config ``local_train`` was built
+    from) is for ragged federations: a cohort that ``cohort_tiers`` cuts
+    into T > 1 equal slices of the client axis trains them one after
+    another inside the same program, each tier's step loop ending at the
+    last real batch of its longest client (a bound read from ``mask`` on
+    the device, so the shapes and the compiled program do not depend on the
+    round). The batches left out are the pure-padding ones ``local_train``
+    gates into no-ops, so the result is exact in any client order; packed
+    by size (``FedAvgAPI._size_ordered``) the short tiers stop early."""
+
+    def train_clients(variables, x, y, mask, keys, lr_scale, n_steps=None):
         # lr_scale=None traces the identical constant-LR program
         # (local_train skips the multiply at trace time), so one vmap
-        # covers both the scheduled and unscheduled paths
-        stacked, stats = jax.vmap(
+        # covers both the scheduled and unscheduled paths; n_steps=None
+        # likewise keeps the whole-length scan
+        return jax.vmap(
             lambda v, xc, yc, mc, kc: local_train(
-                v, xc, yc, mc, kc, lr_scale=lr_scale),
+                v, xc, yc, mc, kc, lr_scale=lr_scale, n_steps=n_steps),
             in_axes=(None, 0, 0, 0, 0))(variables, x, y, mask, keys)
+
+    def body(variables, x, y, mask, keys, lr_scale=None):
+        tiers = cohort_tiers(x.shape[0], tier_clients)
+        if tiers == 1:
+            stacked, stats = train_clients(variables, x, y, mask, keys,
+                                           lr_scale)
+        else:
+            def tier(inp):
+                xt, yt, mt, kt = inp
+                # unbatched under the vmap: its loop stays one ``while``
+                n_steps = jnp.max(jax.vmap(
+                    lambda m: real_batches(m, train))(mt))
+                return train_clients(variables, xt, yt, mt, kt, lr_scale,
+                                     n_steps)
+
+            def split(a):
+                return a.reshape((tiers, a.shape[0] // tiers) + a.shape[1:])
+
+            def join(a):
+                return a.reshape((a.shape[0] * a.shape[1],) + a.shape[2:])
+
+            stacked, stats = jax.tree.map(join, jax.lax.map(
+                tier, jax.tree.map(split, (x, y, mask, keys))))
         totals = jax.tree.map(lambda s: jnp.sum(s, axis=0), stats)
         return stacked, totals
 
@@ -145,7 +196,15 @@ class FedAvgAPI:
         from fedml_tpu.trainer.functional import validate_accum_steps
         validate_accum_steps(cfg, dataset.train_data_local_num_dict)
         self._local_train = make_local_train(module, task, cfg)
-        self._vmapped_body = make_vmapped_body(self._local_train)
+        # tiers engage where they can save steps: clients that differ in
+        # their number of batches (and a cohort of at least two tiers,
+        # which the body reads from its input's shape)
+        ragged = cfg.batch_size and len(
+            {-(-n // cfg.batch_size)
+             for n in dataset.train_data_local_num_dict.values()}) > 1
+        self._tier_clients = TIER_CLIENTS if ragged else None
+        self._vmapped_body = make_vmapped_body(
+            self._local_train, self._tier_clients, cfg)
         from fedml_tpu.utils import on_tpu
         if aggregate_hook is not None:
             hook = aggregate_hook
@@ -221,6 +280,32 @@ class FedAvgAPI:
             self._obs.bind_timer(self.timer)
 
     # -- one round ---------------------------------------------------------
+    def _size_ordered(self, idxs, dataset):
+        """The cohort in the order it is packed: longest client first where
+        the round body runs it in tiers (so each tier's loop ends early),
+        as sampled otherwise. Keys and weights follow the client, not the
+        slot, so the order changes only the mean's summation order."""
+        if cohort_tiers(len(idxs), self._tier_clients) == 1:
+            return idxs
+        idxs = np.asarray(idxs)
+        sizes = dataset.train_data_local_num_dict
+        return idxs[np.argsort([-sizes[int(c)] for c in idxs],
+                               kind="stable")]
+
+    def _rows_stepped(self, idxs, n_pad: int) -> int:
+        """The rows the round program steps through, padding and all: every
+        slot's padded length, or per tier its clients x its longest
+        client's batches x the batch size (what the body's bound comes to,
+        reckoned from the sizes the packer holds)."""
+        tiers = cohort_tiers(len(idxs), self._tier_clients)
+        if tiers == 1:
+            return len(idxs) * n_pad
+        bsz = self.config.train.batch_size
+        sizes = self.dataset.train_data_local_num_dict
+        steps = np.array([-(-sizes[int(c)] // bsz)
+                          for c in idxs]).reshape(tiers, -1).max(axis=1)
+        return int(steps.sum()) * (len(idxs) // tiers) * bsz
+
     def _pack_cohort(self, idxs, dataset=None):
         """Cache-free pack + upload of one sampled cohort (thread-safe: no
         shared mutable state — the prefetcher worker calls this
@@ -246,9 +331,10 @@ class FedAvgAPI:
         the caller's identity check)."""
         ds = self.dataset
         with self.timer.phase("produce"):
-            idxs = sample_clients(round_idx, ds.client_num,
-                                  self.config.client_num_per_round,
-                                  delete_client=self.delete_client)
+            idxs = self._size_ordered(
+                sample_clients(round_idx, ds.client_num,
+                               self.config.client_num_per_round,
+                               delete_client=self.delete_client), ds)
             xd, yd, maskd, wd = self._pack_cohort(idxs, dataset=ds)
             _, keys, agg_key = round_keys(
                 self._base_key, round_idx,
@@ -259,9 +345,10 @@ class FedAvgAPI:
         """Host side of a round: seeded sampling, pad-and-mask packing,
         per-client keys. Shared by all FedAvg-family algorithms."""
         cfg = self.config
-        idxs = sample_clients(round_idx, self.dataset.client_num,
-                              cfg.client_num_per_round,
-                              delete_client=self.delete_client)
+        idxs = self._size_ordered(
+            sample_clients(round_idx, self.dataset.client_num,
+                           cfg.client_num_per_round,
+                           delete_client=self.delete_client), self.dataset)
         # key holds a strong reference to the dataset object (mid-run swaps,
         # e.g. escalating a poisoning attack, must invalidate — and holding
         # the reference prevents CPython id-reuse false hits); cache only
@@ -392,8 +479,8 @@ class FedAvgAPI:
                                        keys, weights, agg_key,
                                        jnp.uint32(round_idx)),
                 source="analytic_conv_gn_jaxpr")
-        # the slots the round program runs, padding and all
-        self.timer.count("rows_dispatched", x.shape[0] * x.shape[1])
+        self.timer.count("rows_dispatched",
+                         self._rows_stepped(idxs, x.shape[1]))
         with self.timer.phase("dispatch"):
             self.variables, stats = self._round_fn(self.variables, x, y,
                                                    mask, keys, weights,
@@ -511,6 +598,12 @@ class FusedRounds:
 
     Stats come back stacked ``[R, ...]`` per scan, so per-round local-loss
     trajectories survive fusion.
+
+    The fused drivers pack cohorts as sampled. Where the host loop packs a
+    ragged cohort by size for ``make_vmapped_body``'s tiers, every client's
+    model is still the same to the bit, but the weighted mean sums them in
+    another order (last bits of a float32), and the fused tiers, exact in
+    any order, stop later than sorted ones would.
     """
 
     def __init__(self, api: FedAvgAPI, device_sampling: bool = False):

@@ -73,9 +73,12 @@ METRICS: Dict[str, Dict[str, str]] = {
                          "idle (charged to device_starved or "
                          "device_starved_max)"),
     "rows_dispatched": _m(KIND_COUNTER, "round pipeline",
-                          "client slots x padded length of every round "
-                          "program dispatched: the rows the device runs, "
-                          "mesh and length padding included"),
+                          "rows every dispatched round program steps "
+                          "through, padding included: client slots x padded "
+                          "length (mesh padding too), or where the sim "
+                          "driver runs a ragged cohort in tiers the sum "
+                          "over tiers of clients x the longest client's "
+                          "batches x batch size"),
     # -- prefetch counters (parallel/prefetch.py) --------------------------
     "prefetch_hit": _m(KIND_COUNTER, "prefetch",
                        "round consumed a speculatively packed cohort"),

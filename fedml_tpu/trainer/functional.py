@@ -7,7 +7,10 @@ DataLoader batches). Here one client's whole local-training pass —
 over a precomputed (epoch-shuffled) index array of padded batches, so XLA
 compiles it into one fused device program. Under ``jax.vmap`` it trains every
 sampled client simultaneously (standalone simulation); under ``shard_map`` it
-becomes the per-shard body of the distributed SPMD round.
+becomes the per-shard body of the distributed SPMD round. A caller that
+knows how far its clients' real batches reach (the sim driver's tiers of a
+ragged cohort, algorithms/fedavg.py) passes that as ``n_steps`` and gets the
+same steps from a loop that stops there.
 
 Data layout per client: flat padded arrays ``x: [n_pad, ...]``, ``y``,
 ``mask: [n_pad]`` with ``n_pad`` a multiple of the batch size; the mask
@@ -190,6 +193,21 @@ def make_batch_schedule(n_pad: int, epochs: int, bsz: int, shuffle: bool,
     return batch_idx, step_keys.reshape(epochs * nb)
 
 
+def real_batches(mask, cfg: TrainConfig):
+    """How many leading batches of each epoch of ``make_batch_schedule``
+    hold a real row of this client; every later batch is pure padding, a
+    step ``local_train`` gates into a no-op. A shuffled schedule sorts the
+    padding rows last, so the real rows fill the first ``ceil(real / bsz)``
+    batches; the identity order reaches as far as the last real row."""
+    bsz = cfg.batch_size or mask.shape[0]
+    real = mask > 0
+    if cfg.shuffle:
+        rows = jnp.sum(real)
+    else:
+        rows = jnp.max(jnp.where(real, jnp.arange(1, mask.shape[0] + 1), 0))
+    return (rows + bsz - 1) // bsz
+
+
 def make_local_train(module, task: str, cfg: TrainConfig,
                      grad_sync_axes: tuple = ()):
     """Build ``local_train(variables, x, y, mask, rng) -> (variables, stats)``.
@@ -203,6 +221,12 @@ def make_local_train(module, task: str, cfg: TrainConfig,
     sharded over inside a ``shard_map`` (e.g. ('seq',) for sequence-parallel
     clients): per-step loss terms and gradients are psum'd over them so
     every shard takes the identical optimizer step.
+
+    ``local_train(..., n_steps=k)`` (a traced scalar, the same for every
+    client of a ``vmap``) runs only the first ``k`` batches of each epoch:
+    exact whenever ``k >= real_batches(mask, cfg)``, because the batches it
+    leaves out are the gated no-ops. Without it the loop is the ``scan``
+    over all ``n_pad // batch_size`` batches.
     """
     from fedml_tpu.utils import on_tpu
 
@@ -228,7 +252,7 @@ def make_local_train(module, task: str, cfg: TrainConfig,
     # the scope names the trainer's operations in a device trace; it is
     # location metadata and changes no instruction
     @jax.named_scope("fedml.local_train")
-    def local_train(variables, x, y, mask, rng, lr_scale=None):
+    def local_train(variables, x, y, mask, rng, lr_scale=None, n_steps=None):
         n_pad = x.shape[0]
         bsz = cfg.batch_size or n_pad
         # accum_steps divisibility cannot be checked here: only REAL
@@ -315,8 +339,31 @@ def make_local_train(module, task: str, cfg: TrainConfig,
                         colls)
             return (params, colls, opt_state), stats
 
-        (params, colls, _), stats = jax.lax.scan(
-            step, init, (batch_idx, step_keys))
+        if n_steps is None:
+            (params, colls, _), stats = jax.lax.scan(
+                step, init, (batch_idx, step_keys))
+        else:
+            # a traced bound makes this a ``while`` whose predicate is a
+            # scalar even under ``vmap``; iteration i is batch i % n_steps
+            # of epoch i // n_steps, the scan's batch with the scan's key
+            nb = n_pad // bsz
+            n_steps = jnp.minimum(n_steps, nb).astype(jnp.int32)
+
+            def bounded_step(i, carry):
+                state, stats = carry
+                at = (i // n_steps) * nb + i % n_steps
+                state, new = step(state, (batch_idx[at], step_keys[at]))
+                return state, jax.tree.map(lambda s, v: s.at[at].set(v),
+                                           stats, new)
+
+            # the scan's stacked per-step stats, zero where no step ran
+            # (what a pure-padding batch reads), so their sum is the
+            # scan's to the bit
+            zeros = jax.tree.map(
+                lambda s: jnp.zeros((cfg.epochs * nb,) + s.shape, s.dtype),
+                jax.eval_shape(step, init, (batch_idx[0], step_keys[0]))[1])
+            (params, colls, _), stats = jax.lax.fori_loop(
+                0, cfg.epochs * n_steps, bounded_step, (init, zeros))
         total = jax.tree.map(lambda s: jnp.sum(s, axis=0), stats)
         return {"params": params, **colls}, total
 
