@@ -115,6 +115,41 @@ def make_vmapped_body(local_train, tier_clients: Optional[int] = None,
     return body
 
 
+def make_folded_body(local_train, interpret: bool = False):
+    """The round body for models a cohort cannot hold a copy each of: the
+    clients train *one after another* inside the one round program (a
+    ``lax.scan`` over the client axis), every one from the global model
+    with ``local_train``'s own loop, and each result is folded into a
+    running float32 sum with weight ``n_k / sum n`` by the Pallas kernel
+    that updates the sum in place (``ops/aggregate.py::tree_fold_pallas``).
+    After the last client the sum is the FedAvg mean, so the body returns
+    ``(new variables, stat totals)`` - aggregation included, where
+    ``make_vmapped_body`` hands back the stacked clients. The device holds
+    the global model, the sum, one training client and its gradients,
+    whatever the cohort."""
+    from fedml_tpu.ops.aggregate import tree_fold_pallas
+
+    def body(variables, x, y, mask, keys, weights, lr_scale=None):
+        share = weights.astype(jnp.float32)
+        share = share / jnp.sum(share)
+
+        def client(acc, inp):
+            xc, yc, mc, kc, wc = inp
+            result, stats = local_train(variables, xc, yc, mc, kc,
+                                        lr_scale=lr_scale)
+            return tree_fold_pallas(acc, result, wc,
+                                    interpret=interpret), stats
+
+        zeros = jax.tree.map(lambda v: jnp.zeros(v.shape, jnp.float32),
+                             variables)
+        acc, stats = jax.lax.scan(client, zeros, (x, y, mask, keys, share))
+        new_vars = jax.tree.map(lambda a, v: a.astype(v.dtype), acc,
+                                variables)
+        return new_vars, jax.tree.map(lambda s: jnp.sum(s, axis=0), stats)
+
+    return body
+
+
 def _normalized(stats, prefix: str) -> Dict[str, float]:
     """Stat sums -> {prefix}_{acc,loss,total} means (+precision/recall)."""
     total = max(1.0, float(stats["count"]))
@@ -163,6 +198,13 @@ class FedAvgConfig:
     # participation — full participation already reuses the resident
     # _pack_cache cohort.
     prefetch_depth: int = 2
+    # fold the cohort client by client (make_folded_body) instead of
+    # training it under one vmap and averaging the stacked results: for
+    # models whose copies a cohort cannot hold side by side. Peak device
+    # memory is then four copies of the model and one client's
+    # activations, whatever the cohort; clients run one after another.
+    # Not for aggregate_hook users, whose hook reads the stacked clients.
+    fold_clients: bool = False
     # observability (fedml_tpu/obs): directory for the flight recorder's
     # per-round timeline (flight_rank0.jsonl) + anomaly-armed one-shot
     # profiles. None (default) = off; on, it is a pure observer —
@@ -226,6 +268,21 @@ class FedAvgAPI:
                                    round_lr_scale(cfg, round_idx))
             new_vars = hook(variables, stacked, weights, agg_key)
             return new_vars, totals
+
+        if self.config.fold_clients:
+            if aggregate_hook is not None:
+                raise ValueError(
+                    "fold_clients folds every client into a running sum as "
+                    "it finishes; an aggregate_hook reads the stacked "
+                    "clients, which the folded round never holds")
+            folded = make_folded_body(self._local_train,
+                                      interpret=not on_tpu())
+
+            def round_fn(variables, x, y, mask, keys, weights,  # noqa: F811
+                         agg_key, round_idx):
+                del agg_key  # the plain weighted mean draws nothing
+                return folded(variables, x, y, mask, keys, weights,
+                              round_lr_scale(cfg, round_idx))
 
         # unjitted round body, shared with FusedRounds so the fused and
         # host paths cannot diverge semantically
@@ -479,8 +536,13 @@ class FedAvgAPI:
                                        keys, weights, agg_key,
                                        jnp.uint32(round_idx)),
                 source="analytic_conv_gn_jaxpr")
-        self.timer.count("rows_dispatched",
-                         self._rows_stepped(idxs, x.shape[1]))
+        rows = self._rows_stepped(idxs, x.shape[1])
+        self.timer.count("rows_dispatched", rows)
+        if x.ndim == 3 and jnp.issubdtype(x.dtype, jnp.integer):
+            # rows of token ids: the positions the round steps through
+            self.timer.count("tokens_dispatched", rows * x.shape[2])
+        if self.config.fold_clients:
+            self.timer.count("clients_folded", len(idxs))
         with self.timer.phase("dispatch"):
             self.variables, stats = self._round_fn(self.variables, x, y,
                                                    mask, keys, weights,

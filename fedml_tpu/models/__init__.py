@@ -48,6 +48,13 @@ def create_model(model_name: str, output_dim: int = 10, **kw):
     if model_name == "transformer":
         from fedml_tpu.models.transformer import TransformerLM
         return TransformerLM(vocab_size=output_dim, **kw)
+    if model_name == "sambay":
+        # Phi-4-mini-flash-reasoning's hybrid decoder; output_dim is the
+        # rows of the (tied) embedding held here
+        from fedml_tpu.models.sambay import SambaYLM
+        if "layer_ids" in kw:
+            kw = {**kw, "layer_ids": tuple(kw["layer_ids"])}
+        return SambaYLM(vocab_size=output_dim, **kw)
     if model_name in ("vgg11", "vgg13", "vgg16", "vgg19"):
         from fedml_tpu.models.vgg import VGG
         return VGG(arch=model_name, num_classes=output_dim, **kw)

@@ -79,6 +79,14 @@ METRICS: Dict[str, Dict[str, str]] = {
                           "driver runs a ragged cohort in tiers the sum "
                           "over tiers of clients x the longest client's "
                           "batches x batch size"),
+    "tokens_dispatched": _m(KIND_COUNTER, "round pipeline",
+                            "where a row is a sequence of token ids: "
+                            "rows_dispatched x the row's positions, added "
+                            "at every dispatch of the sim driver"),
+    "clients_folded": _m(KIND_COUNTER, "round pipeline",
+                         "clients a folded round (FedAvgConfig.fold_clients) "
+                         "trained one after another and folded into the "
+                         "running sum, added at every dispatch"),
     # -- prefetch counters (parallel/prefetch.py) --------------------------
     "prefetch_hit": _m(KIND_COUNTER, "prefetch",
                        "round consumed a speculatively packed cohort"),

@@ -24,7 +24,8 @@ CPU mesh, and a pure-jnp reference used both as the CPU fallback and as the
 test oracle.
 """
 
-from fedml_tpu.ops.aggregate import (tree_weighted_mean_pallas,
+from fedml_tpu.ops.aggregate import (fold_weighted, tree_fold_pallas,
+                                     tree_weighted_mean_pallas,
                                      weighted_mean_flat,
                                      weighted_mean_flat_reference)
 from fedml_tpu.ops.autotune import (AttentionDecision, AutotuneCache,
@@ -39,6 +40,8 @@ __all__ = [
     "weighted_mean_flat",
     "weighted_mean_flat_reference",
     "tree_weighted_mean_pallas",
+    "fold_weighted",
+    "tree_fold_pallas",
     "quantize_int8",
     "dequantize_int8",
     "quantize_tree",

@@ -7,7 +7,14 @@ MXU wants — so the whole aggregation is one kernel pass over HBM instead of a
 per-leaf Python loop. The kernel tiles D into VMEM-sized lanes and keeps the
 tiny weight vector resident.
 
-CPU/test path: ``interpret=True`` runs the same kernel through the Pallas
+Where a copy of the model is gigabytes the clients cannot be stacked: they
+train one after another and each result is *folded* into a running float32
+sum, ``acc += (n_i / sum n) * w_i`` (``tree_fold_pallas``). That kernel
+updates the sum in place (``input_output_aliases``), leaf by leaf in the
+leaf's own shape, so a fold reads the sum and the client once and writes
+the sum once and nothing is concatenated, padded or copied around it.
+
+CPU/test path: ``interpret=True`` runs the same kernels through the Pallas
 interpreter; ``weighted_mean_flat_reference`` is the jnp oracle.
 """
 
@@ -18,6 +25,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 # lane tile for the parameter axis; multiple of 128 (TPU lane width) and
 # small enough that [C, TILE_D] fits VMEM for any realistic clients-per-round
@@ -101,3 +109,59 @@ def tree_weighted_mean_pallas(stacked_tree, weights, *,
         out.append(mean[off:off + size].reshape(shape).astype(leaf.dtype))
         off += size
     return jax.tree.unflatten(treedef, out)
+
+
+# -- the in-place fold ------------------------------------------------------------
+
+#: elements of one block of the fold (float32: 1 MiB; the sum, the client
+#: and the result double-buffered are 6 MiB of VMEM)
+_FOLD_BLOCK = 1 << 18
+#: widest block along the lane axis
+_FOLD_LANES = 2048
+
+
+def _fold_kernel(w_ref, acc_ref, x_ref, out_ref):
+    out_ref[:] = acc_ref[:] + w_ref[0, 0] * x_ref[:].astype(jnp.float32)
+
+
+def _fold_block(rows: int, cols: int):
+    """The block of a ``[rows, cols]`` leaf (``rows % 8 == 0``, ``cols % 128
+    == 0``): the widest multiple of 128 lanes up to ``_FOLD_LANES`` that
+    divides ``cols``, and as many rows as ``_FOLD_BLOCK`` allows."""
+    lanes = max(n for n in range(128, min(cols, _FOLD_LANES) + 1, 128)
+                if cols % n == 0)
+    return min(rows, max(8, _FOLD_BLOCK // lanes // 8 * 8)), lanes
+
+
+def fold_weighted(acc: jax.Array, x: jax.Array, weight, *,
+                  interpret: bool = False) -> jax.Array:
+    """``acc + weight * x`` for one leaf, ``acc`` float32 and ``weight`` a
+    scalar. A matrix the TPU tiles without padding (rows a multiple of 8,
+    columns of 128) goes through the Pallas kernel, which writes the result
+    over ``acc``; anything else - vectors, a handful of narrow matrices - is
+    a sliver of the model and is left to XLA."""
+    weight = jnp.asarray(weight, jnp.float32)
+    if acc.ndim != 2 or acc.shape[0] % 8 or acc.shape[1] % 128:
+        return acc + weight * x.astype(jnp.float32)
+    rows, cols = acc.shape
+    block = _fold_block(rows, cols)
+    tile = pl.BlockSpec(block, lambda i, j: (i, j))
+    return pl.pallas_call(
+        _fold_kernel,
+        grid=(pl.cdiv(rows, block[0]), cols // block[1]),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), tile, tile],
+        out_specs=tile,
+        out_shape=jax.ShapeDtypeStruct(acc.shape, jnp.float32),
+        input_output_aliases={1: 0},
+        interpret=interpret,
+    )(weight.reshape(1, 1), acc, x)
+
+
+@jax.named_scope("fedml.fold")
+def tree_fold_pallas(acc_tree, x_tree, weight, *, interpret: bool = False):
+    """Fold one client's model into the running float32 sum, leaf by leaf:
+    ``acc + weight * x``. ``weight`` is the client's share ``n_i / sum n``,
+    so after the last client the sum is the FedAvg mean."""
+    return jax.tree.map(
+        lambda a, x: fold_weighted(a, x, weight, interpret=interpret),
+        acc_tree, x_tree)

@@ -284,7 +284,7 @@ def make_local_train(module, task: str, cfg: TrainConfig,
                     out, new_vars = forward(
                         {"params": _to_compute(p), **_to_compute(colls)},
                         _to_compute(xb), True, key)
-                    out = out.astype(jnp.float32)
+                    out = _to_f32(out)
                     new_vars = _to_f32(new_vars)
                 else:
                     out, new_vars = forward({"params": p, **colls}, xb,
@@ -370,10 +370,19 @@ def make_local_train(module, task: str, cfg: TrainConfig,
     return local_train
 
 
+#: positions an evaluation batch holds at most where a row is a sequence of
+#: token ids: 512 rows of 2,048 positions would be a million positions'
+#: activations (and their logits) at once
+EVAL_BATCH_TOKENS = 8192
+
+
 def make_eval(module, task: str, eval_batch_size: int = 512):
     """Build ``evaluate(variables, x, y, mask) -> stat sums`` that scans fixed
     eval batches (deterministic mode, no dropout), the jittable analogue of
-    the reference's ``ModelTrainer.test`` loop (MyModelTrainer.py:51-96)."""
+    the reference's ``ModelTrainer.test`` loop (MyModelTrainer.py:51-96).
+    A batch is ``eval_batch_size`` rows, or where the rows are sequences of
+    token ids (integer ``[n, T]``) as many as hold ``EVAL_BATCH_TOKENS``
+    positions, if that is fewer."""
     head: TaskHead = TASK_HEADS[task]
     forward = make_forward(module)
 
@@ -388,6 +397,8 @@ def make_eval(module, task: str, eval_batch_size: int = 512):
             out, _ = forward(variables, dummy_x, False)
             return head(out, dummy_y, jnp.zeros((1,), jnp.float32))
         bsz = min(eval_batch_size, n)
+        if x.ndim == 2 and jnp.issubdtype(x.dtype, jnp.integer):
+            bsz = max(1, min(bsz, EVAL_BATCH_TOKENS // x.shape[1]))
         n_pad = ((n + bsz - 1) // bsz) * bsz
         pad = n_pad - n
         if pad:

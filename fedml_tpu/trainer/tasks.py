@@ -16,7 +16,7 @@ torch's reduction='mean' over the real examples in the batch.
 
 from __future__ import annotations
 
-from typing import Callable, Dict
+from typing import Callable, Dict, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -55,6 +55,70 @@ def nwp_head(logits: jnp.ndarray, targets: jnp.ndarray,
         "loss_sum": jnp.sum(per_tok * tok_mask),
         "count": jnp.sum(tok_mask),
         "correct_sum": jnp.sum(correct * tok_mask),
+    }
+
+
+class TiedHead(NamedTuple):
+    """What a language model with a tied output head hands its task head in
+    place of logits: the final hidden states ``[B, T, d]`` and the embedding
+    ``[V, d]`` whose rows score them. The head then forms the logits
+    ``hidden @ embedding.T`` in blocks of positions and never holds
+    ``[B, T, V]`` at once."""
+
+    hidden: jnp.ndarray
+    embedding: jnp.ndarray
+
+
+#: positions whose logits ``lm_rows_head`` holds at a time
+LOGIT_BLOCK = 512
+
+
+def _position_stats(logits, targets):
+    """(cross-entropy, top-1 hit) of every position; logits [..., V]."""
+    logits = logits.astype(jnp.float32)
+    per_tok = optax.softmax_cross_entropy_with_integer_labels(logits, targets)
+    return per_tok, (jnp.argmax(logits, -1) == targets).astype(jnp.float32)
+
+
+def lm_rows_head(out, targets: jnp.ndarray, mask: jnp.ndarray) -> Stats:
+    """Language modelling where the accounting unit is the *row*: one packed
+    sequence whose ``T`` positions all carry a target (no pad id). Per row
+    the mean cross-entropy over its targets; ``loss_sum`` sums the real
+    rows, ``count`` counts them, ``correct_sum`` sums their mean top-1
+    accuracy - all equal to the token means because every row has exactly
+    ``T`` targets, and in the unit the drivers count cohorts in.
+
+    ``out`` is ``[B, T, V]`` logits or a :class:`TiedHead`, for which the
+    logits are formed ``LOGIT_BLOCK`` positions at a time, each block
+    rematerialised, so neither pass holds more than one block of them."""
+    if isinstance(out, TiedHead):
+        hidden, embedding = out
+        rows, length, _ = hidden.shape
+        block = min(LOGIT_BLOCK, length)
+        pad = (-length) % block
+
+        def blocks(a):
+            a = jnp.pad(a, [(0, 0), (0, pad)] + [(0, 0)] * (a.ndim - 2))
+            a = a.reshape((rows, -1, block) + a.shape[2:])
+            return jnp.swapaxes(a, 0, 1)
+
+        @jax.checkpoint
+        def one(inp):
+            h, t = inp
+            return _position_stats(jnp.einsum("btd,vd->btv", h, embedding),
+                                   t)
+
+        def rejoin(a):
+            return jnp.swapaxes(a, 0, 1).reshape(rows, -1)[:, :length]
+
+        per_tok, correct = jax.tree.map(rejoin, jax.lax.map(
+            one, (blocks(hidden), blocks(targets))))
+    else:
+        per_tok, correct = _position_stats(out, targets)
+    return {
+        "loss_sum": jnp.sum(jnp.mean(per_tok, axis=-1) * mask),
+        "count": jnp.sum(mask),
+        "correct_sum": jnp.sum(jnp.mean(correct, axis=-1) * mask),
     }
 
 
@@ -122,6 +186,7 @@ def segmentation_focal_head(logits, targets, mask, gamma: float = 2.0,
 TASK_HEADS: Dict[str, TaskHead] = {
     "classification": classification_head,
     "nwp": nwp_head,
+    "lm_rows": lm_rows_head,
     "tag_prediction": tag_prediction_head,
     "segmentation": segmentation_head,
     "segmentation_focal": segmentation_focal_head,
