@@ -43,7 +43,7 @@ import logging
 import os
 import sys
 import time
-from typing import Dict, Sequence, Tuple
+from typing import Any, Dict, Sequence, Tuple
 
 ROOT = os.path.dirname(os.path.abspath(__file__))
 OUT_DIR = os.path.join(ROOT, "runs", "chip_smoke")
@@ -344,15 +344,16 @@ def attention_errors(got, ref) -> Tuple[float, float]:
             float(np.max(rows[:, ref.shape[1] // 16:])))
 
 
-def kernel_leg(*, clients: int, dims: Sequence[int], topk_frac: float,
-               attn_shape: Tuple[int, int, int, int],
+def kernel_leg(*, cohorts: Sequence[Tuple[int, Any]], dims: Sequence[int],
+               topk_frac: float, attn_shape: Tuple[int, int, int, int],
                block_grid: Sequence[Tuple[int, int]]) -> Dict:
     """Every Pallas kernel in ``fedml_tpu/ops`` against its reference, on
     this backend: compiled on tpu, interpreted on cpu — never a choice
     made here (``fedml_tpu.utils.on_tpu``).
 
-    ``dims`` are flat parameter counts for the aggregation / quantize /
-    top-k kernels (``clients`` rows for aggregation); ``attn_shape`` is
+    ``cohorts`` are (clients, a model's tree of shapes) pairs for the
+    stacked mean; ``dims`` are flat parameter counts for the quantize /
+    top-k kernels; ``attn_shape`` is
     (B, S, H, D) for flash attention, run forward and backward at every
     ``block_grid`` pair against the ``highest``-precision oracle, held to
     a multiple of the default-precision XLA attention's own error (see
@@ -361,7 +362,8 @@ def kernel_leg(*, clients: int, dims: Sequence[int], topk_frac: float,
     import jax.numpy as jnp
     import numpy as np
 
-    from fedml_tpu.ops.aggregate import (weighted_mean_flat,
+    from fedml_tpu.ops.aggregate import (mean_kernel_params,
+                                         tree_weighted_mean_pallas,
                                          weighted_mean_flat_reference)
     from fedml_tpu.ops.flash_attention import flash_attention
     from fedml_tpu.ops.quantize import BLOCK, dequantize_int8, quantize_int8
@@ -379,22 +381,44 @@ def kernel_leg(*, clients: int, dims: Sequence[int], topk_frac: float,
         out = jax.block_until_ready(fn(*args))
         return out, round(time.perf_counter() - t0, 3)
 
+    for clients, shapes in cohorts:
+        leaves, treedef = jax.tree.flatten(shapes)
+
+        @jax.jit
+        def make_cohort(key):
+            # parameter-scale values; integer sample counts as weights
+            k_w, *k_x = jax.random.split(key, len(leaves) + 1)
+            return (treedef.unflatten(
+                [0.05 * jax.random.normal(k, (clients,) + a.shape, a.dtype)
+                 for k, a in zip(k_x, leaves)]),
+                jax.random.randint(k_w, (clients,), 50, 150).astype(
+                    jnp.float32))
+
+        @jax.jit
+        def distance(stacked, got, weights):
+            # the oracle a leaf at a time: the stack may be gigabytes
+            wants = [weighted_mean_flat_reference(a.reshape(clients, -1),
+                                                  weights)
+                     for a in jax.tree.leaves(stacked)]
+            errs = [jnp.max(jnp.abs(b.reshape(-1) - want), initial=0.0)
+                    for b, want in zip(jax.tree.leaves(got), wants)]
+            return (jnp.max(jnp.stack(errs)), jnp.max(jnp.stack(
+                [jnp.max(jnp.abs(want), initial=0.0) for want in wants])))
+
+        stacked, weights = make_cohort(jax.random.key(clients))
+        got, first_s = timed(jax.jit(lambda s, w: tree_weighted_mean_pallas(
+            s, w, interpret=interpret)), stacked, weights)
+        err, scale = distance(stacked, got, weights)
+        kernel, xla = mean_kernel_params(shapes, clients)
+        checks.append(_check(
+            "aggregate.tree_weighted_mean_pallas", float(err),
+            AGG_TOL * float(scale), shape=[clients, kernel + xla],
+            kernel_params=kernel, first_call_s=first_s))
+        del stacked, got
+
     for d in dims:
         key = jax.random.key(d)
-        k_x, k_w, k_q = jax.random.split(key, 3)
-        # parameter-scale values; integer sample counts as weights
-        stacked = 0.05 * jax.random.normal(k_x, (clients, d), jnp.float32)
-        weights = jax.random.randint(k_w, (clients,), 50, 150).astype(
-            jnp.float32)
-        got, first_s = timed(lambda s, w: weighted_mean_flat(
-            s, w, interpret=interpret), stacked, weights)
-        want = weighted_mean_flat_reference(stacked, weights)
-        checks.append(_check(
-            "aggregate.weighted_mean_flat", _max_abs(got - want),
-            AGG_TOL * _max_abs(want), shape=[clients, d],
-            first_call_s=first_s))
-        del stacked, got, want
-
+        k_x, k_q = jax.random.split(key)
         x = 0.05 * jax.random.normal(k_x, (d,), jnp.float32)
         (q, scales), first_s = timed(lambda v, k: quantize_int8(
             v, k, interpret=interpret), x, k_q)
@@ -481,18 +505,17 @@ def kernel_leg(*, clients: int, dims: Sequence[int], topk_frac: float,
             "failures": failures}
 
 
-def param_count(model_name: str, classes: int, sample_shape) -> int:
-    """Parameter count of a zoo model from shapes alone (no device work)."""
+def model_shapes(model_name: str, classes: int, sample_shape, **kwargs):
+    """A zoo model's variables as shapes alone (no device work)."""
     import jax
     import jax.numpy as jnp
 
     from fedml_tpu.models import create_model
-    from fedml_tpu.utils.flops import count_params
 
-    model = create_model(model_name, output_dim=classes)
-    return count_params(jax.eval_shape(lambda: model.init(
+    model = create_model(model_name, output_dim=classes, **kwargs)
+    return jax.eval_shape(lambda: model.init(
         jax.random.key(0), jnp.zeros(sample_shape, jnp.float32),
-        train=False)))
+        train=False))
 
 
 def main() -> int:
@@ -557,10 +580,14 @@ def main() -> int:
         added_since_last_leg().values())
 
     _log("kernel leg")
+    from fedml_tpu.utils.flops import count_params
+    # the benchmark's two stacked cohorts (the published 7x7 stem)
+    resnet = model_shapes("resnet18_gn", 100, (1, 24, 24, 3),
+                          small_images=False)
+    cnn = model_shapes("cnn", 62, (1, 28, 28, 1))
     legs["kernels"] = kernel_leg(
-        clients=10,
-        dims=(param_count("resnet18_gn", 100, (1, 24, 24, 3)),
-              param_count("cnn", 62, (1, 28, 28, 1))),
+        cohorts=((104, resnet), (256, cnn)),
+        dims=(count_params(resnet), count_params(cnn)),
         topk_frac=0.01, attn_shape=(4, 2048, 4, 64),
         block_grid=DEFAULT_BLOCK_GRID)
     legs["kernels"]["cache_entries_added"] = sum(

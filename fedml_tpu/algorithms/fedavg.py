@@ -251,8 +251,9 @@ class FedAvgAPI:
         if aggregate_hook is not None:
             hook = aggregate_hook
         elif on_tpu():
-            # fused single-pass kernel over the whole [clients, params] stack
-            # instead of one reduction per leaf (fedml_tpu/ops/aggregate.py)
+            # the mean leaf by leaf, the wide leaves through a Pallas kernel
+            # that reads them as the trainer left them
+            # (fedml_tpu/ops/aggregate.py)
             from fedml_tpu.ops import tree_weighted_mean_pallas
 
             def hook(variables, stacked, weights, key):
@@ -315,6 +316,14 @@ class FedAvgAPI:
         self._prefetch = None
         from fedml_tpu.utils.tracing import RoundTimer
         self.timer = RoundTimer()
+        if (aggregate_hook is None and on_tpu()
+                and not self.config.fold_clients):
+            # how much of the model the stacked mean's kernel takes
+            from fedml_tpu.ops import mean_kernel_params
+            kernel, xla = mean_kernel_params(
+                self.variables, self.config.client_num_per_round)
+            self.timer.count("agg_kernel_params", kernel)
+            self.timer.count("agg_xla_params", xla)
         # virtualized populations (fedml_tpu/state/) front the per-client
         # shards with a tiered store; binding its counters here puts
         # state_cache_hits/misses/evictions + state_bytes_read/written on

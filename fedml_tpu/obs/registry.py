@@ -83,6 +83,15 @@ METRICS: Dict[str, Dict[str, str]] = {
                             "where a row is a sequence of token ids: "
                             "rows_dispatched x the row's positions, added "
                             "at every dispatch of the sim driver"),
+    "agg_kernel_params": _m(KIND_COUNTER, "round pipeline",
+                            "parameters of the model whose stacked mean the "
+                            "sim driver's Pallas kernel takes (leaves the "
+                            "TPU tiles without padding), counted once when "
+                            "the driver is built on a TPU"),
+    "agg_xla_params": _m(KIND_COUNTER, "round pipeline",
+                         "the rest of the model (vectors, narrow matrices), "
+                         "whose stacked mean is left to XLA; with "
+                         "agg_kernel_params the model's parameter count"),
     "clients_folded": _m(KIND_COUNTER, "round pipeline",
                          "clients a folded round (FedAvgConfig.fold_clients) "
                          "trained one after another and folded into the "

@@ -6,9 +6,9 @@ its comm payloads are full-precision pickled tensors (reference:
 fedml_core/distributed/communication/mpi/mpi_send_thread.py:27). Here the two
 corresponding device-side primitives are hand-tiled Pallas kernels:
 
-- :mod:`fedml_tpu.ops.aggregate` — fused sample-weighted client aggregation
-  (the FedAvg server rule) over a ``[clients, params]`` stack, tiled so the
-  weighted reduction rides the MXU.
+- :mod:`fedml_tpu.ops.aggregate` — sample-weighted client aggregation (the
+  FedAvg server rule) leaf by leaf in the leaf's own shape: the mean of a
+  stacked cohort, or a client folded in place into a running sum.
 - :mod:`fedml_tpu.ops.quantize` — int8 block-scaled quantization with
   stochastic rounding for cross-silo model-delta compression.
 - :mod:`fedml_tpu.ops.flash_attention` — streaming-softmax attention for
@@ -24,9 +24,9 @@ CPU mesh, and a pure-jnp reference used both as the CPU fallback and as the
 test oracle.
 """
 
-from fedml_tpu.ops.aggregate import (fold_weighted, tree_fold_pallas,
+from fedml_tpu.ops.aggregate import (fold_weighted, mean_kernel_params,
+                                     tree_fold_pallas,
                                      tree_weighted_mean_pallas,
-                                     weighted_mean_flat,
                                      weighted_mean_flat_reference)
 from fedml_tpu.ops.autotune import (AttentionDecision, AutotuneCache,
                                     autotune_attention,
@@ -37,9 +37,9 @@ from fedml_tpu.ops.quantize import (dequantize_int8, dequantize_tree,
                                     quantize_int8, quantize_tree)
 
 __all__ = [
-    "weighted_mean_flat",
     "weighted_mean_flat_reference",
     "tree_weighted_mean_pallas",
+    "mean_kernel_params",
     "fold_weighted",
     "tree_fold_pallas",
     "quantize_int8",

@@ -1,18 +1,24 @@
-"""Fused weighted client aggregation as a Pallas TPU kernel.
+"""Weighted client aggregation as Pallas TPU kernels.
 
 The FedAvg server update is ``w_global = sum_i n_i * w_i / sum_i n_i``
-(reference: FedAVGAggregator.py:72-80). With client updates stacked as a
-``[C, D]`` matrix this is a ``[1, C] @ [C, D]`` matvec — exactly the shape the
-MXU wants — so the whole aggregation is one kernel pass over HBM instead of a
-per-leaf Python loop. The kernel tiles D into VMEM-sized lanes and keeps the
-tiny weight vector resident.
+(reference: FedAVGAggregator.py:72-80). Both forms here work leaf by leaf,
+in the shape and layout the trainer left the leaf in, so nothing is
+concatenated, padded or copied around a kernel: a round's aggregation reads
+every client's parameters once and writes the mean once.
+
+Where the cohort's models are stacked (the ``vmap``-ped round: every leaf
+``[C, ...]``), ``tree_weighted_mean_pallas`` takes the mean of each leaf:
+a leaf the TPU tiles without padding goes through ``_mean_kernel``, a grid
+over row and lane blocks with the clients' shares in SMEM and a float32 sum
+over the client axis on the VPU (exact float32: the MXU's default is one
+bf16 pass); vectors and narrow matrices, a fiftieth of a convolutional
+model, are left to XLA's fused multiply-reduce.
 
 Where a copy of the model is gigabytes the clients cannot be stacked: they
 train one after another and each result is *folded* into a running float32
 sum, ``acc += (n_i / sum n) * w_i`` (``tree_fold_pallas``). That kernel
-updates the sum in place (``input_output_aliases``), leaf by leaf in the
-leaf's own shape, so a fold reads the sum and the client once and writes
-the sum once and nothing is concatenated, padded or copied around it.
+updates the sum in place (``input_output_aliases``), so a fold reads the
+sum and the client once and writes the sum once.
 
 CPU/test path: ``interpret=True`` runs the same kernels through the Pallas
 interpreter; ``weighted_mean_flat_reference`` is the jnp oracle.
@@ -20,28 +26,12 @@ interpreter; ``weighted_mean_flat_reference`` is the jnp oracle.
 
 from __future__ import annotations
 
-import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-# lane tile for the parameter axis; multiple of 128 (TPU lane width) and
-# small enough that [C, TILE_D] fits VMEM for any realistic clients-per-round
-_TILE_D = 2048
-
-
-def _wmean_kernel(w_ref, x_ref, out_ref):
-    # w: [1, C], x: [C, TILE_D] -> out: [1, TILE_D]; rides the MXU.
-    # HIGHEST: at Mosaic's default precision the MXU multiplies f32
-    # operands in one bf16 pass, which rounds every parameter of the new
-    # global model to ~3 digits (measured on a v5e: 3e-3 of max|w|).
-    # Measured there at [10, 11.2M]: 2.49 ms per call against 2.20 ms at
-    # the default precision (CHANGES.md, PR 21)
-    out_ref[:] = jnp.dot(w_ref[:], x_ref[:],
-                         preferred_element_type=jnp.float32,
-                         precision=jax.lax.Precision.HIGHEST)
 
 
 def weighted_mean_flat_reference(stacked: jax.Array,
@@ -54,61 +44,103 @@ def weighted_mean_flat_reference(stacked: jax.Array,
                       precision=jax.lax.Precision.HIGHEST)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def weighted_mean_flat(stacked: jax.Array, weights: jax.Array,
-                       *, interpret: bool = False) -> jax.Array:
-    """Sample-weighted mean over the client axis of a ``[C, D]`` stack.
+# -- the stacked mean -------------------------------------------------------------
 
-    Returns a ``[D]`` float32 vector. ``weights`` are the per-client sample
-    counts ``n_i``; normalization by ``sum(n_i)`` is folded into the weight
-    vector so the kernel is a single matvec.
-    """
-    c, d = stacked.shape
-    w = weights.astype(jnp.float32)
-    w = (w / jnp.sum(w)).reshape(1, c)
+#: bytes of VMEM the stacked mean's input block may take, double-buffered
+#: (Mosaic's scoped limit on a v5e is 16 MiB)
+_MEAN_VMEM = 8 << 20
+#: most elements of one output block: its float32 sum is carried through
+#: the loop over clients in vector registers (16 of the 64)
+_MEAN_ACC = 1 << 14
 
-    pad = (-d) % _TILE_D
-    if pad:
-        stacked = jnp.pad(stacked, ((0, 0), (0, pad)))
-    dp = d + pad
 
-    out = pl.pallas_call(
-        _wmean_kernel,
-        grid=(dp // _TILE_D,),
-        in_specs=[
-            pl.BlockSpec((1, c), lambda i: (0, 0)),
-            pl.BlockSpec((c, _TILE_D), lambda i: (0, i)),
-        ],
-        out_specs=pl.BlockSpec((1, _TILE_D), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((1, dp), jnp.float32),
+def _mean_kernel(share_ref, x_ref, out_ref):
+    # share: [C] in SMEM, x: [1, C, r, l] -> out: [1, r, l]. A multiply-add
+    # a client on the VPU, which keeps float32 exact; the MXU form needs
+    # Precision.HIGHEST for that and measured no faster (CHANGES.md, PR 21)
+    def add(c, acc):
+        return acc + share_ref[c] * x_ref[0, c].astype(jnp.float32)
+
+    out_ref[0] = jax.lax.fori_loop(
+        0, x_ref.shape[1], add, jnp.zeros(out_ref.shape[1:], jnp.float32))
+
+
+def _mean_block(clients: int, shape, dtype):
+    """The ``(rows, lanes)`` block in which ``_mean_kernel`` takes the mean
+    of a stacked leaf ``[clients, *shape]``, or None where the leaf is left
+    to XLA. The kernel takes a float32 leaf whose last two dimensions the
+    TPU tiles without padding (a multiple of 8 by a multiple of 128). The
+    block is the widest multiple of 128 lanes that divides the columns, and
+    as many rows, with the clients' blocks double-buffered inside
+    ``_MEAN_VMEM``."""
+    budget = min(_MEAN_ACC, _MEAN_VMEM // (2 * 4 * clients))
+    if (len(shape) < 2 or shape[-2] % 8 or shape[-1] % 128
+            or dtype != jnp.float32 or budget < 8 * 128):
+        return None
+    rows, cols = shape[-2:]
+    lanes = max(n for n in range(128, min(cols, budget // 8) + 1, 128)
+                if cols % n == 0)
+    return min(rows, budget // lanes // 8 * 8), lanes
+
+
+def stacked_mean_leaf(x: jax.Array, share: jax.Array, *,
+                      interpret: bool = False) -> jax.Array:
+    """``sum_c share[c] * x[c]`` for one stacked leaf ``[C, ...]``, summed in
+    float32 and returned in the leaf's dtype; ``share`` is ``[C]`` float32.
+    Which leaves go through the Pallas kernel is read from the shape
+    (``_mean_block``); a leaf's mean costs one read of the leaf either way.
+
+    The kernel reads a leaf ``[C, *lead, R, L]`` as ``[prod(lead), C, R,
+    L]``: that is how XLA lays out a vmapped convolution's kernels in the
+    trainer's loop (``[104, 3, 3, 512, 512]`` is carried as ``[3, 3, 104,
+    512, 512]``), so the view is a bitcast where the client axis in front
+    would cost a transposing copy of the leaf."""
+    c, shape = x.shape[0], x.shape[1:]
+    block = _mean_block(c, shape, x.dtype)
+    if block is None:
+        share = share.reshape((c,) + (1,) * len(shape))
+        return jnp.sum(share * x.astype(jnp.float32), axis=0).astype(x.dtype)
+    rows, cols = shape[-2:]
+    planes = math.prod(shape[:-2])
+    return pl.pallas_call(
+        _mean_kernel,
+        grid=(planes, pl.cdiv(rows, block[0]), cols // block[1]),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
+                  pl.BlockSpec((1, c) + block, lambda p, i, j: (p, 0, i, j))],
+        out_specs=pl.BlockSpec((1,) + block, lambda p, i, j: (p, i, j)),
+        out_shape=jax.ShapeDtypeStruct((planes, rows, cols), jnp.float32),
         interpret=interpret,
-    )(w, stacked)
-    return out[0, :d]
+    )(share, jnp.moveaxis(x, 0, -3).reshape(planes, c, rows, cols)
+      ).reshape(shape)
+
+
+def mean_kernel_params(tree, clients: int):
+    """``(through the kernel, left to XLA)``: the parameters of one model
+    ``tree`` on either side of ``stacked_mean_leaf``'s choice when
+    ``clients`` copies of it are stacked."""
+    kernel = xla = 0
+    for leaf in jax.tree.leaves(tree):
+        if _mean_block(clients, leaf.shape, leaf.dtype) is None:
+            xla += math.prod(leaf.shape)
+        else:
+            kernel += math.prod(leaf.shape)
+    return kernel, xla
 
 
 @jax.named_scope("fedml.aggregate")
 def tree_weighted_mean_pallas(stacked_tree, weights, *,
                               interpret: bool = False):
-    """Pytree front-end: ravel all leaves into one ``[C, D]`` matrix, run the
-    fused kernel once, and unravel.
+    """Sample-weighted mean over the leading (client) axis of every leaf,
+    leaf by leaf in the leaf's own shape (``stacked_mean_leaf``).
 
-    Drop-in for :func:`fedml_tpu.core.pytree.tree_weighted_mean` — one kernel
-    launch for the whole model instead of one reduction per leaf, which is the
-    difference between a bandwidth-bound single pass and dozens of tiny
-    dispatches for deep models (ResNet-56 has 250+ leaves).
-    """
-    leaves, treedef = jax.tree.flatten(stacked_tree)
-    c = leaves[0].shape[0]
-    sizes = [leaf[0].size for leaf in leaves]
-    shapes = [leaf.shape[1:] for leaf in leaves]
-    flat = jnp.concatenate(
-        [leaf.reshape(c, -1).astype(jnp.float32) for leaf in leaves], axis=1)
-    mean = weighted_mean_flat(flat, weights, interpret=interpret)
-    out, off = [], 0
-    for size, shape, leaf in zip(sizes, shapes, leaves):
-        out.append(mean[off:off + size].reshape(shape).astype(leaf.dtype))
-        off += size
-    return jax.tree.unflatten(treedef, out)
+    Drop-in for :func:`fedml_tpu.core.pytree.tree_weighted_mean`;
+    ``weights`` are the per-client sample counts ``n_i``, normalised once
+    to float32 shares."""
+    share = weights.astype(jnp.float32)
+    share = share / jnp.sum(share)
+    return jax.tree.map(
+        lambda x: stacked_mean_leaf(x, share, interpret=interpret),
+        stacked_tree)
 
 
 # -- the in-place fold ------------------------------------------------------------

@@ -48,7 +48,9 @@ def test_last_stdout_line_is_the_result_and_nothing_else(
                         lambda: str(tmp_path / "cache"))
     monkeypatch.setattr(chip_smoke, "load_federation",
                         lambda *a, **k: (None, "m", "t"))
-    monkeypatch.setattr(chip_smoke, "param_count", lambda *a: 1)
+    monkeypatch.setattr(chip_smoke, "model_shapes",
+                        lambda *a, **k: {"w": jax.ShapeDtypeStruct(
+                            (8, 128), jax.numpy.float32)})
     monkeypatch.setattr(chip_smoke, "peak_memory", dict)
     leg_failures = []
     monkeypatch.setattr(
@@ -124,13 +126,20 @@ def test_parity_leg_tiny_and_its_tolerance(tiny_federation, tmp_path):
 
 
 def test_kernel_leg_tiny_interpreted():
+    def leaf(*shape):
+        return jax.ShapeDtypeStruct(shape, jax.numpy.float32)
+
     out = chip_smoke.kernel_leg(
-        clients=3, dims=(5000, 4096), topk_frac=0.05,
+        cohorts=((3, {"conv": leaf(3, 3, 8, 128), "bias": leaf(128)}),
+                 (5, {"fc": leaf(72, 128), "head": leaf(128, 62)})),
+        dims=(5000, 4096), topk_frac=0.05,
         attn_shape=(1, 256, 2, 32), block_grid=((128, 128), (256, 128)))
     assert out["interpreted"] is True
     assert out["failures"] == [], out["failures"]
     kernels = {c["kernel"] for c in out["checks"]}
-    assert kernels == {"aggregate.weighted_mean_flat",
+    assert [(c["shape"], c["kernel_params"]) for c in out["checks"][:2]] == [
+        ([3, 9344], 9216), ([5, 17152], 9216)]
+    assert kernels == {"aggregate.tree_weighted_mean_pallas",
                        "quantize.int8_round_trip",
                        "sparsify.topk_int8_round_trip",
                        "sparsify.topk_rebuild", "sparsify.topk_support",

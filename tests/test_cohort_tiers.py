@@ -258,3 +258,68 @@ def test_training_in_tiers_follows_the_untiered_trajectory():
     scale = max(float(jnp.max(jnp.abs(x)))
                 for x in jax.tree.leaves(plain.variables))
     assert err <= 1e-6 * scale
+
+
+# -- what PR 30 (the stacked mean leaf by leaf) must not have moved, and its counter
+
+def test_the_spmd_round_traces_the_parents_program():
+    """``make_spmd_round`` and its ``_weighted_psum_mean`` as commit c3a6b52
+    traced them: the first 16 hex digits of sha256(str(jaxpr)) under this
+    JAX."""
+    import hashlib
+
+    from jax.sharding import Mesh
+
+    from fedml_tpu.parallel.spmd import _weighted_psum_mean, make_spmd_round
+
+    ds = make_blob_federated(client_num=16, n_samples=16 * 25, seed=0,
+                             partition_method="homo")
+    api = _api(ds)
+    _, (x, y, mask, keys, weights, _) = api._prepare_round(1)
+    mesh = Mesh(np.asarray(jax.devices()[:4]), ("clients",))
+    round_fn = make_spmd_round(api.module, "classification",
+                               api.config.train, mesh)
+    stacked = jax.tree.map(lambda v: jnp.stack([v] * 4), api.variables)
+    mean = jax.shard_map(
+        lambda s, w: _weighted_psum_mean(s, w, ("clients",)), mesh=mesh,
+        in_specs=(jax.P("clients"), jax.P("clients")), out_specs=jax.P())
+    got = {
+        "make_spmd_round": jax.make_jaxpr(round_fn)(
+            api.variables, x, y, mask, keys, weights),
+        "_weighted_psum_mean": jax.make_jaxpr(mean)(stacked, weights[:4])}
+    assert {k: hashlib.sha256(str(v).encode()).hexdigest()[:16]
+            for k, v in got.items()} == {
+                "make_spmd_round": "53250671e36c295c",
+                "_weighted_psum_mean": "7d82c41a055e5550"}
+
+
+@pytest.mark.parametrize("model, args, row, cohort, kernel, total", [
+    ("resnet18_gn", dict(output_dim=100, small_images=False), (24, 24, 3),
+     104, 11_010_048, 11_227_812),
+    ("cnn", dict(output_dim=62), (28, 28, 1), 256, 1_179_648, 1_206_590)])
+def test_the_driver_counts_what_the_mean_kernel_takes(
+        monkeypatch, model, args, row, cohort, kernel, total):
+    """98.1 % of ResNet-18-GN and 97.8 % of the CNN go through the kernel,
+    counted where the Pallas mean is the driver's aggregation: on a TPU,
+    with no hook of the caller's and no fold."""
+    import fedml_tpu.utils as utils
+    from fedml_tpu.data.base import FederatedDataset
+    from fedml_tpu.models import create_model
+
+    images = {c: (np.zeros((2,) + row, np.float32), np.zeros(2, np.int32))
+              for c in range(2)}
+    ds = FederatedDataset.from_client_arrays(images, images,
+                                             class_num=args["output_dim"])
+    config = FedAvgConfig(client_num_per_round=cohort, prefetch_depth=0,
+                          train=TrainConfig(epochs=1, batch_size=2, lr=0.1))
+    module = create_model(model, **args)
+    assert "agg_kernel_params" not in FedAvgAPI(
+        ds, module, config=config).timer.counters  # the CPU's mean is XLA's
+    monkeypatch.setattr(utils, "on_tpu", lambda: True)
+    counters = FedAvgAPI(ds, module, config=config).timer.counters
+    assert counters["agg_kernel_params"] == kernel
+    assert counters["agg_kernel_params"] + counters["agg_xla_params"] == total
+    assert round(100 * kernel / total, 1) == (98.1 if model != "cnn" else 97.8)
+    assert "agg_kernel_params" not in FedAvgAPI(
+        ds, module, config=config,
+        aggregate_hook=lambda v, s, w, k: v).timer.counters

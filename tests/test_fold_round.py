@@ -5,6 +5,7 @@ that must not move when the fold is unset. Also the token bound of
 ``make_eval``'s batches and the sequence-row counters."""
 
 import dataclasses
+import hashlib
 
 import jax
 import jax.numpy as jnp
@@ -311,3 +312,72 @@ def test_the_fold_is_one_config_field_and_off_by_default():
     fields = {f.name: f.default for f in dataclasses.fields(FedAvgConfig)}
     assert fields["fold_clients"] is False
     assert fedavg.make_folded_body is make_folded_body
+
+
+# -- what PR 30 (the stacked mean leaf by leaf) must not have moved ---------------
+
+def _digest(fn, *args) -> str:
+    return hashlib.sha256(
+        str(jax.make_jaxpr(fn)(*args)).encode()).hexdigest()[:16]
+
+
+#: the first 16 hex digits of sha256(str(jaxpr)) as commit c3a6b52 (before
+#: the stacked mean went leaf by leaf) traces these under this JAX
+PARENT = {"fold_weighted": "99ab2da55ece564a",
+          "tree_fold_pallas": "9d91e92b7d7db19d",
+          "make_folded_body": "628c1bd4a2e0ea6d"}
+
+
+@pytest.mark.parametrize("what", sorted(PARENT))
+def test_the_fold_traces_the_parents_program(what, hybrid):
+    if what == "make_folded_body":
+        dataset, module = hybrid
+        api = _api(dataset, module, "lm_rows")
+        _, (x, y, mask, keys, weights, _) = api._prepare_round(0)
+        got = _digest(make_folded_body(api._local_train, interpret=True),
+                      api.variables, x, y, mask, keys, weights)
+    elif what == "tree_fold_pallas":
+        tree = {"w": jnp.zeros((520, 256)), "narrow": jnp.zeros((5120, 16)),
+                "b": jnp.zeros((7,))}
+        got = _digest(lambda a, b, w: tree_fold_pallas(
+            a, b, w, interpret=True), tree, tree, jnp.float32(0.25))
+    else:
+        acc = jnp.zeros((160, 5120))
+        got = _digest(lambda a, b: fold_weighted(
+            a, b.astype(jnp.bfloat16), 0.3, interpret=True), acc, acc)
+    assert got == PARENT[what]
+
+
+def test_the_sim_round_differs_from_the_parents_only_in_the_aggregation():
+    """The round a TPU runs: the trainer's equations are the vmapped body's,
+    the parent's (test_cohort_tiers holds that body to the parent's), and
+    every equation after them is inside ``fedml.aggregate``."""
+    from fedml_tpu.ops import tree_weighted_mean_pallas
+
+    ds = make_blob_federated(client_num=8, n_samples=8 * 25, seed=0,
+                             partition_method="homo")
+    api = _api(ds, LogisticRegression(num_classes=ds.class_num),
+               "classification",
+               train=TrainConfig(epochs=1, batch_size=8, lr=0.1))
+    _, (x, y, mask, keys, weights, _) = api._prepare_round(1)
+
+    def tpu_round(variables, x, y, mask, keys, weights):
+        stacked, totals = api._vmapped_body(variables, x, y, mask, keys,
+                                            None)
+        return tree_weighted_mean_pallas(stacked, weights,
+                                         interpret=True), totals
+
+    ours = jax.make_jaxpr(tpu_round)(api.variables, x, y, mask, keys,
+                                     weights).jaxpr
+    body = jax.make_jaxpr(api._vmapped_body)(api.variables, x, y, mask,
+                                             keys).jaxpr
+    n = len(body.eqns)
+    assert [str(e.primitive) for e in ours.eqns[:n]] == [
+        str(e.primitive) for e in body.eqns]
+    assert [[v.aval for v in e.outvars] for e in ours.eqns[:n]] == [
+        [v.aval for v in e.outvars] for e in body.eqns]
+    rest = ours.eqns[n:]
+    assert rest and all("fedml.aggregate" in str(e.source_info.name_stack)
+                        for e in rest)
+    assert not any("fedml.aggregate" in str(e.source_info.name_stack)
+                   for e in ours.eqns[:n])

@@ -6,30 +6,45 @@ import numpy as np
 import pytest
 
 from fedml_tpu.core.pytree import tree_weighted_mean
-from fedml_tpu.ops import (dequantize_int8, dequantize_tree, quantize_int8,
-                           quantize_tree, tree_weighted_mean_pallas,
-                           weighted_mean_flat, weighted_mean_flat_reference)
+from fedml_tpu.models import create_model
+from fedml_tpu.ops import (dequantize_int8, dequantize_tree,
+                           mean_kernel_params, quantize_int8, quantize_tree,
+                           tree_weighted_mean_pallas,
+                           weighted_mean_flat_reference)
+from fedml_tpu.ops.aggregate import stacked_mean_leaf
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr, those of its sub-jaxprs included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
 
 
 class TestWeightedMean:
     def test_matches_reference_flat(self):
+        # a [C, D] leaf: a vector a client, the side that is left to XLA
         rng = np.random.RandomState(0)
         x = rng.randn(7, 5000).astype(np.float32)
         w = rng.uniform(1, 100, size=7).astype(np.float32)
-        got = weighted_mean_flat(jnp.asarray(x), jnp.asarray(w),
-                                 interpret=True)
+        got = tree_weighted_mean_pallas({"w": jnp.asarray(x)},
+                                        jnp.asarray(w), interpret=True)["w"]
         want = weighted_mean_flat_reference(jnp.asarray(x), jnp.asarray(w))
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=1e-5, atol=1e-6)
 
     def test_unpadded_tile_boundary(self):
+        # the same stack as whole (8, 128) tiles: the kernel's side
         rng = np.random.RandomState(1)
-        x = rng.randn(3, 4096).astype(np.float32)  # exact multiple of tile
+        x = rng.randn(3, 4096).astype(np.float32)
         w = np.array([1.0, 2.0, 3.0], np.float32)
-        got = weighted_mean_flat(jnp.asarray(x), jnp.asarray(w),
-                                 interpret=True)
+        leaf = jnp.asarray(x).reshape(3, 32, 128)
+        assert "pallas_call" in str(jax.make_jaxpr(
+            lambda a: tree_weighted_mean_pallas(a, w, interpret=True))(leaf))
+        got = tree_weighted_mean_pallas(leaf, jnp.asarray(w), interpret=True)
         want = weighted_mean_flat_reference(jnp.asarray(x), jnp.asarray(w))
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+        np.testing.assert_allclose(np.asarray(got).ravel(), np.asarray(want),
                                    rtol=1e-5, atol=1e-6)
 
     def test_tree_frontend_matches_pytree_rule(self):
@@ -46,6 +61,82 @@ class TestWeightedMean:
             lambda a, b: np.testing.assert_allclose(
                 np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6),
             got, want)
+
+    # (leaf shape behind the client axis, dtype, does the kernel take it)
+    LEAVES = {
+        "conv3x3": ((3, 3, 16, 128), jnp.float32, True),
+        "conv1x1": ((1, 1, 8, 256), jnp.float32, True),
+        # 200 rows: no block of 128, 72 or 32 rows divides them
+        "ragged_rows": ((200, 128), jnp.float32, True),
+        "wide": ((8, 2304), jnp.float32, True),
+        "narrow64": ((3, 3, 8, 64), jnp.float32, False),
+        "head": ((128, 62), jnp.float32, False),
+        "odd_rows": ((3, 3, 4, 128), jnp.float32, False),
+        "vector": ((128,), jnp.float32, False),
+        "scalar": ((), jnp.float32, False),
+        "bf16": ((16, 128), jnp.bfloat16, False),
+    }
+
+    @pytest.mark.parametrize("clients", [8, 104, 256])
+    @pytest.mark.parametrize("leaf", sorted(LEAVES))
+    def test_leaf_mean_matches_the_float64_mean(self, leaf, clients):
+        shape, dtype, kernel = self.LEAVES[leaf]
+        rng = np.random.RandomState(len(leaf) + clients)
+        x = jnp.asarray(rng.randn(clients, *shape), dtype)
+        w = rng.randint(17, 341, size=clients).astype(np.float32)
+        share = jnp.asarray(w / w.sum())
+
+        def mean(a):
+            return stacked_mean_leaf(a, share, interpret=True)
+
+        assert ("pallas_call" in str(jax.make_jaxpr(mean)(x))) == kernel
+        got = jax.jit(mean)(x)
+        assert got.dtype == dtype and got.shape == shape
+        with jax.enable_x64(True):
+            want = tree_weighted_mean(np.asarray(x, np.float64),
+                                      np.asarray(w, np.float64))
+        # float32 sums of `clients` terms; bf16 rounds the result once
+        tol = 2e-6 if dtype == jnp.float32 else 2.0 ** -8
+        np.testing.assert_allclose(np.asarray(got, np.float64),
+                                   np.asarray(want), rtol=tol, atol=tol)
+
+    def test_a_cohort_too_wide_for_vmem_goes_to_xla(self):
+        x = jax.ShapeDtypeStruct((2048, 16, 128), jnp.float32)
+        share = jax.ShapeDtypeStruct((2048,), jnp.float32)
+        assert "pallas_call" not in str(jax.make_jaxpr(
+            lambda a, s: stacked_mean_leaf(a, s, interpret=True))(x, share))
+
+    @pytest.mark.parametrize("model, args, row, clients, kernel, total", [
+        ("resnet18_gn", dict(output_dim=100, small_images=False),
+         (24, 24, 3), 104, 11_010_048, 11_227_812),
+        ("cnn", dict(output_dim=62), (28, 28, 1), 256, 1_179_648,
+         1_206_590)])
+    def test_no_stack_of_the_cohort_is_built(self, model, args, row, clients,
+                                             kernel, total):
+        module = create_model(model, **args)
+        variables = jax.eval_shape(lambda: module.init(
+            jax.random.key(0), jnp.zeros((1,) + row), train=False))
+        assert mean_kernel_params(variables, clients) == (kernel,
+                                                          total - kernel)
+        stacked = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct((clients,) + a.shape, a.dtype),
+            variables)
+        closed = jax.make_jaxpr(lambda s, w: tree_weighted_mean_pallas(
+            s, w, interpret=True))(
+                stacked, jax.ShapeDtypeStruct((clients,), jnp.float32))
+        names = [eqn.primitive.name for eqn in _eqns(closed.jaxpr)]
+        assert not {"concatenate", "pad", "dynamic_update_slice",
+                    "gather"} & set(names)
+        # one call a leaf the kernel takes, whatever their shapes
+        calls = sum(
+            mean_kernel_params([a], clients)[0] > 0
+            for a in jax.tree.leaves(variables))
+        assert names.count("pallas_call") == calls > 0
+        # nothing larger than the largest stacked leaf is ever formed
+        largest = max(a.size for a in jax.tree.leaves(stacked))
+        assert largest < clients * total
+        for eqn in closed.jaxpr.eqns:
+            assert all(v.aval.size <= largest for v in eqn.outvars), eqn
 
 
 class TestQuantize:
