@@ -168,7 +168,7 @@ def _round_costs(api) -> "tuple[float, float, str | None]":
     except hid the cause."""
     import jax.numpy as jnp
 
-    _, args = api._prepare_round(0)
+    _, _, args = api._pack_round(0)
     try:
         # lower the EXACT jitted round program run_round dispatches —
         # round_idx is its final traced operand (lr_decay_round schedule);
@@ -196,7 +196,7 @@ def _analytic_round_flops(api) -> float:
 
     from fedml_tpu.utils.flops import analytic_flops
 
-    _, args = api._prepare_round(0)
+    _, _, args = api._pack_round(0)
     return analytic_flops(api._round_fn_py, api.variables, *args,
                           jnp.uint32(0))
 

@@ -59,13 +59,16 @@ def cohort_tiers(clients: int, tier_clients: Optional[int]) -> int:
     return max(1, clients // tier_clients)  # an empty cohort: one, too
 
 
-def make_vmapped_body(local_train, tier_clients: Optional[int] = None,
-                      train: Optional[TrainConfig] = None):
-    """vmap local training over the client axis and sum stats — the shared
-    round body every FedAvg-family algorithm composes with its own
-    aggregation rule. ``lr_scale`` (optional scalar, broadcast to every
-    client) applies TrainConfig.lr_decay_round's per-round schedule; None
-    traces the identical constant-LR program as before.
+def make_vmapped_clients(local_train, tier_clients: Optional[int] = None,
+                         train: Optional[TrainConfig] = None):
+    """vmap local training over the client axis: ``(variables, x, y, mask,
+    keys, lr_scale=None) -> (stacked clients, per-client stats)``. The one
+    place the cohort is trained side by side: ``make_vmapped_body`` sums
+    its stats for a single device, the mesh rounds of ``parallel/spmd.py``
+    run it on each chip's shard of the cohort and sum after their ``psum``
+    mean. ``lr_scale`` (optional scalar, broadcast to every client)
+    applies TrainConfig.lr_decay_round's per-round schedule; None traces
+    the identical constant-LR program as before.
 
     ``tier_clients`` (with ``train``, the config ``local_train`` was built
     from) is for ragged federations: a cohort that ``cohort_tiers`` cuts
@@ -87,30 +90,41 @@ def make_vmapped_body(local_train, tier_clients: Optional[int] = None,
                 v, xc, yc, mc, kc, lr_scale=lr_scale, n_steps=n_steps),
             in_axes=(None, 0, 0, 0, 0))(variables, x, y, mask, keys)
 
-    def body(variables, x, y, mask, keys, lr_scale=None):
+    def clients(variables, x, y, mask, keys, lr_scale=None):
         tiers = cohort_tiers(x.shape[0], tier_clients)
         if tiers == 1:
-            stacked, stats = train_clients(variables, x, y, mask, keys,
-                                           lr_scale)
-        else:
-            def tier(inp):
-                xt, yt, mt, kt = inp
-                # unbatched under the vmap: its loop stays one ``while``
-                n_steps = jnp.max(jax.vmap(
-                    lambda m: real_batches(m, train))(mt))
-                return train_clients(variables, xt, yt, mt, kt, lr_scale,
-                                     n_steps)
+            return train_clients(variables, x, y, mask, keys, lr_scale)
 
-            def split(a):
-                return a.reshape((tiers, a.shape[0] // tiers) + a.shape[1:])
+        def tier(inp):
+            xt, yt, mt, kt = inp
+            # unbatched under the vmap: its loop stays one ``while``
+            n_steps = jnp.max(jax.vmap(
+                lambda m: real_batches(m, train))(mt))
+            return train_clients(variables, xt, yt, mt, kt, lr_scale,
+                                 n_steps)
 
-            def join(a):
-                return a.reshape((a.shape[0] * a.shape[1],) + a.shape[2:])
+        def split(a):
+            return a.reshape((tiers, a.shape[0] // tiers) + a.shape[1:])
 
-            stacked, stats = jax.tree.map(join, jax.lax.map(
-                tier, jax.tree.map(split, (x, y, mask, keys))))
-        totals = jax.tree.map(lambda s: jnp.sum(s, axis=0), stats)
-        return stacked, totals
+        def join(a):
+            return a.reshape((a.shape[0] * a.shape[1],) + a.shape[2:])
+
+        return jax.tree.map(join, jax.lax.map(
+            tier, jax.tree.map(split, (x, y, mask, keys))))
+
+    return clients
+
+
+def make_vmapped_body(local_train, tier_clients: Optional[int] = None,
+                      train: Optional[TrainConfig] = None):
+    """``make_vmapped_clients`` with the stats summed over the cohort:
+    ``(stacked clients, stat totals)`` - the shared round body every
+    FedAvg-family algorithm composes with its own aggregation rule."""
+    clients = make_vmapped_clients(local_train, tier_clients, train)
+
+    def body(variables, x, y, mask, keys, lr_scale=None):
+        stacked, stats = clients(variables, x, y, mask, keys, lr_scale)
+        return stacked, jax.tree.map(lambda s: jnp.sum(s, axis=0), stats)
 
     return body
 
@@ -218,7 +232,18 @@ class FedAvgConfig:
 
 class FedAvgAPI:
     """Standalone simulation API (parity:
-    fedml_api/standalone/fedavg/fedavg_api.py), all clients vmapped."""
+    fedml_api/standalone/fedavg/fedavg_api.py), all clients vmapped - and
+    the one round driver: the host half of a round (sampling, the cohort's
+    order, pack, upload, keys, prefetcher, counters, the loop) is written
+    here only. ``parallel/spmd.py::DistributedFedAvgAPI`` places it on a
+    mesh by overriding the seams below (placement, cohort padding,
+    programs, evaluation)."""
+
+    #: prefix of the flight-record job id a run derives when none is set
+    _job_prefix = "sim"
+    #: the attributes that hold a (prefetcher, dataset-at-build) pair once
+    #: built; ``prefetch_stats`` / ``release_prefetch`` read all of them
+    _prefetch_slots = ("_prefetch",)
 
     def __init__(self, dataset: FederatedDataset, module,
                  task: str = "classification",
@@ -237,13 +262,69 @@ class FedAvgAPI:
 
         from fedml_tpu.trainer.functional import validate_accum_steps
         validate_accum_steps(cfg, dataset.train_data_local_num_dict)
+        if self.config.pack not in ("cohort", "global"):
+            raise ValueError(f"unknown pack policy: {self.config.pack!r}")
+        self._n_pad = dataset.padded_len(cfg.batch_size)
+        self._base_key = jax.random.key(self.config.seed)
+        from fedml_tpu.utils.tracing import RoundTimer
+        self.timer = RoundTimer()
+
+        sample_x = dataset.train_data_global[0][:1]
+        self.variables = module.init(jax.random.key(self.config.seed),
+                                     jnp.asarray(sample_x), train=False)
+        self._build_programs(aggregate_hook)
+        self.history: List[Dict] = []
+        # packed-cohort cache: when a round samples the same client set
+        # (e.g. full participation), skip host packing and re-upload — the
+        # device-side analogue of the reference's update_dataset re-pointing
+        # (FedAVGTrainer.py:25-30)
+        self._pack_cache = None
+        # eval arrays live on device across test rounds (re-uploading the
+        # global unions every evaluation dominated host time on image sets)
+        self._eval_cache = None
+        # cohort prefetcher (parallel/prefetch.py), built lazily on the
+        # first partial-participation round; (prefetcher, dataset-at-build)
+        self._prefetch = None
+        # virtualized populations (fedml_tpu/state/) front the per-client
+        # shards with a tiered store; binding its counters here puts
+        # state_cache_hits/misses/evictions + state_bytes_read/written on
+        # the same evidence row as the phase timings
+        store = getattr(dataset, "store", None)
+        if store is not None and hasattr(store, "bind_timer"):
+            store.bind_timer(self.timer)
+        # observability (fedml_tpu/obs): flight recorder + slow-round
+        # anomaly profiling; config.obs_dir None (default) keeps this
+        # fully off
+        from fedml_tpu.obs import build_observability, default_job_id
+        devices = self._round_devices()
+        self._obs = build_observability(
+            getattr(self.config, "obs_dir", None),
+            # collision-safe default: two unconfigured runs sharing an
+            # obs dir must not interleave under one literal id
+            job_id=(getattr(self.config, "job_id", None)
+                    or default_job_id(self._job_prefix)),
+            rank=0, role="server",
+            # fleet MFU denominator: every device the round program spans
+            # (on a mesh all of data x fsdp x tp, not just the federation
+            # axis), its kind read from one of them so a mixed host (CPU
+            # coordinator + TPU mesh) rates the mesh
+            perf_device_count=len(devices), perf_device=devices[0])
+        if self._obs is not None:
+            self._obs.bind_timer(self.timer)
+
+    # -- the seams a mesh overrides ----------------------------------------
+    def _build_programs(self, aggregate_hook) -> None:
+        """Programs: build ``_round_fn`` and ``_eval_fn``; ``self.variables``
+        is the fresh model and the timer is there for counters."""
+        cfg = self.config.train
+        module, task = self.module, self.task
         self._local_train = make_local_train(module, task, cfg)
         # tiers engage where they can save steps: clients that differ in
         # their number of batches (and a cohort of at least two tiers,
         # which the body reads from its input's shape)
         ragged = cfg.batch_size and len(
             {-(-n // cfg.batch_size)
-             for n in dataset.train_data_local_num_dict.values()}) > 1
+             for n in self.dataset.train_data_local_num_dict.values()}) > 1
         self._tier_clients = TIER_CLIENTS if ragged else None
         self._vmapped_body = make_vmapped_body(
             self._local_train, self._tier_clients, cfg)
@@ -294,28 +375,6 @@ class FedAvgAPI:
         # instead of holding both live (free bandwidth on big models)
         self._round_fn = jax.jit(round_fn, donate_argnums=(0,))
         self._eval_fn = jax.jit(make_eval(module, task))
-        if self.config.pack not in ("cohort", "global"):
-            raise ValueError(f"unknown pack policy: {self.config.pack!r}")
-        self._n_pad = dataset.padded_len(cfg.batch_size)
-        self._base_key = jax.random.key(self.config.seed)
-
-        sample_x = dataset.train_data_global[0][:1]
-        self.variables = module.init(jax.random.key(self.config.seed),
-                                     jnp.asarray(sample_x), train=False)
-        self.history: List[Dict] = []
-        # packed-cohort cache: when a round samples the same client set
-        # (e.g. full participation), skip host packing and re-upload — the
-        # device-side analogue of the reference's update_dataset re-pointing
-        # (FedAVGTrainer.py:25-30)
-        self._pack_cache = None
-        # eval arrays live on device across test rounds (re-uploading the
-        # global unions every evaluation dominated host time on image sets)
-        self._eval_cache = None
-        # cohort prefetcher (parallel/prefetch.py), built lazily on the
-        # first partial-participation round; (prefetcher, dataset-at-build)
-        self._prefetch = None
-        from fedml_tpu.utils.tracing import RoundTimer
-        self.timer = RoundTimer()
         if (aggregate_hook is None and on_tpu()
                 and not self.config.fold_clients):
             # how much of the model the stacked mean's kernel takes
@@ -324,26 +383,30 @@ class FedAvgAPI:
                 self.variables, self.config.client_num_per_round)
             self.timer.count("agg_kernel_params", kernel)
             self.timer.count("agg_xla_params", xla)
-        # virtualized populations (fedml_tpu/state/) front the per-client
-        # shards with a tiered store; binding its counters here puts
-        # state_cache_hits/misses/evictions + state_bytes_read/written on
-        # the same evidence row as the phase timings
-        store = getattr(dataset, "store", None)
-        if store is not None and hasattr(store, "bind_timer"):
-            store.bind_timer(self.timer)
-        # observability (fedml_tpu/obs): flight recorder + slow-round
-        # anomaly profiling for the sim driver; config.obs_dir None
-        # (default) keeps this fully off
-        from fedml_tpu.obs import build_observability, default_job_id
-        self._obs = build_observability(
-            getattr(self.config, "obs_dir", None),
-            # collision-safe default: two unconfigured runs sharing an
-            # obs dir must not interleave under one literal id
-            job_id=(getattr(self.config, "job_id", None)
-                    or default_job_id("sim")),
-            rank=0, role="server")
-        if self._obs is not None:
-            self._obs.bind_timer(self.timer)
+
+    def _round_devices(self) -> list:
+        """The devices the round program spans."""
+        return jax.devices()[:1]
+
+    def _put(self, a):
+        """Placement: a packed host array, or the round's per-client keys,
+        onto the device(s) as the round program takes them."""
+        return jnp.asarray(a)
+
+    def _pad_round(self, idxs):
+        """Cohort padding: ``(slots, alive)``, the clients the round's
+        slots hold and a 0/1 weight a slot (None: every slot is a sampled
+        client, the case on one device)."""
+        return idxs, None
+
+    def _round_inputs(self, x, y, mask, keys, weights, agg_key) -> tuple:
+        """Of a round's placed inputs, those ``_round_fn`` takes."""
+        return x, y, mask, keys, weights, agg_key
+
+    def _round_operands(self, args: tuple, round_idx: int) -> tuple:
+        """``_round_fn``'s operands after the model: ``_pack_round``'s
+        inputs and the round index (the rate's decay)."""
+        return args + (jnp.uint32(round_idx),)
 
     # -- one round ---------------------------------------------------------
     def _size_ordered(self, idxs, dataset):
@@ -358,83 +421,82 @@ class FedAvgAPI:
         return idxs[np.argsort([-sizes[int(c)] for c in idxs],
                                kind="stable")]
 
-    def _rows_stepped(self, idxs, n_pad: int) -> int:
-        """The rows the round program steps through, padding and all: every
+    def _rows_stepped(self, slots, n_pad: int) -> int:
+        """The rows the round program steps through, padding and all, from
+        the padded slot list (``_pad_round``'s, duplicates included): every
         slot's padded length, or per tier its clients x its longest
         client's batches x the batch size (what the body's bound comes to,
         reckoned from the sizes the packer holds)."""
-        tiers = cohort_tiers(len(idxs), self._tier_clients)
+        tiers = cohort_tiers(len(slots), self._tier_clients)
         if tiers == 1:
-            return len(idxs) * n_pad
+            return len(slots) * n_pad
         bsz = self.config.train.batch_size
         sizes = self.dataset.train_data_local_num_dict
         steps = np.array([-(-sizes[int(c)] // bsz)
-                          for c in idxs]).reshape(tiers, -1).max(axis=1)
-        return int(steps.sum()) * (len(idxs) // tiers) * bsz
+                          for c in slots]).reshape(tiers, -1).max(axis=1)
+        return int(steps.sum()) * (len(slots) // tiers) * bsz
 
-    def _pack_cohort(self, idxs, dataset=None):
-        """Cache-free pack + upload of one sampled cohort (thread-safe: no
-        shared mutable state — the prefetcher worker calls this
-        concurrently with the main thread's dispatch)."""
+    def _pack_cohort(self, idxs, ds):
+        """Cache-free pad + pack + upload of one sampled cohort of ``ds``:
+        ``(slots, (x, y, mask, weights))`` (thread-safe: no shared mutable
+        state — the prefetcher worker calls this concurrently with the main
+        thread's dispatch)."""
         cfg = self.config
-        ds = dataset if dataset is not None else self.dataset
         with self.timer.phase("pack"):
-            n_pad = (ds.cohort_padded_len(idxs, cfg.train.batch_size)
+            slots, alive = self._pad_round(idxs)
+            n_pad = (ds.cohort_padded_len(slots, cfg.train.batch_size)
                      if cfg.pack == "cohort" else self._n_pad)
-            x, y, mask = ds.pack_clients(idxs, cfg.train.batch_size,
+            x, y, mask = ds.pack_clients(slots, cfg.train.batch_size,
                                          n_pad=n_pad)
-            weights = ds.client_weights(idxs)
+            weights = ds.client_weights(slots)
+            if alive is not None:  # zero-weight duplicate slots
+                mask = mask * alive[:, None]
+                weights = weights * alive
         with self.timer.phase("upload"):
-            return (jnp.asarray(x), jnp.asarray(y), jnp.asarray(mask),
-                    jnp.asarray(weights))
+            return slots, (self._put(x), self._put(y), self._put(mask),
+                           self._put(weights))
 
     def _pack_round(self, round_idx: int):
-        """The full host side of one round — seeded sampling, pack,
-        upload, per-client keys — as a pure function of the round index
-        (the prefetcher's ``produce``). The dataset reference is snapshot
-        once so a concurrent mid-run swap can never mix two datasets'
-        arrays inside one payload (the stale payload is then discarded by
-        the caller's identity check)."""
+        """The full host side of one round — seeded sampling, the cohort's
+        order, pack (or the resident cohort), upload, per-client keys — as
+        a pure function of the round index: ``(dataset, idxs, placed
+        inputs)``. The prefetcher's ``produce`` *and* the serial path. The
+        dataset reference is snapshot once so a concurrent mid-run swap can
+        never mix two datasets' arrays inside one payload (the caller's
+        identity check then discards the stale payload). ``_pack_cache`` is
+        written only under full participation, where ``_round_prefetcher``
+        builds no prefetcher: so only ever on the round thread."""
         ds = self.dataset
         with self.timer.phase("produce"):
             idxs = self._size_ordered(
                 sample_clients(round_idx, ds.client_num,
                                self.config.client_num_per_round,
                                delete_client=self.delete_client), ds)
-            xd, yd, maskd, wd = self._pack_cohort(idxs, dataset=ds)
+            # the key holds a strong reference to the dataset object
+            # (mid-run swaps, e.g. escalating a poisoning attack, must
+            # invalidate — and holding the reference prevents CPython
+            # id-reuse false hits); cache only under full participation —
+            # partial cohorts are seeded per round and would just pin dead
+            # device buffers without ever hitting
+            full = len(idxs) == ds.client_num
+            cohort = tuple(int(i) for i in idxs) if full else None
+            cache = self._pack_cache
+            if (full and cache is not None and cache[0] is ds
+                    and cache[1] == cohort):
+                slots, (xd, yd, maskd, wd) = cache[2]
+            else:
+                if cache is not None:
+                    self._pack_cache = None  # free the old buffers first
+                slots, (xd, yd, maskd, wd) = self._pack_cohort(idxs, ds)
+                if full:
+                    self._pack_cache = (ds, cohort,
+                                        (slots, (xd, yd, maskd, wd)))
             _, keys, agg_key = round_keys(
                 self._base_key, round_idx,
-                jnp.asarray(np.asarray(idxs), dtype=jnp.uint32))
-        return ds, idxs, (xd, yd, maskd, keys, wd, agg_key)
-
-    def _prepare_round(self, round_idx: int):
-        """Host side of a round: seeded sampling, pad-and-mask packing,
-        per-client keys. Shared by all FedAvg-family algorithms."""
-        cfg = self.config
-        idxs = self._size_ordered(
-            sample_clients(round_idx, self.dataset.client_num,
-                           cfg.client_num_per_round,
-                           delete_client=self.delete_client), self.dataset)
-        # key holds a strong reference to the dataset object (mid-run swaps,
-        # e.g. escalating a poisoning attack, must invalidate — and holding
-        # the reference prevents CPython id-reuse false hits); cache only
-        # under full participation — partial cohorts are seeded per round
-        # and would just pin dead device buffers without ever hitting
-        cohort = tuple(int(i) for i in idxs)
-        if (self._pack_cache is not None
-                and self._pack_cache[0] is self.dataset
-                and self._pack_cache[1] == cohort):
-            xd, yd, maskd, wd = self._pack_cache[2]
-        else:
-            self._pack_cache = None  # free the old buffers before packing
-            xd, yd, maskd, wd = self._pack_cohort(idxs)
-            if len(idxs) == self.dataset.client_num:
-                self._pack_cache = (self.dataset, cohort,
-                                    (xd, yd, maskd, wd))
-        _, keys, agg_key = round_keys(
-            self._base_key, round_idx,
-            jnp.asarray(np.asarray(idxs), dtype=jnp.uint32))
-        return idxs, (xd, yd, maskd, keys, wd, agg_key)
+                jnp.asarray(np.asarray(slots), dtype=jnp.uint32))
+            keys = self._put(keys)
+        return ds, idxs, self._round_inputs(xd, yd, maskd, keys, wd,
+                                            agg_key)
 
     def _round_prefetcher(self):
         """The cohort prefetcher for the current config/dataset, or None
@@ -465,18 +527,31 @@ class FedAvgAPI:
                                     name="fedavg-cohort-prefetch"))
         return self._prefetch[0]
 
+    def _prefetchers(self) -> list:
+        """Every prefetcher built so far (``_prefetch_slots``)."""
+        return [pair[0] for pair in (getattr(self, slot)
+                                     for slot in self._prefetch_slots)
+                if pair is not None]
+
     def prefetch_stats(self):
-        """Prefetcher counters (hits/misses/invalidated) or None when the
-        serial path ran — evidence hook for bench/tests; the durations are
-        the timer's ``prefetch_wait`` and ``produce``."""
-        return self._prefetch[0].stats() if self._prefetch else None
+        """Prefetcher counters (hits/misses/invalidated), summed over the
+        prefetchers built, or None when the serial path ran — evidence
+        hook for bench/tests; the durations are the timer's
+        ``prefetch_wait`` and ``produce``."""
+        out = None
+        for pf in self._prefetchers():
+            stats = pf.stats()
+            out = stats if out is None else {k: out[k] + v
+                                             for k, v in stats.items()}
+        return out
 
     def release_prefetch(self):
         """Drop every speculative slot (their device buffers) without
-        stopping the worker — for callers driving ``run_round`` in
-        patterns the ``comm_round`` speculation clamp can't see."""
-        if self._prefetch is not None:
-            self._prefetch[0].invalidate()
+        stopping the workers — for callers driving ``run_round`` (or a
+        mesh's ``run_rounds_fused``) in patterns the ``comm_round``
+        speculation clamp can't see."""
+        for pf in self._prefetchers():
+            pf.invalidate()
 
     def fused_rounds(self, device_sampling: bool = False) -> "FusedRounds":
         """The fused multi-round driver PAIRED with this API class
@@ -489,7 +564,8 @@ class FedAvgAPI:
             raise TypeError(
                 f"{type(self).__name__} cannot fuse rounds: its round has "
                 "a host-side stage (e.g. the secure share exchange) that "
-                "cannot run inside a scan")
+                "cannot run inside a scan, or is not the single-device "
+                "program FusedRounds scans (a mesh: train_fused)")
         if self._obs is not None:
             # per-round boundaries don't exist inside a fused scan — say
             # so instead of leaving an empty timeline to be discovered
@@ -511,10 +587,9 @@ class FedAvgAPI:
         packed slots pinning HBM."""
         pf = self._round_prefetcher()
         if pf is None:
-            with self.timer.phase("produce"):
-                out = self._prepare_round(round_idx)
+            _, idxs, args = self._pack_round(round_idx)
             self.timer.update_rss()  # consume() samples it on the
-            return out               # pipelined path; mirror it here
+            return idxs, args        # pipelined path; mirror it here
         from fedml_tpu.parallel.prefetch import consume
         _, idxs, args = consume(pf, round_idx, self.timer, self.dataset,
                                 self._pack_round,
@@ -531,32 +606,33 @@ class FedAvgAPI:
         # everything queued: was it starved while the host made this
         # round's inputs?
         with self.timer.starved_probe(jax.tree.leaves(self.variables)[0]):
-            idxs, (x, y, mask, keys, weights, agg_key) = \
-                self._host_round_inputs(round_idx)
+            idxs, args = self._host_round_inputs(round_idx)
         if self._obs is not None:
             # one-shot roofline probe (obs/perf.py): the analytic FLOP
             # count of THE round program about to dispatch, traced from
-            # the live inputs BEFORE any donation invalidates them.
-            # Tracing touches no RNG/device state — a pure observer.
+            # the live inputs (on a mesh at GLOBAL shapes, so the count is
+            # the whole mesh's, as the fleet peak is) BEFORE any donation
+            # invalidates them. Tracing touches no RNG/device state — a
+            # pure observer.
             from fedml_tpu.utils.flops import analytic_flops
             fn = getattr(self, "_round_fn_py", None) or self._round_fn
+            operands = self._round_operands(args, round_idx)
             self._obs.probe_round_flops(
-                lambda: analytic_flops(fn, self.variables, x, y, mask,
-                                       keys, weights, agg_key,
-                                       jnp.uint32(round_idx)),
+                lambda: analytic_flops(fn, self.variables, *operands),
                 source="analytic_conv_gn_jaxpr")
-        rows = self._rows_stepped(idxs, x.shape[1])
+        # the rows the round program runs: mesh and length padding and all
+        # where the slots are trained whole, the tiers' bounds otherwise
+        x = args[0]
+        rows = self._rows_stepped(self._pad_round(idxs)[0], x.shape[1])
         self.timer.count("rows_dispatched", rows)
         if x.ndim == 3 and jnp.issubdtype(x.dtype, jnp.integer):
             # rows of token ids: the positions the round steps through
             self.timer.count("tokens_dispatched", rows * x.shape[2])
-        if self.config.fold_clients:
+        if getattr(self.config, "fold_clients", False):
             self.timer.count("clients_folded", len(idxs))
         with self.timer.phase("dispatch"):
-            self.variables, stats = self._round_fn(self.variables, x, y,
-                                                   mask, keys, weights,
-                                                   agg_key,
-                                                   jnp.uint32(round_idx))
+            self.variables, stats = self._round_fn(
+                self.variables, *self._round_operands(args, round_idx))
         rec = self.timer.end_round(
             round_idx, extra={"cohort": [int(i) for i in idxs]})
         if self._obs is not None:
@@ -567,9 +643,14 @@ class FedAvgAPI:
 
     # -- the outer loop (reference fedavg_api.py:46-95) ---------------------
     def train(self) -> Dict:
+        return self._train_rounds(0)
+
+    def _train_rounds(self, start: int, after_round=None) -> Dict:
+        """Rounds ``start .. comm_round - 1`` with the evaluation cadence;
+        ``after_round(round_idx)`` runs at the end of each (a checkpoint)."""
         cfg = self.config
         t0 = time.time()
-        for round_idx in range(cfg.comm_round):
+        for round_idx in range(start, cfg.comm_round):
             _, train_stats = self.run_round(round_idx)
             # dispatch is an async enqueue; the wall clock here still tracks
             # real progress because the host blocks once the device queue
@@ -597,6 +678,8 @@ class FedAvgAPI:
                             for k, v in self.timer.means().items()})
                 self.history.append(rec)
                 logging.info("round %d: %s", round_idx, rec)
+            if after_round is not None:
+                after_round(round_idx)
         return self.history[-1] if self.history else {}
 
     # -- evaluation (reference _local_test_on_all_clients; the per-client
@@ -644,7 +727,7 @@ class FusedRounds:
     - **full participation** (``client_num_per_round == client_num``): data
       is packed and uploaded once; per-round/per-client RNG keys are derived
       *inside* the scan by the same ``fold_in`` chain the host loop uses
-      (FedAvgAPI._prepare_round), so the fused trajectory is equal to the
+      (FedAvgAPI._pack_round), so the fused trajectory is equal to the
       host loop's round for round.
     - **block sampling** (the default when ``client_num_per_round <
       client_num``): the R cohorts are drawn host-side UP FRONT with the
@@ -898,7 +981,7 @@ def _audit_round_fn() -> AuditSpec:
             train=TrainConfig(epochs=1, batch_size=8)))
 
     def inputs(r):
-        _, (x, y, mask, keys, w, agg_key) = api._prepare_round(r)
+        _, _, (x, y, mask, keys, w, agg_key) = api._pack_round(r)
         return (api.variables, x, y, mask, keys, w, agg_key, jnp.uint32(r))
 
     return AuditSpec(fn=api._round_fn, sweep=[inputs(r) for r in range(3)],

@@ -153,7 +153,7 @@ def _audit_fedopt_round() -> AuditSpec:
             train=TrainConfig(epochs=1, batch_size=8)))
 
     def inputs(r):
-        _, (x, y, mask, keys, w, _) = api._prepare_round(r)
+        _, _, (x, y, mask, keys, w, _) = api._pack_round(r)
         return (api.variables, api.server_opt_state, x, y, mask, keys, w,
                 jnp.uint32(r))
 

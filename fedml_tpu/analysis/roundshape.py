@@ -219,8 +219,8 @@ _STAGE_RULES: Dict[str, List[Tuple[str, Tuple[str, ...]]]] = {
     ],
     "pack": [
         ("pad_and_mask_pack", ("pack_clients",)),
-        ("shared_fedavg_pack", ("_host_round_inputs", "_prepare_round",
-                                "_pack_cohort", "_pack_round")),
+        ("shared_fedavg_pack", ("_host_round_inputs", "_pack_round",
+                                "_pack_cohort")),
         ("per_client_host_batches", ("train_data_local_dict",)),
     ],
     "train": [

@@ -88,6 +88,14 @@ class _RawPeer:
         never-connected ``accept()``) so the port can be rebound by the
         next stage immediately instead of leaking for the process
         lifetime."""
+        try:
+            # on Linux a close() alone does not wake an accept() already
+            # blocked in the serve thread: the thread, and with it the
+            # bound port, would outlive this call
+            self._server.shutdown(socket.SHUT_RDWR)
+        # ft: allow[FT007] second close(): the listener is already gone
+        except OSError:
+            pass
         self._server.close()
         self._thread.join(timeout=1.0)
 

@@ -87,7 +87,7 @@ def round_keys(base_key, round_idx, client_ids):
     ``round_key = fold_in(base, round)``, per-client training keys
     ``fold_in(round_key, client_id)``, and the aggregation key at the
     ``AGG_KEY_SENTINEL`` fold. One definition — host loop
-    (FedAvgAPI._prepare_round), fused scans (FusedRounds), and mesh scans
+    (FedAvgAPI._pack_round), fused scans (FusedRounds), and mesh scans
     (make_spmd_multiround) all call it, so host/fused/mesh trajectory
     parity cannot drift. ``client_ids`` must be uint32 (traced or host).
 

@@ -23,13 +23,16 @@ Scaling model (how this maps to hardware):
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Optional, Tuple
+import logging
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from fedml_tpu.algorithms.fedavg import (FedAvgAPI, _normalized,
+                                         make_vmapped_clients)
 from fedml_tpu.core.sampling import (eval_subsample, round_keys,
                                      sample_clients)
 from fedml_tpu.data.base import FederatedDataset
@@ -77,6 +80,26 @@ def _weighted_psum_mean(stacked, weights, axes: Tuple[str, ...]):
     return jax.tree.map(lambda s: s / wtot.astype(s.dtype), wsum)
 
 
+def _make_shard_round(module, task: str, cfg: TrainConfig,
+                      axes: Tuple[str, ...]):
+    """One chip's half of a flat mesh round, for a ``shard_map`` body: its
+    shard of the cohort through the shared vmapped clients
+    (``algorithms.fedavg.make_vmapped_clients`` - no ``tier_clients`` yet:
+    a size-ordered cohort sharded contiguously would put every long client
+    on chip 0), the FedAvg mean and the stat totals each a ``psum`` over
+    ``axes``."""
+    clients = make_vmapped_clients(make_local_train(module, task, cfg))
+
+    def shard_round(variables, x, y, mask, keys, weights, lr_scale):
+        stacked, stats = clients(variables, x, y, mask, keys, lr_scale)
+        new_vars = _weighted_psum_mean(stacked, weights, axes)
+        totals = jax.tree.map(
+            lambda s: jax.lax.psum(jnp.sum(s, axis=0), axes), stats)
+        return new_vars, totals
+
+    return shard_round
+
+
 def make_spmd_round(module, task: str, cfg: TrainConfig, mesh: Mesh,
                     axis: str = "clients", donate: bool = False,
                     check_vma: bool = True):
@@ -91,7 +114,7 @@ def make_spmd_round(module, task: str, cfg: TrainConfig, mesh: Mesh,
     model (the driver overwrites its reference each round); leave False when
     the caller reuses the same variables across calls (parity tests).
     """
-    local_train = make_local_train(module, task, cfg)
+    shard_round = _make_shard_round(module, task, cfg, (axis,))
     decayed = cfg.lr_decay_round != 1.0
 
     def body(variables, x, y, mask, keys, weights, *maybe_r):
@@ -101,14 +124,7 @@ def make_spmd_round(module, task: str, cfg: TrainConfig, mesh: Mesh,
         # so sim==mesh parity holds under the schedule too); None traces
         # the identical constant-LR program
         scale = round_lr_scale(cfg, maybe_r[0]) if decayed else None
-        stacked, stats = jax.vmap(
-            lambda v, xc, yc, mc, kc: local_train(
-                v, xc, yc, mc, kc, lr_scale=scale),
-            in_axes=(None, 0, 0, 0, 0))(variables, x, y, mask, keys)
-        new_vars = _weighted_psum_mean(stacked, weights, (axis,))
-        totals = jax.tree.map(
-            lambda s: jax.lax.psum(jnp.sum(s, axis=0), axis), stats)
-        return new_vars, totals
+        return shard_round(variables, x, y, mask, keys, weights, scale)
 
     sharded = P(axis)
     in_specs = (P(), sharded, sharded, sharded, sharded, sharded)
@@ -139,7 +155,7 @@ def make_spmd_multiround(module, task: str, cfg: TrainConfig, mesh: Mesh,
     as in make_spmd_round and ``client_ids`` the uint32 global client ids
     of the local slots (used only for key derivation).
     """
-    local_train = make_local_train(module, task, cfg)
+    shard_round = _make_shard_round(module, task, cfg, (axis,))
 
     def body(variables, x, y, mask, client_ids, weights, base_key, r0):
         # client_ids/x/y/mask/weights are sharded inputs — already
@@ -148,15 +164,8 @@ def make_spmd_multiround(module, task: str, cfg: TrainConfig, mesh: Mesh,
 
         def one_round(vars_r, r):
             _, keys, _ = round_keys(base_key, r, client_ids)
-            scale = round_lr_scale(cfg, r)
-            stacked, stats = jax.vmap(
-                lambda v, xc, yc, mc, kc: local_train(
-                    v, xc, yc, mc, kc, lr_scale=scale),
-                in_axes=(None, 0, 0, 0, 0))(vars_r, x, y,
-                                            mask, keys)
-            new_vars = _weighted_psum_mean(stacked, weights, (axis,))
-            totals = jax.tree.map(
-                lambda s: jax.lax.psum(jnp.sum(s, axis=0), axis), stats)
+            new_vars, totals = shard_round(vars_r, x, y, mask, keys,
+                                           weights, round_lr_scale(cfg, r))
             # re-vary: the psum result is replicated-typed, the next scan
             # step consumes it as the (device-varying) client input again
             return _pvary(new_vars, (axis,)), totals
@@ -203,7 +212,7 @@ def make_spmd_block_multiround(module, task: str, cfg: TrainConfig,
     core/sampling.round_keys — trajectory parity with R ``run_round``
     calls is exact).
     """
-    local_train = make_local_train(module, task, cfg)
+    shard_round = _make_shard_round(module, task, cfg, (axis,))
 
     def body(variables, xs, ys, masks, idsR, weightsR, base_key, r0):
         variables = _pvary(variables, (axis,))
@@ -211,15 +220,8 @@ def make_spmd_block_multiround(module, task: str, cfg: TrainConfig,
         def one_round(vars_r, inp):
             r, x, y, mask, ids, weights = inp
             _, keys, _ = round_keys(base_key, r, ids)
-            scale = round_lr_scale(cfg, r)
-            stacked, stats = jax.vmap(
-                lambda v, xc, yc, mc, kc: local_train(
-                    v, xc, yc, mc, kc, lr_scale=scale),
-                in_axes=(None, 0, 0, 0, 0))(vars_r, x, y,
-                                            mask, keys)
-            new_vars = _weighted_psum_mean(stacked, weights, (axis,))
-            totals = jax.tree.map(
-                lambda s: jax.lax.psum(jnp.sum(s, axis=0), axis), stats)
+            new_vars, totals = shard_round(vars_r, x, y, mask, keys,
+                                           weights, round_lr_scale(cfg, r))
             return _pvary(new_vars, (axis,)), totals
 
         rs = r0 + jnp.arange(xs.shape[0], dtype=jnp.uint32)
@@ -270,7 +272,7 @@ def make_hierarchical_spmd_round(module, task: str, cfg: TrainConfig,
         raise NotImplementedError(
             "lr_decay_round is not defined for the 2-tier round (ambiguous "
             "round index); use the flat FedAvg drivers for the schedule")
-    local_train = make_local_train(module, task, cfg)
+    clients = make_vmapped_clients(make_local_train(module, task, cfg))
 
     def body(variables, x, y, mask, keys, weights):
         # carry type: group-varying; per-client variation is introduced at the
@@ -279,9 +281,7 @@ def make_hierarchical_spmd_round(module, task: str, cfg: TrainConfig,
 
         def scan_body(vars_g, rkeys):
             local_vars = _pvary(vars_g, ("clients",))
-            stacked, stats = jax.vmap(
-                local_train, in_axes=(None, 0, 0, 0, 0))(local_vars, x, y,
-                                                         mask, rkeys)
+            stacked, stats = clients(local_vars, x, y, mask, rkeys)
             agg = _weighted_psum_mean(stacked, weights, ("clients",))
             return agg, stats
 
@@ -353,24 +353,31 @@ class DistributedFedAvgConfig:
     mesh_shape: Optional[Dict[str, int]] = None
 
 
-class DistributedFedAvgAPI:
+class DistributedFedAvgAPI(FedAvgAPI):
     """Distributed FedAvg driver (parity: FedML_FedAvg_distributed,
     FedAvgAPI.py:20) — outer loop on the host, round on the mesh.
 
+    It is ``FedAvgAPI``'s round driver placed on a mesh: the host half of a
+    round is inherited, and this class overrides only what a mesh changes.
     Sampled-client shards are placed with
     ``NamedSharding(mesh, P('clients'))`` so each device receives only its
-    clients' data (the client-virtualization gather, FedAVGTrainer.py:25-30).
+    clients' data (the client-virtualization gather, FedAVGTrainer.py:25-30),
+    the cohort is padded to a multiple of the mesh, the round and eval
+    programs are the mesh's, and evaluation is the sharded test union.
     """
+
+    _job_prefix = "spmd"
+    _prefetch_slots = ("_prefetch", "_block_prefetch")
+    # FusedRounds scans the single-device round; the mesh's fused path is
+    # run_rounds_fused / train_fused below
+    _fused_driver_cls = None
 
     def __init__(self, dataset: FederatedDataset, module,
                  task: str = "classification", mesh: Optional[Mesh] = None,
                  config: Optional[DistributedFedAvgConfig] = None):
-        self.dataset = dataset
-        self.module = module
-        self.task = task
-        self.config = config or DistributedFedAvgConfig()
-        mp = self.config.model_parallel
-        mesh_shape = getattr(self.config, "mesh_shape", None)
+        config = config or DistributedFedAvgConfig()
+        mp = config.model_parallel
+        mesh_shape = getattr(config, "mesh_shape", None)
         if mp and mp not in ("tp", "fsdp"):
             raise ValueError(f"unknown model_parallel: {mp!r}")
         if mp and mesh_shape:
@@ -378,18 +385,13 @@ class DistributedFedAvgAPI:
                 "mesh_shape supersedes model_parallel — declare the mp "
                 "axis on the named mesh instead, e.g. "
                 "mesh_shape={'data': n, 'tp': k}")
-        if (mp or mesh_shape) and self.config.train.lr_decay_round != 1.0:
+        if (mp or mesh_shape) and config.train.lr_decay_round != 1.0:
             raise NotImplementedError(
                 "lr_decay_round is not threaded through the model-parallel "
                 "(gspmd) round; use the flat clients-axis mesh")
-        if self.config.pack not in ("cohort", "global"):
-            raise ValueError(f"unknown pack policy: {self.config.pack!r}")
-        from fedml_tpu.trainer.functional import validate_accum_steps
-        validate_accum_steps(self.config.train,
-                             dataset.train_data_local_num_dict)
         if mesh is None and mp:
             devs = jax.devices()
-            k = self.config.mp_size
+            k = config.mp_size
             if len(devs) % k != 0:
                 raise ValueError(
                     f"mp_size {k} must divide device count {len(devs)}")
@@ -419,11 +421,24 @@ class DistributedFedAvgAPI:
         # round/eval slots pad to the FEDERATION axis ('clients', or
         # 'data' on the named mesh — == all devices when 1-D)
         self.n_dev = int(self.mesh.shape[self._data_axis])
+        self._data_sharding = NamedSharding(self.mesh, P(self._data_axis))
+        # fused-block prefetcher (parallel/prefetch.py), built lazily like
+        # the base's cohort one
+        self._block_prefetch = None
+        super().__init__(dataset, module, task=task, config=config)
+
+    # -- what a mesh changes of the round driver ---------------------------
+    def _build_programs(self, aggregate_hook) -> None:
+        """The mesh's round and eval programs, and the fresh model
+        committed to the layout they were built for."""
+        module, task, cfg = self.module, self.task, self.config
+        mp = cfg.model_parallel
+        self._tier_clients = None  # no tiers on a mesh yet (ROADMAP S3)
         if self._layout is not None:
             from fedml_tpu.parallel.mesh import (make_mesh_eval,
                                                  make_mesh_federated_round)
             self._round_fn, self._shard_params = make_mesh_federated_round(
-                module, task, self.config.train, self.mesh, self._layout,
+                module, task, cfg.train, self.mesh, self._layout,
                 donate=True)
             self._eval_fn = make_mesh_eval(module, task, self.mesh,
                                            self._layout)
@@ -437,7 +452,7 @@ class DistributedFedAvgAPI:
                 from fedml_tpu.parallel.fsdp import fsdp_param_specs
                 specs_fn = fsdp_param_specs(int(self.mesh.shape["fsdp"]))
             self._round_fn, self._shard_params = \
-                make_sharded_federated_round(module, task, self.config.train,
+                make_sharded_federated_round(module, task, cfg.train,
                                              self.mesh, specs_fn,
                                              donate=True)
             self._eval_fn = make_gspmd_eval(module, task, self.mesh,
@@ -450,17 +465,11 @@ class DistributedFedAvgAPI:
             # and run with the check off (correctness held by the
             # sim==mesh parity tests) — every other model keeps the guard
             self._check_vma = not getattr(module, "flax_rnn_carry", False)
-            self._round_fn = make_spmd_round(module, task, self.config.train,
+            self._round_fn = make_spmd_round(module, task, cfg.train,
                                              self.mesh, donate=True,
                                              check_vma=self._check_vma)
             self._eval_fn = make_sharded_eval(module, task, self.mesh,
                                               check_vma=self._check_vma)
-        self._n_pad = dataset.padded_len(self.config.train.batch_size)
-        self._base_key = jax.random.key(self.config.seed)
-        self._data_sharding = NamedSharding(self.mesh, P(self._data_axis))
-        sample_x = dataset.train_data_global[0][:1]
-        self.variables = module.init(jax.random.key(self.config.seed),
-                                     jnp.asarray(sample_x), train=False)
         if self._shard_params is not None:  # place into the TP/FSDP layout
             self.variables = self._shard_params(self.variables)
         else:
@@ -471,38 +480,43 @@ class DistributedFedAvgAPI:
             # chip run paid 33.8 s, then 24.7 s)
             self.variables = jax.device_put(
                 self.variables, NamedSharding(self.mesh, P()))
-        self.history: List[Dict] = []
-        from fedml_tpu.utils.tracing import RoundTimer
-        self.timer = RoundTimer()  # pack/dispatch means, as FedAvgAPI
-        # observability (fedml_tpu/obs): per-round flight timeline +
-        # slow-round anomaly profiling; config.obs_dir None = off
-        from fedml_tpu.obs import build_observability, default_job_id
-        self._obs = build_observability(
-            getattr(self.config, "obs_dir", None),
-            # collision-safe default (see obs.default_job_id): unset
-            # job ids must not collide in a shared obs dir
-            job_id=(getattr(self.config, "job_id", None)
-                    or default_job_id("spmd")),
-            rank=0, role="server",
-            # fleet MFU denominator: the WHOLE mesh (data x fsdp x tp),
-            # not just the federation axis — an fsdp/tp round must never
-            # report single-chip MFU. Kind read from a mesh device so a
-            # mixed host (CPU coordinator + TPU mesh) rates the mesh.
-            perf_device_count=int(self.mesh.size),
-            perf_device=self.mesh.devices.flat[0])
-        if self._obs is not None:
-            self._obs.bind_timer(self.timer)
-        # same-cohort device cache as FedAvgAPI._pack_cache: full
-        # participation re-samples the identical set each round, so the
-        # sharded x/y/mask/weights can stay resident across rounds
-        self._pack_cache = None
-        # eval union: padded to a mesh multiple, sharded, device-resident
-        self._eval_cache = None
-        # cohort / fused-block prefetchers (parallel/prefetch.py), built
-        # lazily; each is (prefetcher, dataset-at-build) so a mid-run
-        # dataset swap invalidates in-flight slots like _pack_cache
-        self._prefetch = None
-        self._block_prefetch = None
+
+    def _round_devices(self) -> list:
+        return list(self.mesh.devices.flat)
+
+    def _put(self, a):
+        return jax.device_put(jnp.asarray(a), self._data_sharding)
+
+    def _pad_round(self, idxs):
+        """Pad the sampled-client list to a mesh-size multiple with
+        zero-weight duplicate slots (masked out of the aggregation)."""
+        idxs = np.asarray(idxs)
+        P_round = len(idxs)
+        rem = (-P_round) % self.n_dev
+        if rem == 0:
+            return idxs, np.ones(P_round, np.float32)
+        padded = np.concatenate([idxs, np.repeat(idxs[-1:], rem)])
+        alive = np.concatenate([np.ones(P_round), np.zeros(rem)])
+        return padded, alive.astype(np.float32)
+
+    def _round_inputs(self, x, y, mask, keys, weights, agg_key) -> tuple:
+        # the psum mean draws nothing: the mesh programs take no key for it
+        return x, y, mask, keys, weights
+
+    def _round_operands(self, args: tuple, round_idx: int) -> tuple:
+        # the decayed builder takes the replicated round index as its final
+        # operand (make_spmd_round's conditional spec)
+        if self.config.train.lr_decay_round != 1.0:
+            return args + (jnp.uint32(round_idx),)
+        return args
+
+    def evaluate(self, round_idx: int) -> Dict:
+        """Test metrics over the sharded test union (``_eval_global``)."""
+        rec = {"round": round_idx}
+        stats = self._eval_global()
+        if stats is not None:
+            rec.update(_normalized(stats, "test"))
+        return rec
 
     def _eval_global(self):
         xt, yt = self.dataset.test_data_global
@@ -523,190 +537,16 @@ class DistributedFedAvgAPI:
                            [(0, pad)] + [(0, 0)] * (yt.ndim - 1))
                 m = np.concatenate([np.ones(n, np.float32),
                                     np.zeros(pad, np.float32)])
-                put = lambda a: jax.device_put(jnp.asarray(a),
-                                               self._data_sharding)
-                self._eval_cache = (self.dataset, (put(x), put(y), put(m)))
+                # eval union: padded to a mesh multiple, sharded, resident
+                self._eval_cache = (self.dataset, (self._put(x),
+                                                   self._put(y),
+                                                   self._put(m)))
             x, y, m = self._eval_cache[1]
             # every caller reads the sums as host floats next; waiting here
             # makes the span the evaluation and not its enqueue
             # ft: allow[FT003] eval-boundary sync, inside the eval phase
             return jax.block_until_ready(
                 self._eval_fn(self.variables, x, y, m))
-
-    def _pad_round(self, idxs: np.ndarray):
-        """Pad the sampled-client list to a mesh-size multiple with
-        zero-weight duplicate slots (masked out of the aggregation)."""
-        P_round = len(idxs)
-        rem = (-P_round) % self.n_dev
-        if rem == 0:
-            return idxs, np.ones(P_round, np.float32)
-        padded = np.concatenate([idxs, np.repeat(idxs[-1:], rem)])
-        alive = np.concatenate([np.ones(P_round), np.zeros(rem)])
-        return padded, alive.astype(np.float32)
-
-    def _pack_cohort(self, idxs, dataset=None):
-        """Cache-free pad + pack + sharded upload of one sampled cohort
-        (thread-safe: no shared mutable state — the prefetcher worker runs
-        this concurrently with the main thread's dispatch)."""
-        cfg = self.config
-        ds = dataset if dataset is not None else self.dataset
-        with self.timer.phase("pack"):
-            padded, alive = self._pad_round(np.asarray(idxs))
-            n_pad = (ds.cohort_padded_len(padded, cfg.train.batch_size)
-                     if cfg.pack == "cohort" else self._n_pad)
-            x, y, mask = ds.pack_clients(padded, cfg.train.batch_size,
-                                         n_pad=n_pad)
-            mask = mask * alive[:, None]
-            weights = ds.client_weights(padded) * alive
-        with self.timer.phase("upload"):
-            put = lambda a: jax.device_put(jnp.asarray(a),
-                                           self._data_sharding)
-            return padded, (put(x), put(y), put(mask), put(weights))
-
-    def _pack_round(self, round_idx: int):
-        """Full host side of one round (sampling, pack, upload, sharded
-        per-client keys) as a function of the round index — the
-        prefetcher's ``produce``. The dataset reference is snapshot once
-        so a concurrent swap can't mix arrays; the payload carries it for
-        the caller's identity check."""
-        ds = self.dataset
-        with self.timer.phase("produce"):
-            idxs = sample_clients(round_idx, ds.client_num,
-                                  self.config.client_num_per_round)
-            padded, (xd, yd, maskd, wd) = self._pack_cohort(idxs,
-                                                            dataset=ds)
-            _, keys, _ = round_keys(
-                self._base_key, round_idx,
-                jnp.asarray(np.asarray(padded), dtype=jnp.uint32))
-            keysd = jax.device_put(keys, self._data_sharding)
-        return ds, idxs, (xd, yd, maskd, keysd, wd)
-
-    def _round_prefetcher(self):
-        """Cohort prefetcher, or None for the serial path (depth 0 via
-        config or the $FEDML_TPU_PREFETCH kill switch, or full
-        participation where _pack_cache already keeps the cohort
-        resident)."""
-        from fedml_tpu.parallel.prefetch import (RoundPrefetcher,
-                                                 bind_prefetcher,
-                                                 resolve_prefetch_depth)
-        depth = resolve_prefetch_depth(
-            getattr(self.config, "prefetch_depth", 0))
-        if (depth <= 0 or self.config.client_num_per_round
-                >= self.dataset.client_num):
-            if self._prefetch is not None:
-                # kill switch flipped mid-run: free the resident slots
-                self._prefetch[0].invalidate()
-            return None
-        self._prefetch = bind_prefetcher(
-            self._prefetch, self.dataset,
-            lambda: RoundPrefetcher(self._pack_round, depth,
-                                    name="mesh-cohort-prefetch"))
-        return self._prefetch[0]
-
-    def prefetch_stats(self):
-        """Merged cohort + block prefetcher counters (hits/misses/
-        invalidated), or None when every round ran the serial path —
-        evidence hook for bench/tests."""
-        out = None
-        for pf in (self._prefetch, self._block_prefetch):
-            if pf is None:
-                continue
-            stats = pf[0].stats()
-            if out is None:
-                out = stats
-            else:
-                for k, v in stats.items():
-                    out[k] = out[k] + v
-        return out
-
-    def release_prefetch(self):
-        """Drop every speculative slot (their device buffers — a block
-        slot is a whole ``[R, P, n_pad, ...]`` sharded window) without
-        stopping the workers. ``train``/``train_fused`` end clean on
-        their own (the speculation clamp / final ``()`` window), but a
-        DIRECT ``run_rounds_fused`` loop leaves its last speculative
-        window resident — call this when it finishes if you need the HBM
-        back before the API dies."""
-        for pf in (self._prefetch, self._block_prefetch):
-            if pf is not None:
-                pf[0].invalidate()
-
-    def _host_round_inputs(self, round_idx: int):
-        """Pipelined-or-serial host inputs for one round, as
-        ``FedAvgAPI._host_round_inputs``: the prefetcher's slot, or (depth
-        0, full participation) the serial pack with its resident
-        ``_pack_cache`` cohort."""
-        pf = self._round_prefetcher()
-        if pf is not None:
-            from fedml_tpu.parallel.prefetch import consume
-            _, idxs, args = consume(pf, round_idx, self.timer,
-                                    self.dataset, self._pack_round,
-                                    round_bound=self.config.comm_round)
-            return idxs, args
-        with self.timer.phase("produce"):
-            cfg = self.config
-            idxs = sample_clients(round_idx, self.dataset.client_num,
-                                  cfg.client_num_per_round)
-            cohort = tuple(int(i) for i in idxs)
-            if (self._pack_cache is not None
-                    and self._pack_cache[0] is self.dataset
-                    and self._pack_cache[1] == cohort):
-                padded, xd, yd, maskd, wd = self._pack_cache[2]
-            else:
-                self._pack_cache = None
-                padded, (xd, yd, maskd, wd) = self._pack_cohort(idxs)
-                if len(idxs) == self.dataset.client_num:
-                    self._pack_cache = (self.dataset, cohort,
-                                        (padded, xd, yd, maskd, wd))
-            _, keys, _ = round_keys(
-                self._base_key, round_idx,
-                jnp.asarray(np.asarray(padded), dtype=jnp.uint32))
-            keysd = jax.device_put(keys, self._data_sharding)
-        return idxs, (xd, yd, maskd, keysd, wd)
-
-    def run_round(self, round_idx: int):
-        # flight-recorder round boundary (fedml_tpu/obs) — same pure-
-        # observer wiring as FedAvgAPI.run_round
-        self.timer.begin_round(round_idx)
-        if self._obs is not None:
-            self._obs.round_begin(round_idx)
-        # as FedAvgAPI.run_round: was the device starved meanwhile?
-        with self.timer.starved_probe(jax.tree.leaves(self.variables)[0]):
-            idxs, (xd, yd, maskd, keysd, wd) = self._host_round_inputs(
-                round_idx)
-        decayed = self.config.train.lr_decay_round != 1.0
-        if self._obs is not None:
-            # one-shot roofline probe (obs/perf.py): trace the sharded
-            # round program at GLOBAL shapes — analytic_flops then counts
-            # the whole-mesh FLOPs, matching the fleet peak the perf
-            # accountant was built with (perf_device_count=mesh.size).
-            # Traced before dispatch so donation can't invalidate inputs.
-            from fedml_tpu.utils.flops import analytic_flops
-            args = ((self.variables, xd, yd, maskd, keysd, wd,
-                     jnp.uint32(round_idx)) if decayed
-                    else (self.variables, xd, yd, maskd, keysd, wd))
-            self._obs.probe_round_flops(
-                lambda: analytic_flops(self._round_fn, *args),
-                source="analytic_conv_gn_jaxpr")
-        # the slots the round program runs, mesh and length padding and all
-        self.timer.count("rows_dispatched", xd.shape[0] * xd.shape[1])
-        with self.timer.phase("dispatch"):
-            if decayed:
-                # decayed builder takes the replicated round index as its
-                # final operand (make_spmd_round's conditional spec)
-                self.variables, stats = self._round_fn(
-                    self.variables, xd, yd, maskd, keysd, wd,
-                    jnp.uint32(round_idx))
-            else:
-                self.variables, stats = self._round_fn(
-                    self.variables, xd, yd, maskd, keysd, wd)
-        rec = self.timer.end_round(
-            round_idx, extra={"cohort": [int(i) for i in idxs]})
-        if self._obs is not None:
-            self._obs.round_end(round_idx,
-                                rec["duration_s"] if rec else None,
-                                record=rec)
-        return idxs, stats
 
     def run_rounds_fused(self, r0: int, rounds: int, next_window=None):
         """Advance the model by ``rounds`` rounds in ONE device dispatch.
@@ -759,8 +599,7 @@ class DistributedFedAvgAPI:
                 padded, cfg.train.batch_size, n_pad=self._n_pad)
             mask = mask * alive[:, None]
             weights = self.dataset.client_weights(padded) * alive
-            put = lambda a: jax.device_put(jnp.asarray(a),
-                                           self._data_sharding)
+            put = self._put
             # keyed by dataset identity like _pack_cache/_eval_cache: a
             # mid-run dataset swap must invalidate the resident arrays
             self._fused_data = (self.dataset,
@@ -876,10 +715,8 @@ class DistributedFedAvgAPI:
         0, freq, 2*freq, ..., and the last round — the same cadence as
         ``train()``, so fused and host histories line up (the mesh analogue
         of FusedRounds.train)."""
-        from fedml_tpu.algorithms.fedavg import _normalized
         cfg = self.config
         if self._obs is not None:
-            import logging
             # same caveat as FedAvgAPI.fused_rounds: fused scans have no
             # per-round host boundary to record
             logging.warning(
@@ -912,68 +749,42 @@ class DistributedFedAvgAPI:
                        else ())
                 stats = self.run_rounds_fused(w0, chunk, next_window=nxt)
                 wi += 1
-            rec = {"round": e,
-                   "train_loss_local": (
-                       float(stats["loss_sum"][-1])
-                       / max(1.0, float(stats["count"][-1])))}
             with self.timer.phase("device_wait"):
                 # ft: allow[FT003] eval-boundary sync, by design
                 jax.block_until_ready(self.variables)
-            test_stats = self._eval_global()
-            if test_stats is not None:
-                rec.update(_normalized(test_stats, "test"))
+            rec = self.evaluate(e)
+            rec["train_loss_local"] = (
+                float(stats["loss_sum"][-1])
+                / max(1.0, float(stats["count"][-1])))
             self.history.append(rec)
         return self.history[-1] if self.history else {}
 
     def train(self, checkpoint_mgr=None, resume: bool = False) -> Dict:
-        """Round loop with optional round-level checkpoint/resume: client
-        sampling and per-client RNG are (seed, round)-derived, so restarting
-        from ``(round_idx, variables)`` is bit-identical to never stopping
-        (utils/checkpoint.py)."""
-        import time
-
-        from fedml_tpu.algorithms.fedavg import _normalized, _progress_log
-        cfg = self.config
-        if (checkpoint_mgr is not None and self._obs is not None
-                and getattr(cfg, "job_id", None) is None):
+        """The base's round loop with optional round-level
+        checkpoint/resume: client sampling and per-client RNG are (seed,
+        round)-derived, so restarting from ``(round_idx, variables)`` is
+        bit-identical to never stopping (utils/checkpoint.py)."""
+        if checkpoint_mgr is None:
+            return self._train_rounds(0)
+        if self._obs is not None and getattr(self.config, "job_id",
+                                             None) is None:
             # re-key the derived default id onto the run's durable
             # namespace BEFORE any record lands: a crash-resumed leg must
             # rejoin its own flight timeline, not fork a phantom second
             # job under a fresh nonce (obs.default_job_id stable_key)
             from fedml_tpu.obs import default_job_id
             self._obs.recorder.job_id = default_job_id(
-                "spmd", stable_key=checkpoint_mgr.directory)
-        t0 = time.time()
+                self._job_prefix, stable_key=checkpoint_mgr.directory)
         start = 0
-        if checkpoint_mgr is not None and resume:
+        if resume:
             restored = checkpoint_mgr.restore_latest(
                 {"variables": self.variables})
             if restored:
                 state, meta = restored
                 self.variables = state["variables"]
                 start = meta["round_idx"]
-        for round_idx in range(start, cfg.comm_round):
-            _, stats = self.run_round(round_idx)
-            _progress_log.info("round %d/%d dispatched (wall %.1fs)",
-                               round_idx + 1, cfg.comm_round,
-                               time.time() - t0)
-            last = round_idx == cfg.comm_round - 1
-            if round_idx % cfg.frequency_of_the_test == 0 or last:
-                rec = {"round": round_idx,
-                       "train_loss_local": float(stats["loss_sum"]) / max(
-                           1.0, float(stats["count"]))}
-                with self.timer.phase("device_wait"):
-                    # ft: allow[FT003] eval-boundary sync, by design
-                    jax.block_until_ready(self.variables)
-                test_stats = self._eval_global()
-                if test_stats is not None:
-                    rec.update(_normalized(test_stats, "test"))
-                rec["wall_s"] = time.time() - t0  # as FedAvgAPI.train
-                self.history.append(rec)
-            if checkpoint_mgr is not None:
-                checkpoint_mgr.save(round_idx + 1,
-                                    {"variables": self.variables})
-        return self.history[-1] if self.history else {}
+        return self._train_rounds(start, lambda r: checkpoint_mgr.save(
+            r + 1, {"variables": self.variables}))
 
 
 # -- static-analysis hook (fedml_tpu.analysis layer 2) ----------------------

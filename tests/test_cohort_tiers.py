@@ -69,7 +69,7 @@ def test_tiered_clients_are_bit_equal_to_the_whole_length_scan(order, epochs):
     ds = _ragged()
     api = _api(ds, epochs=epochs)
     assert api._tier_clients == TIER
-    idxs, (x, y, mask, keys, w, _) = api._prepare_round(1)
+    idxs, (x, y, mask, keys, w, _) = api._pack_round(1)[1:]
     if order != "by_size":
         # exactness does not need the sort: permute every aligned input
         perm = (np.random.RandomState(0).permutation(len(idxs))
@@ -91,7 +91,7 @@ def test_per_client_stats_are_bit_equal(epochs):
     the scan, under the vmap with one bound for all."""
     ds = _ragged()
     api = _api(ds, epochs=epochs)
-    _, (x, y, mask, keys, _, _) = api._prepare_round(0)
+    _, (x, y, mask, keys, _, _) = api._pack_round(0)[1:]
     # the short half of the size-ordered cohort, at the cohort's length
     x, y, mask, keys = (a[8:] for a in (x, y, mask, keys))
     cfg = api.config.train
@@ -112,7 +112,7 @@ def test_per_client_stats_are_bit_equal(epochs):
 def test_a_bound_past_the_padded_length_is_the_whole_loop():
     ds = _ragged()
     api = _api(ds)
-    _, (x, y, mask, keys, _, _) = api._prepare_round(0)
+    _, (x, y, mask, keys, _, _) = api._pack_round(0)[1:]
     got = [jax.jit(lambda n: api._local_train(
         api.variables, x[0], y[0], mask[0], keys[0], n_steps=n))(n)
         for n in (x.shape[1] // BSZ, 10 ** 6)]
@@ -153,7 +153,7 @@ def test_a_uniform_federation_traces_the_parents_round_program():
                              partition_method="homo")
     api = _api(ds)
     assert api._tier_clients is None
-    idxs, args = api._prepare_round(1)
+    idxs, args = api._pack_round(1)[1:]
     assert list(idxs) == list(fedavg.sample_clients(1, 16, 16))
 
     def parents_body(variables, x, y, mask, keys, lr_scale=None):
@@ -176,7 +176,7 @@ def test_tiers_need_two_whole_tiers(cohort, tiers):
     assert cohort_tiers(cohort, TIER) == tiers
     assert cohort_tiers(cohort, None) == 1
     api = _api(_ragged(), cohort=cohort)
-    _, args = api._prepare_round(0)
+    _, args = api._pack_round(0)[1:]
     text = str(jax.make_jaxpr(api._vmapped_body)(api.variables, *args[:4]))
     assert ("while" in text) == (tiers > 1)
 
@@ -187,7 +187,7 @@ def test_the_cohort_is_packed_longest_first_and_stays_aligned():
     ds = _ragged()
     api = _api(ds)
     sampled = fedavg.sample_clients(2, ds.client_num, 16)
-    idxs, (x, y, mask, keys, w, _) = api._prepare_round(2)
+    idxs, (x, y, mask, keys, w, _) = api._pack_round(2)[1:]
     sizes = [ds.train_data_local_num_dict[int(c)] for c in idxs]
     assert sorted(idxs) == sorted(sampled)
     assert sizes == sorted(sizes, reverse=True)
@@ -226,11 +226,11 @@ def test_rows_dispatched_counts_the_steps_the_tiers_run(cohort):
 def test_full_participation_keeps_the_ordered_pack_on_the_device():
     ds = _ragged(clients=16)
     api = _api(ds)
-    first, args = api._prepare_round(0)
+    first, args = api._pack_round(0)[1:]
     sizes = [ds.train_data_local_num_dict[int(c)] for c in first]
     assert sizes == sorted(sizes, reverse=True) and len(set(sizes)) > 1
     assert api._pack_cache[1] == tuple(int(c) for c in first)
-    again, args2 = api._prepare_round(1)
+    again, args2 = api._pack_round(1)[1:]
     assert list(again) == list(first)
     assert all(a is b for a, b in zip(args[:3], args2[:3]))  # a cache hit
     assert api.timer.counts["pack"] == 1
@@ -275,7 +275,7 @@ def test_the_spmd_round_traces_the_parents_program():
     ds = make_blob_federated(client_num=16, n_samples=16 * 25, seed=0,
                              partition_method="homo")
     api = _api(ds)
-    _, (x, y, mask, keys, weights, _) = api._prepare_round(1)
+    _, (x, y, mask, keys, weights, _) = api._pack_round(1)[1:]
     mesh = Mesh(np.asarray(jax.devices()[:4]), ("clients",))
     round_fn = make_spmd_round(api.module, "classification",
                                api.config.train, mesh)
@@ -291,6 +291,67 @@ def test_the_spmd_round_traces_the_parents_program():
             for k, v in got.items()} == {
                 "make_spmd_round": "53250671e36c295c",
                 "_weighted_psum_mean": "7d82c41a055e5550"}
+
+
+def _spmd_programs(api, x, y, mask, keys, weights):
+    """name -> thunk tracing one of ``parallel/spmd.py``'s other round
+    builders on the spmd guard's federation (three rounds a fused scan, two
+    edge rounds on a 2x2 mesh)."""
+    import dataclasses
+
+    from jax.sharding import Mesh
+
+    from fedml_tpu.parallel import spmd
+
+    cfg = api.config.train
+    flat = Mesh(np.asarray(jax.devices()[:4]), ("clients",))
+    two_tier = Mesh(np.asarray(jax.devices()[:4]).reshape(2, 2),
+                    ("group", "clients"))
+    ids = jnp.arange(16, dtype=jnp.uint32)
+    block = [jnp.stack([a] * 3) for a in (x, y, mask, ids, weights)]
+    build = lambda make, *a, **kw: make(api.module, "classification", *a,
+                                        **kw)
+    r0 = jnp.uint32(0)
+    return {
+        "make_spmd_round[lr_decay]": lambda: jax.make_jaxpr(build(
+            spmd.make_spmd_round,
+            dataclasses.replace(cfg, lr_decay_round=0.9), flat))(
+                api.variables, x, y, mask, keys, weights, jnp.uint32(1)),
+        "make_spmd_multiround": lambda: jax.make_jaxpr(build(
+            spmd.make_spmd_multiround, cfg, flat, 3))(
+                api.variables, x, y, mask, ids, weights, api._base_key, r0),
+        "make_spmd_block_multiround": lambda: jax.make_jaxpr(build(
+            spmd.make_spmd_block_multiround, cfg, flat))(
+                api.variables, *block, api._base_key, r0),
+        "make_hierarchical_spmd_round": lambda: jax.make_jaxpr(build(
+            spmd.make_hierarchical_spmd_round, cfg, two_tier,
+            group_comm_round=2))(api.variables, x, y, mask, keys, weights),
+    }
+
+
+@pytest.mark.parametrize("builder, digest", [
+    ("make_spmd_round[lr_decay]", "f3dcfeeeb7ca7c81"),
+    ("make_spmd_multiround", "3790c402e35efeac"),
+    ("make_spmd_block_multiround", "5870ab137cb972eb"),
+    ("make_hierarchical_spmd_round", "a9571107287d0c34")])
+def test_the_other_spmd_rounds_trace_the_parents_programs(builder, digest):
+    """The decayed, fused and two-tier mesh rounds as commit 9f93359 (before
+    they shared ``make_vmapped_clients``) traced them: sha256(str(jaxpr))
+    as in the guard above, a ``frozenset`` of axis names printed in sorted
+    order (its own order follows the process's hash seed)."""
+    import hashlib
+    import re
+
+    ds = make_blob_federated(client_num=16, n_samples=16 * 25, seed=0,
+                             partition_method="homo")
+    api = _api(ds)
+    _, _, (x, y, mask, keys, weights, _) = api._pack_round(1)
+    text = re.sub(
+        r"frozenset\(\{([^}]*)\}\)",
+        lambda m: "frozenset({%s})" % ", ".join(
+            sorted(m.group(1).split(", "))),
+        str(_spmd_programs(api, x, y, mask, keys, weights)[builder]()))
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == digest
 
 
 @pytest.mark.parametrize("model, args, row, cohort, kernel, total", [

@@ -339,11 +339,18 @@ class TestAsyncCheckpointWriter:
         orig = inner.save
 
         def gated_save(state, versions=None):
-            gate.wait(10)
+            # held until the test releases it; a writer killed meanwhile
+            # (abort) publishes nothing, as a killed process would not -
+            # a timeout that let the held write through raced abort's join
+            deadline = time.monotonic() + 10
+            while not gate.wait(0.01):
+                if writer._stopped or time.monotonic() > deadline:
+                    raise RuntimeError("the held write never went through")
             return orig(state, versions=versions)
 
         inner.save = gated_save
-        return AsyncCheckpointWriter(inner), inner, gate, orig
+        writer = AsyncCheckpointWriter(inner)
+        return writer, inner, gate, orig
 
     def test_flush_barrier_publishes_newest(self, tmp_path):
         from fedml_tpu.control import AsyncCheckpointWriter

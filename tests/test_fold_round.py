@@ -107,7 +107,7 @@ def test_folded_round_equals_the_stacked_round(hybrid):
     dataset, module = hybrid
     with jax.default_matmul_precision("highest"):
         api = _api(dataset, module, "lm_rows")
-        _, (x, y, mask, keys, weights, _) = api._prepare_round(0)
+        _, (x, y, mask, keys, weights, _) = api._pack_round(0)[1:]
         assert sorted(np.asarray(weights).tolist()) == [1.0, 2.0, 2.0, 2.0]
         stacked_body = make_vmapped_body(api._local_train)
         folded_body = make_folded_body(api._local_train, interpret=True)
@@ -230,7 +230,7 @@ def test_an_unfolded_driver_traces_the_parents_round_program(decay):
     api = _api(ds, LogisticRegression(num_classes=ds.class_num),
                "classification", train=cfg)
     assert api.config.fold_clients is False
-    _, args = api._prepare_round(1)
+    _, args = api._pack_round(1)[1:]
 
     def parents_round_fn(variables, x, y, mask, keys, weights, agg_key,
                          round_idx):
@@ -333,7 +333,7 @@ def test_the_fold_traces_the_parents_program(what, hybrid):
     if what == "make_folded_body":
         dataset, module = hybrid
         api = _api(dataset, module, "lm_rows")
-        _, (x, y, mask, keys, weights, _) = api._prepare_round(0)
+        _, (x, y, mask, keys, weights, _) = api._pack_round(0)[1:]
         got = _digest(make_folded_body(api._local_train, interpret=True),
                       api.variables, x, y, mask, keys, weights)
     elif what == "tree_fold_pallas":
@@ -359,7 +359,7 @@ def test_the_sim_round_differs_from_the_parents_only_in_the_aggregation():
     api = _api(ds, LogisticRegression(num_classes=ds.class_num),
                "classification",
                train=TrainConfig(epochs=1, batch_size=8, lr=0.1))
-    _, (x, y, mask, keys, weights, _) = api._prepare_round(1)
+    _, (x, y, mask, keys, weights, _) = api._pack_round(1)[1:]
 
     def tpu_round(variables, x, y, mask, keys, weights):
         stacked, totals = api._vmapped_body(variables, x, y, mask, keys,

@@ -1,7 +1,7 @@
 """FusedRounds: R FedAvg rounds under one lax.scan (throughput mode).
 
 Contract points: (1) full-participation fusion reproduces the host loop's
-trajectory (the in-scan fold_in chain equals FedAvgAPI._prepare_round's),
+trajectory (the in-scan fold_in chain equals FedAvgAPI._pack_round's),
 (2) the chunked train() loop learns, records history, and matches the host
 loop's eval cadence, (3) partial cohorts default to BLOCK mode —
 host-presampled R-cohort blocks packed at the block's cohort bucket,

@@ -11,6 +11,7 @@ finally).
 import json
 import socket
 import textwrap
+import time
 from pathlib import Path
 
 import pytest
@@ -403,8 +404,9 @@ class TestListenerReleaseRegressions:
         # serve thread AFTER a connection arrived; a stage failing
         # before the connect leaked the port for the process lifetime
         from fedml_tpu.comm.fanout_smoke import _RawPeer
-        port = _free_port()
-        peer = _RawPeer(port)
+        peer = _RawPeer(0)  # its own free port: no gap to lose it in
+        port = peer._server.getsockname()[1]
+        time.sleep(0.05)  # let the serve thread block in accept()
         peer.close()
         peer.close()  # idempotent
         assert not peer._thread.is_alive()

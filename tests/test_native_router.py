@@ -8,6 +8,7 @@ the environment) and exercise it end-to-end.
 import socket
 import struct
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -65,6 +66,11 @@ class TestRouterCore:
             assert (src, payload) == (1, b"hello-from-1")
             _send(b, 1, b"reply")
             assert _recv(a) == (2, b"reply")
+            # the router counts a frame after the write its reader has
+            # already seen: give the counter its moment on a loaded host
+            deadline = time.monotonic() + 5
+            while r.frames_routed < 2 and time.monotonic() < deadline:
+                time.sleep(0.005)
             assert r.frames_routed == 2
             assert r.bytes_routed == len(b"hello-from-1") + len(b"reply")
             a.close(), b.close()

@@ -192,6 +192,9 @@ def test_the_starved_probe_takes_a_host_array_for_an_idle_device():
 @pytest.mark.parametrize("driver", sorted(DRIVERS))
 def test_driver_spans_nest_by_thread_and_round(driver):
     api = DRIVERS[driver](_dataset(), frequency_of_the_test=10 ** 9)
+    # the premise of the last block: round 0 starts on an idle device (on
+    # a loaded host the fresh model's init may still be in flight)
+    jax.block_until_ready(api.variables)
     for r in range(ROUNDS):
         api.run_round(r)
     jax.block_until_ready(api.variables)
@@ -365,7 +368,7 @@ def test_trajectory_is_bit_identical_to_the_parents(driver):
     plain = DRIVERS[driver](ds, prefetch_depth=0)
     for r in range(ROUNDS):
         if driver == "sim":
-            _, args = plain._prepare_round(r)
+            _, args = plain._pack_round(r)[1:]
             args = args + (np.uint32(r),)
         else:
             _, _, args = plain._pack_round(r)
