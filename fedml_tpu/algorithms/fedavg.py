@@ -269,9 +269,14 @@ class FedAvgAPI:
         from fedml_tpu.utils.tracing import RoundTimer
         self.timer = RoundTimer()
 
-        sample_x = dataset.train_data_global[0][:1]
+        sample_x = jnp.asarray(dataset.train_data_global[0][:1])
         self.variables = module.init(jax.random.key(self.config.seed),
-                                     jnp.asarray(sample_x), train=False)
+                                     sample_x, train=False)
+        # how much of the model the local step never reads at this
+        # federation's row shape (models/common.py::LiveTapConv)
+        from fedml_tpu.models.common import dead_tap_params
+        self.timer.count("conv_dead_tap_params", dead_tap_params(
+            module, self.variables, sample_x))
         self._build_programs(aggregate_hook)
         self.history: List[Dict] = []
         # packed-cohort cache: when a round samples the same client set

@@ -5,6 +5,10 @@ from __future__ import annotations
 from typing import Optional
 
 import flax.linen as nn
+import jax
+from flax.linen.linear import canonicalize_padding
+from flax.traverse_util import flatten_dict
+from jax import lax
 
 
 def bn(train: bool, sync_axis: Optional[str] = None) -> nn.BatchNorm:
@@ -21,3 +25,95 @@ def bn(train: bool, sync_axis: Optional[str] = None) -> nn.BatchNorm:
     shard==global parity)."""
     return nn.BatchNorm(use_running_average=not train, momentum=0.9,
                         epsilon=1e-5, axis_name=sync_axis)
+
+
+def live_window(size: int, k: int, stride: int, pad) -> tuple:
+    """``(lo, hi, pad_lo, pad_hi)``: the contiguous window ``[lo, hi)`` of a
+    ``k``-tap kernel axis whose taps meet a real position of a ``size``-long
+    input axis at some output position, under ``stride`` and zero padding
+    ``pad = (before, after)``, and the padding a convolution with
+    ``kernel[lo:hi]`` takes to give the same outputs. The taps outside the
+    window only ever multiply the zero border (a 3x3 kernel with padding 1 on
+    a 1x1 map: the centre tap of nine). A pure function of shapes; where
+    every tap is live it returns ``(0, k, *pad)``."""
+    before, after = pad
+    last = (size + before + after - k) // stride  # the last output position
+    # output o lays tap t on real position stride * o + t - before: the
+    # lowest live tap belongs to the last output that still reaches the
+    # input, the highest to the first output whose window has left the border
+    o_low = min(last, (size - 1 + before) // stride)
+    o_high = max(0, -((k - 1 - before) // stride))
+    lo = max(0, before - stride * o_low)
+    hi = min(k, size + before - stride * o_high)
+    if last < 0 or lo >= hi:  # no output, or none that meets the input
+        return 0, k, before, after
+    return lo, hi, before - lo, after - (k - hi)
+
+
+class LiveTapConv(nn.Conv):
+    """``nn.Conv`` (same ``kernel`` parameter: shape, name, initialiser) that
+    reads only the live window of its kernel (``live_window``, from the
+    static spatial shape of its input). The forward pass, the data gradient
+    and the weight gradient then leave out exactly the products that have a
+    zero of the padding as one factor; the dead taps stay in the parameter
+    tree and get a gradient of exactly zero. Where no tap is dead, or the
+    convolution is anything but a plain zero-padded one (dilation, groups,
+    a mask, a bias, string padding), this is ``nn.Conv.__call__``.
+
+    It sows the parameters it slices away as ``dead_tap_params`` into
+    ``intermediates`` (a no-op unless that collection is mutable):
+    ``dead_tap_params`` below reads them."""
+
+    @nn.compact
+    def __call__(self, x):
+        size = self.kernel_size
+        size = (size,) if isinstance(size, int) else tuple(size)
+        strides = self.strides or 1
+        if isinstance(strides, int):
+            strides = (strides,) * len(size)
+        pads = canonicalize_padding(self.padding, len(size))
+        plain = (
+            x.ndim == 4 and len(size) == 2 and not isinstance(pads, str)
+            and not self.use_bias and self.mask is None
+            and self.feature_group_count == 1
+            and self.input_dilation in (None, 1)
+            and self.kernel_dilation in (None, 1)
+            and self.conv_general_dilated is None
+            and self.conv_general_dilated_cls is None)
+        windows = [live_window(n, k, s, p) for n, k, s, p in
+                   zip(x.shape[1:3], size, strides, pads)] if plain else ()
+        if all((lo, hi) == (0, k) for (lo, hi, _, _), k in zip(windows, size)):
+            return nn.Conv.__call__(self, x)
+        kernel = self.param("kernel", self.kernel_init,
+                            size + (x.shape[-1], self.features),
+                            self.param_dtype)
+        x, kernel, _ = self.promote_dtype(x, kernel, None, dtype=self.dtype)
+        window = kernel[tuple(slice(lo, hi) for lo, hi, _, _ in windows)]
+        self.sow("intermediates", "dead_tap_params",
+                 kernel.size - window.size)
+        # the window is cut once, ahead of both passes: without the barrier
+        # XLA fuses the slice into the forward convolution and cuts it again
+        # from the whole kernel for the data gradient, after the fused SGD
+        # update has overwritten that kernel in place - so it first copies
+        # the whole kernel, at every local step (PERF.md section 6, "PR 32")
+        return lax.conv_general_dilated(
+            x, lax.optimization_barrier(window), strides,
+            [w[2:] for w in windows],
+            dimension_numbers=("NHWC", "HWIO", "NHWC"),
+            precision=self.precision)
+
+
+def dead_tap_params(module, variables, x) -> int:
+    """Parameters of ``variables`` that sit in kernel taps ``LiveTapConv``
+    slices away when ``module`` runs on inputs shaped like ``x``: traced
+    under ``eval_shape``, so nothing is computed."""
+    found = []
+
+    def run(variables, x):
+        _, sown = module.apply(variables, x, train=False,
+                               mutable=["intermediates"])
+        found.extend(n for path, ns in flatten_dict(sown).items()
+                     if path[-1] == "dead_tap_params" for n in ns)
+
+    jax.eval_shape(run, variables, x)
+    return sum(found)

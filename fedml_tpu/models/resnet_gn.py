@@ -19,6 +19,8 @@ from typing import Sequence
 import flax.linen as nn
 import jax.numpy as jnp
 
+from fedml_tpu.models.common import LiveTapConv
+
 
 class GNBasicBlock(nn.Module):
     planes: int
@@ -32,15 +34,19 @@ class GNBasicBlock(nn.Module):
     @nn.compact
     def __call__(self, x, train: bool = False):
         identity = x
-        out = nn.Conv(self.planes, (3, 3), strides=(self.stride, self.stride),
-                      padding=1, use_bias=False)(x)
+        # on a map smaller than the kernel's reach (the last stage at 24x24
+        # crops is 1x1) the 3x3 convolutions read only their live taps; the
+        # names are nn.Conv's own, so the parameter tree is unchanged
+        out = LiveTapConv(self.planes, (3, 3), padding=1, use_bias=False,
+                          strides=(self.stride, self.stride), name="Conv_0")(x)
         out = nn.relu(self._norm(self.planes)(out))
-        out = nn.Conv(self.planes, (3, 3), padding=1, use_bias=False)(out)
+        out = LiveTapConv(self.planes, (3, 3), padding=1, use_bias=False,
+                          name="Conv_1")(out)
         out = self._norm(self.planes)(out)
         if self.stride != 1 or x.shape[-1] != self.planes:
             identity = nn.Conv(self.planes, (1, 1),
                                strides=(self.stride, self.stride),
-                               use_bias=False)(x)
+                               use_bias=False, name="Conv_2")(x)
             identity = self._norm(self.planes)(identity)
         return nn.relu(out + identity)
 

@@ -92,6 +92,13 @@ METRICS: Dict[str, Dict[str, str]] = {
                          "the rest of the model (vectors, narrow matrices), "
                          "whose stacked mean is left to XLA; with "
                          "agg_kernel_params the model's parameter count"),
+    "conv_dead_tap_params": _m(KIND_COUNTER, "round pipeline",
+                               "parameters of the model in convolution "
+                               "taps that only ever meet zero padding at "
+                               "the federation's row shape, which "
+                               "LiveTapConv slices out of the local step "
+                               "(they stay in the model and its mean); "
+                               "counted once when the driver is built"),
     "clients_folded": _m(KIND_COUNTER, "round pipeline",
                          "clients a folded round (FedAvgConfig.fold_clients) "
                          "trained one after another and folded into the "

@@ -1,10 +1,14 @@
 """The stacked mean's Pallas kernel compiled for a described TPU v5e, at the
 widths and cohorts the benchmark's cells run: what Mosaic refuses (a block
 off the tiling, too much VMEM) the interpreter accepts, and this costs no
-chip time. Nothing runs: no result and no time comes from here.
+chip time. And the sim round of ResNet-18-GN, for the passes over dead
+convolution taps it must not hold. Nothing runs: no result and no time
+comes from here.
 
 The topology is described inside a fixture, never at import: only the
 worker that is given this file may load the TPU's library."""
+
+import re
 
 import jax
 import jax.numpy as jnp
@@ -46,3 +50,54 @@ def test_the_mean_kernel_compiles_for_a_v5e(one_chip, clients, shape):
     text = jax.jit(tree_weighted_mean_pallas).lower(
         leaf, weights).compile().as_text()
     assert "tpu_custom_call" in text
+
+
+def test_the_resnet_round_makes_no_pass_over_dead_taps(one_chip):
+    """The published ResNet-18-GN's sim round at 24x24 crops and a cohort of
+    8, as the driver assembles it on a TPU: the last stage runs on a 1x1
+    map, so its 3x3 kernels are read through their live window
+    (``models/common.py::LiveTapConv``) and the program holds no bf16 copy
+    and no ``reverse`` of a whole ``[3, 3, 512, 512]`` kernel (the parent's
+    held nine such passes, 490 MB each at the cell's cohort of 104). Stage
+    3's ``[3, 3, 256, 256]`` kernels run on a 2x2 map, every tap live: their
+    re-layouts rightly stay."""
+    from fedml_tpu.algorithms.fedavg import make_vmapped_body
+    from fedml_tpu.models import create_model
+    from fedml_tpu.trainer.functional import TrainConfig, make_local_train
+
+    cohort, rows = 8, 40
+    module = create_model("resnet18_gn", output_dim=100, small_images=False)
+    body = make_vmapped_body(make_local_train(
+        module, "classification", TrainConfig(epochs=1, batch_size=20,
+                                              lr=0.1)))
+
+    def round_fn(variables, x, y, mask, keys, weights):
+        stacked, totals = body(variables, x, y, mask, keys, None)
+        return tree_weighted_mean_pallas(stacked, weights), totals
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    variables = jax.eval_shape(lambda: module.init(
+        jax.random.key(0), jnp.zeros((1, 24, 24, 3)), train=False))
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    text = jax.jit(round_fn, donate_argnums=(0,)).lower(
+        jax.tree.map(lambda a: arg(a.shape, a.dtype), variables),
+        arg((cohort, rows, 24, 24, 3), jnp.float32),
+        arg((cohort, rows), jnp.int32), arg((cohort, rows), jnp.float32),
+        arg((cohort,), key.dtype), arg((cohort,), jnp.float32)
+    ).compile().as_text()
+
+    def kernels(pattern, width):
+        """Result shapes matching ``pattern`` that hold the cohort's whole
+        3x3 kernels of ``width`` x ``width``, in any order of dimensions."""
+        return [m for m in re.findall(pattern, text)
+                if sorted(int(d) for d in m.split(","))
+                == [3, 3, cohort, width, width]]
+
+    assert "tpu_custom_call" in text
+    reverse = r"= \w+\[([0-9,]+)\]\S* reverse\("
+    assert kernels(r"= f32\[([0-9,]+)\]\S* add\(", 512)  # the update's
+    assert not kernels(reverse, 512)
+    assert not kernels(r"bf16\[([0-9,]+)\]", 512)
+    assert kernels(reverse, 256)
