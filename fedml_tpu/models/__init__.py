@@ -55,6 +55,15 @@ def create_model(model_name: str, output_dim: int = 10, **kw):
         if "layer_ids" in kw:
             kw = {**kw, "layer_ids": tuple(kw["layer_ids"])}
         return SambaYLM(vocab_size=output_dim, **kw)
+    if model_name == "lfm2_moe":
+        # LFM2-8B-A1B's hybrid decoder (short convolutions, grouped-query
+        # attention, routed experts); output_dim is the rows of the (tied)
+        # embedding held here
+        from fedml_tpu.models.lfm2_moe import Lfm2MoeLM
+        for name in ("layer_ids", "layer_types", "experts_held"):
+            if name in kw:
+                kw = {**kw, name: tuple(kw[name])}
+        return Lfm2MoeLM(vocab_size=output_dim, **kw)
     if model_name in ("vgg11", "vgg13", "vgg16", "vgg19"):
         from fedml_tpu.models.vgg import VGG
         return VGG(arch=model_name, num_classes=output_dim, **kw)

@@ -2,13 +2,50 @@
 
 from __future__ import annotations
 
-from typing import Optional
+from typing import Callable, Dict, Optional, Tuple
 
 import flax.linen as nn
 import jax
+import jax.numpy as jnp
 from flax.linen.linear import canonicalize_padding
 from flax.traverse_util import flatten_dict
 from jax import lax
+
+
+Spec = Tuple[Tuple[str, Tuple[int, ...], Callable], ...]
+
+
+class Leaves(nn.Module):
+    """One named group of parameters, declared from ``specs`` and returned
+    as a dictionary: for models written as pure functions of a parameter
+    tree (``models/sambay.py``, ``models/lfm2_moe.py``)."""
+
+    specs: Spec
+
+    @nn.compact
+    def __call__(self) -> Dict[str, jnp.ndarray]:
+        return {name: self.param(name, init, shape)
+                for name, shape, init in self.specs}
+
+
+def rms_norm(x, scale, eps: float):
+    """``x / rms(x) * scale`` over the last axis."""
+    return x * lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+                         + eps) * scale
+
+
+def rotary(x, theta: float):
+    """Rotary positions (rotate-half over the whole last axis) of ``x [...,
+    T, D]``, positions ``0 .. T - 1``: ``x cos + rotate_half(x) sin`` with
+    frequencies ``theta ** (-2 i / D)``."""
+    length, dim = x.shape[-2:]
+    freqs = theta ** (-jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
+    angles = jnp.arange(length, dtype=jnp.float32)[:, None] * freqs[None, :]
+    angles = jnp.concatenate([angles, angles], axis=-1)
+    x1, x2 = jnp.split(x, 2, axis=-1)
+    return (x * jnp.cos(angles).astype(x.dtype)
+            + jnp.concatenate([-x2, x1], axis=-1)
+            * jnp.sin(angles).astype(x.dtype))
 
 
 def bn(train: bool, sync_axis: Optional[str] = None) -> nn.BatchNorm:

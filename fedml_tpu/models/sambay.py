@@ -42,29 +42,16 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Callable, Dict, Optional, Tuple
+from typing import Optional, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from fedml_tpu.models.common import Leaves as _Leaves, Spec
 from fedml_tpu.ops.block_attention import causal_attention
 from fedml_tpu.ops.selective_scan import selective_scan
 from fedml_tpu.trainer.tasks import TiedHead
-
-Spec = Tuple[Tuple[str, Tuple[int, ...], Callable], ...]
-
-
-class _Leaves(nn.Module):
-    """One named group of parameters, declared from ``specs`` and returned
-    as a dictionary."""
-
-    specs: Spec
-
-    @nn.compact
-    def __call__(self) -> Dict[str, jnp.ndarray]:
-        return {name: self.param(name, init, shape)
-                for name, shape, init in self.specs}
 
 
 # -- initialisers ---------------------------------------------------------------

@@ -1,4 +1,4 @@
-"""Pallas TPU kernels for the framework's hot ops.
+"""Pallas TPU kernels (and two XLA operators) for the framework's hot ops.
 
 The reference's server hot path is a host-side Python loop over ``state_dict``
 keys (reference: fedml_api/distributed/fedavg/FedAVGAggregator.py:58-87) and
@@ -19,6 +19,11 @@ corresponding device-side primitives are hand-tiled Pallas kernels:
   attention, memoized in an on-disk per-device-kind cache so neither
   tuning nor a losing kernel is ever paid twice.
 
+- :mod:`fedml_tpu.ops.moe` — routed gated experts without drops for the
+  experts one chip holds (XLA: blocks of sorted rows through ``dot_general``,
+  the backward pass written out); :mod:`fedml_tpu.ops.selective_scan` and
+  :mod:`fedml_tpu.ops.block_attention` are XLA too.
+
 Every kernel has an ``interpret=True`` path so the math is testable on the
 CPU mesh, and a pure-jnp reference used both as the CPU fallback and as the
 test oracle.
@@ -33,6 +38,7 @@ from fedml_tpu.ops.autotune import (AttentionDecision, AutotuneCache,
                                     make_autotuned_attention)
 from fedml_tpu.ops.flash_attention import (flash_attention,
                                            make_flash_attention)
+from fedml_tpu.ops.moe import routed_experts
 from fedml_tpu.ops.quantize import (dequantize_int8, dequantize_tree,
                                     quantize_int8, quantize_tree)
 
@@ -48,6 +54,7 @@ __all__ = [
     "dequantize_tree",
     "flash_attention",
     "make_flash_attention",
+    "routed_experts",
     "AttentionDecision",
     "AutotuneCache",
     "autotune_attention",

@@ -170,11 +170,17 @@ def fold_weighted(acc: jax.Array, x: jax.Array, weight, *,
     """``acc + weight * x`` for one leaf, ``acc`` float32 and ``weight`` a
     scalar. A matrix the TPU tiles without padding (rows a multiple of 8,
     columns of 128) goes through the Pallas kernel, which writes the result
-    over ``acc``; anything else - vectors, a handful of narrow matrices - is
-    a sliver of the model and is left to XLA."""
+    over ``acc``; a stack of such matrices (routed experts: ``[experts, d,
+    w]``) goes through it as one matrix of all their rows, which is the same
+    bytes in the same order; anything else - vectors, a handful of narrow
+    matrices - is a sliver of the model and is left to XLA."""
     weight = jnp.asarray(weight, jnp.float32)
-    if acc.ndim != 2 or acc.shape[0] % 8 or acc.shape[1] % 128:
+    if acc.ndim < 2 or acc.shape[-2] % 8 or acc.shape[-1] % 128:
         return acc + weight * x.astype(jnp.float32)
+    if acc.ndim > 2:
+        flat = (-1, acc.shape[-1])
+        return fold_weighted(acc.reshape(flat), x.reshape(flat), weight,
+                             interpret=interpret).reshape(acc.shape)
     rows, cols = acc.shape
     block = _fold_block(rows, cols)
     tile = pl.BlockSpec(block, lambda i, j: (i, j))

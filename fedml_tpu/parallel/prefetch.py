@@ -90,7 +90,7 @@ def _worker(ref: "weakref.ref", requests: "queue.SimpleQueue") -> None:
             else:  # invalidated or mispredicted past: drop the stale slot
                 pf._stats["invalidated"] += 1
             pf._cond.notify_all()
-        del pf, payload, exc, item  # hold nothing while idle
+        del pf, payload, exc, item, produce  # hold nothing while idle
 
 
 class RoundPrefetcher:

@@ -69,6 +69,30 @@ class TiedHead(NamedTuple):
     embedding: jnp.ndarray
 
 
+class RoutedTiedHead(NamedTuple):
+    """A :class:`TiedHead` of a model with routed experts: beside the hidden
+    states and the embedding, ``expert_load [B, sparse layers, experts
+    held]`` - per row and sparse layer the (token, choice) pairs that landed
+    on each expert held here. ``lm_rows_head`` turns it into two stat sums
+    over the real rows."""
+
+    hidden: jnp.ndarray
+    embedding: jnp.ndarray
+    expert_load: jnp.ndarray
+
+
+def _routing_stats(expert_load, mask) -> Stats:
+    """``moe_assignments``: the pairs that landed on held experts, all sparse
+    layers; ``moe_top_expert_assignments``: per sparse layer the most loaded
+    held expert's pairs, summed. Sums like every stat, so ``held x top /
+    assignments`` over any span of steps is the load-weighted peak-to-mean
+    ratio, 1.0 when balanced."""
+    load = jnp.einsum("b,ble->le", mask, jax.lax.stop_gradient(expert_load))
+    return {"moe_assignments": jnp.sum(load),
+            "moe_top_expert_assignments": jnp.sum(jnp.max(load, axis=-1,
+                                                          initial=0.0))}
+
+
 #: positions whose logits ``lm_rows_head`` holds at a time
 LOGIT_BLOCK = 512
 
@@ -90,7 +114,13 @@ def lm_rows_head(out, targets: jnp.ndarray, mask: jnp.ndarray) -> Stats:
 
     ``out`` is ``[B, T, V]`` logits or a :class:`TiedHead`, for which the
     logits are formed ``LOGIT_BLOCK`` positions at a time, each block
-    rematerialised, so neither pass holds more than one block of them."""
+    rematerialised, so neither pass holds more than one block of them. A
+    :class:`RoutedTiedHead` adds the two routing sums of
+    ``_routing_stats``; any other output gets the three keys alone."""
+    routing = {}
+    if isinstance(out, RoutedTiedHead):
+        routing = _routing_stats(out.expert_load, mask)
+        out = TiedHead(out.hidden, out.embedding)
     if isinstance(out, TiedHead):
         hidden, embedding = out
         rows, length, _ = hidden.shape
@@ -119,6 +149,7 @@ def lm_rows_head(out, targets: jnp.ndarray, mask: jnp.ndarray) -> Stats:
         "loss_sum": jnp.sum(jnp.mean(per_tok, axis=-1) * mask),
         "count": jnp.sum(mask),
         "correct_sum": jnp.sum(jnp.mean(correct, axis=-1) * mask),
+        **routing,
     }
 
 

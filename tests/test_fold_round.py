@@ -55,7 +55,8 @@ def _api(dataset, module, task, **config):
 # -- the kernel -------------------------------------------------------------------
 
 @pytest.mark.parametrize("shape", [(8, 128), (24, 384), (520, 256),
-                                   (16, 4096), (160, 5120)])
+                                   (16, 4096), (160, 5120), (3, 8, 128),
+                                   (4, 16, 256)])
 def test_fold_kernel_equals_the_plain_sum(shape):
     rs = np.random.RandomState(0)
     acc = jnp.asarray(rs.randn(*shape), jnp.float32)
@@ -70,7 +71,7 @@ def test_fold_kernel_equals_the_plain_sum(shape):
 
 
 @pytest.mark.parametrize("shape", [(2560,), (64,), (5120, 16), (4, 5120),
-                                   (5120, 192), (3, 8, 128)])
+                                   (5120, 192), (3, 4, 128), (2, 8, 64)])
 def test_leaves_the_kernel_cannot_tile_go_to_xla(shape):
     acc, x = jnp.ones(shape), jnp.full(shape, 2.0)
     text = str(jax.make_jaxpr(

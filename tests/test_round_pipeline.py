@@ -132,6 +132,28 @@ class TestRoundPrefetcher:
         finally:
             pf.close()
 
+    def test_an_idle_worker_holds_neither_producer_nor_prefetcher(self):
+        """The producer is a driver's bound method: a worker that kept it
+        between requests kept the driver, its model on the device and this
+        prefetcher (so its own shutdown) alive for good."""
+        import gc
+        import weakref
+
+        class Owner:
+            def produce(self, key):
+                return key
+
+        owner = Owner()
+        owner.prefetch = pf = RoundPrefetcher(owner.produce, depth=1)
+        assert pf.get(0)[0] == 0 and pf.get(1) == (1, True)
+        time.sleep(0.05)  # the worker is back at its queue
+        thread, alive = pf._thread, weakref.ref(owner)
+        del owner, pf
+        gc.collect()
+        assert alive() is None
+        thread.join(timeout=5.0)
+        assert not thread.is_alive()
+
     def test_close_falls_back_to_inline_produce(self):
         pf = RoundPrefetcher(lambda r: r * 2, depth=2)
         pf.get(0)

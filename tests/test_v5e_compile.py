@@ -101,3 +101,31 @@ def test_the_resnet_round_makes_no_pass_over_dead_taps(one_chip):
     assert not kernels(reverse, 512)
     assert not kernels(r"bf16\[([0-9,]+)\]", 512)
     assert kernels(reverse, 256)
+
+
+def test_the_routed_experts_are_xla_and_ragged_dot_would_not_be(one_chip):
+    """``ops/moe.py`` at the widths of ``lfm2_8b_a1b_ep4.silo4`` (4,096
+    tokens, top-4 of 32, 8 experts of 2048 x 1792 held), forward and
+    backward: the TPU compiler takes the loops and makes no custom call of
+    them - the benchmark books every ``tpu_custom_call`` of the round as
+    aggregation - where it lowers ``jax.lax.ragged_dot`` to one."""
+    from fedml_tpu.ops.moe import routed_experts
+
+    def arg(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(s, router, bias, w1, w3, w2):
+        y, load = routed_experts(s, router, bias, w1, w3, w2, top_k=4,
+                                 experts_held=(0, 8))
+        return jnp.sum(y * y), load
+
+    text = jax.jit(jax.value_and_grad(
+        loss, argnums=(0, 1, 3, 4, 5), has_aux=True)).lower(
+            arg(4096, 2048), arg(2048, 32), arg(32), arg(8, 2048, 1792),
+            arg(8, 2048, 1792), arg(8, 1792, 2048)).compile().as_text()
+    assert "tpu_custom_call" not in text
+    assert "while" in text and re.search(r"f32\[8,2048,1792\]", text)
+    ragged = jax.jit(jax.lax.ragged_dot).lower(
+        arg(16384, 2048), arg(8, 2048, 1792),
+        arg(8, dtype=jnp.int32)).compile().as_text()
+    assert "tpu_custom_call" in ragged
