@@ -277,6 +277,11 @@ class FedAvgAPI:
         from fedml_tpu.models.common import dead_tap_params
         self.timer.count("conv_dead_tap_params", dead_tap_params(
             module, self.variables, sample_x))
+        # and how much of it a client's local loop carries: the rest stays
+        # at the global model's value (trainer/functional.py)
+        from fedml_tpu.trainer.functional import carried_params
+        self.timer.count("local_carried_params", carried_params(
+            module, cfg, self.variables, sample_x))
         self._build_programs(aggregate_hook)
         self.history: List[Dict] = []
         # packed-cohort cache: when a round samples the same client set

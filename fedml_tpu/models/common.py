@@ -2,6 +2,7 @@
 
 from __future__ import annotations
 
+import math
 from typing import Callable, Dict, Optional, Tuple
 
 import flax.linen as nn
@@ -97,9 +98,15 @@ class LiveTapConv(nn.Conv):
     convolution is anything but a plain zero-padded one (dilation, groups,
     a mask, a bias, string padding), this is ``nn.Conv.__call__``.
 
-    It sows the parameters it slices away as ``dead_tap_params`` into
-    ``intermediates`` (a no-op unless that collection is mutable):
-    ``dead_tap_params`` below reads them."""
+    The stored ``kernel`` may be the declared one or its window already cut
+    (``kernel[window]``, the window's shape): a client's local loop carries
+    the window alone and hands it over as it is
+    (``trainer/functional.py::make_local_train``). The dead taps are still
+    in the model's tree because they are the source's parameters: every
+    checkpoint, mean, evaluation and any other row shape has all nine.
+
+    It sows the window as ``live_window`` into ``intermediates`` (a no-op
+    unless that collection is mutable): ``live_windows`` below reads it."""
 
     @nn.compact
     def __call__(self, x):
@@ -121,36 +128,83 @@ class LiveTapConv(nn.Conv):
                    zip(x.shape[1:3], size, strides, pads)] if plain else ()
         if all((lo, hi) == (0, k) for (lo, hi, _, _), k in zip(windows, size)):
             return nn.Conv.__call__(self, x)
+        window = tuple((lo, hi) for lo, hi, _, _ in windows)
+        self.sow("intermediates", "live_window", window)
+        channels = (x.shape[-1], self.features)
+        cut = window_shape(window, size + channels)
+        is_cut = (self.has_variable("params", "kernel") and
+                  self.get_variable("params", "kernel").shape == cut)
+        # anything but the window's or the declared shape is ``param``'s
+        # shape error
         kernel = self.param("kernel", self.kernel_init,
-                            size + (x.shape[-1], self.features),
+                            cut if is_cut else size + channels,
                             self.param_dtype)
         x, kernel, _ = self.promote_dtype(x, kernel, None, dtype=self.dtype)
-        window = kernel[tuple(slice(lo, hi) for lo, hi, _, _ in windows)]
-        self.sow("intermediates", "dead_tap_params",
-                 kernel.size - window.size)
-        # the window is cut once, ahead of both passes: without the barrier
-        # XLA fuses the slice into the forward convolution and cuts it again
-        # from the whole kernel for the data gradient, after the fused SGD
-        # update has overwritten that kernel in place - so it first copies
-        # the whole kernel, at every local step (PERF.md section 6, "PR 32")
+        if not is_cut:
+            # the window is cut once, ahead of both passes: without the
+            # barrier XLA fuses the slice into the forward convolution and
+            # cuts it again from the whole kernel for the data gradient,
+            # after the fused SGD update has overwritten that kernel in
+            # place - so it first copies the whole kernel, at every local
+            # step (PERF.md section 6, "PR 32")
+            kernel = lax.optimization_barrier(cut_window(kernel, window))
         return lax.conv_general_dilated(
-            x, lax.optimization_barrier(window), strides,
-            [w[2:] for w in windows],
+            x, kernel, strides, [w[2:] for w in windows],
             dimension_numbers=("NHWC", "HWIO", "NHWC"),
             precision=self.precision)
 
 
-def dead_tap_params(module, variables, x) -> int:
-    """Parameters of ``variables`` that sit in kernel taps ``LiveTapConv``
-    slices away when ``module`` runs on inputs shaped like ``x``: traced
-    under ``eval_shape``, so nothing is computed."""
-    found = []
+def window_shape(window, shape) -> tuple:
+    """The shape of ``cut_window(leaf, window)`` for a leaf of ``shape``."""
+    return tuple(hi - lo for lo, hi in window) + tuple(shape[len(window):])
+
+
+def cut_window(leaf, window):
+    """``leaf[lo:hi, ...]`` over its leading axes: ``window`` is a tuple of
+    ``(lo, hi)``, one an axis."""
+    return leaf[tuple(slice(lo, hi) for lo, hi in window)]
+
+
+def write_window(leaf, cut, window):
+    """``leaf`` with ``cut`` written over ``cut_window(leaf, window)``."""
+    return lax.dynamic_update_slice(
+        leaf, cut, [lo for lo, _ in window] + [0] * (leaf.ndim - len(window)))
+
+
+def live_windows(module, variables, x) -> Dict[tuple, tuple]:
+    """``{path of a kernel in variables["params"]: its live window}`` (a
+    tuple of ``(lo, hi)`` over the leading, spatial axes) for every kernel
+    ``LiveTapConv`` slices when ``module`` runs on rows shaped like ``x``:
+    the part of the leaf a training step at that row shape can read or
+    change. Traced under ``eval_shape``, so nothing is computed; a pure
+    function of the module and the input's shape, empty for a model with
+    no padded convolution on a map smaller than its kernel. A kernel shared
+    by calls whose windows differ is left out."""
+    found = {}
 
     def run(variables, x):
         _, sown = module.apply(variables, x, train=False,
                                mutable=["intermediates"])
-        found.extend(n for path, ns in flatten_dict(sown).items()
-                     if path[-1] == "dead_tap_params" for n in ns)
+        found.update(
+            (path[:-1] + ("kernel",), windows[0])
+            for path, windows in flatten_dict(
+                sown.get("intermediates", {})).items()
+            if path[-1] == "live_window" and len(set(windows)) == 1)
 
     jax.eval_shape(run, variables, x)
-    return sum(found)
+    return found
+
+
+def dead_taps(windows, params) -> int:
+    """Parameters of ``params`` outside ``windows`` in the leaves they
+    name (``live_windows``)."""
+    flat = flatten_dict(params)
+    return sum(flat[path].size - math.prod(window_shape(window,
+                                                        flat[path].shape))
+               for path, window in windows.items())
+
+
+def dead_tap_params(module, variables, x) -> int:
+    """Parameters of ``variables`` that sit in kernel taps ``LiveTapConv``
+    slices away when ``module`` runs on inputs shaped like ``x``."""
+    return dead_taps(live_windows(module, variables, x), variables["params"])

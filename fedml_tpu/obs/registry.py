@@ -99,6 +99,13 @@ METRICS: Dict[str, Dict[str, str]] = {
                                "LiveTapConv slices out of the local step "
                                "(they stay in the model and its mean); "
                                "counted once when the driver is built"),
+    "local_carried_params": _m(KIND_COUNTER, "round pipeline",
+                               "parameters a client's local loop carries "
+                               "through its steps: the model's count less "
+                               "the dead taps (conv_dead_tap_params) where "
+                               "the client optimizer leaves a zero gradient "
+                               "alone; counted once when the driver is "
+                               "built"),
     "clients_folded": _m(KIND_COUNTER, "round pipeline",
                          "clients a folded round (FedAvgConfig.fold_clients) "
                          "trained one after another and folded into the "

@@ -27,6 +27,8 @@ import jax
 import jax.numpy as jnp
 import optax
 
+from fedml_tpu.models.common import (cut_window, dead_taps, live_windows,
+                                     write_window)
 from fedml_tpu.trainer.tasks import TASK_HEADS, TaskHead
 
 
@@ -208,6 +210,52 @@ def real_batches(mask, cfg: TrainConfig):
     return (rows + bsz - 1) // bsz
 
 
+def leaves_zero_gradients_alone(cfg: TrainConfig) -> bool:
+    """Whether ``cfg``'s optimizer answers a zero gradient with an update
+    of exactly zero, step after step, read off one scalar parameter over two
+    accumulation cycles: true of SGD with or without momentum and of
+    amsgrad (under ``MultiSteps`` too), false once ``add_decayed_weights``
+    moves a parameter by its own value."""
+    tx = make_optimizer(cfg)
+    with jax.ensure_compile_time_eval():
+        param, zero = jnp.ones(()), jnp.zeros(())
+        state = tx.init(param)
+        for _ in range(2 * cfg.accum_steps):
+            update, state = tx.update(zero, state, param)
+            if float(update) != 0.0:
+                return False
+    return True
+
+
+def carried_windows(module, cfg: TrainConfig, variables, rows) -> dict:
+    """``{path in variables["params"]: window}`` of the kernels whose
+    window alone ``make_local_train``'s loop carries on batches shaped like
+    ``rows``: the model's ``live_windows`` there, and none where
+    ``cfg``'s optimizer moves a parameter whose gradient is zero."""
+    windows = live_windows(module, variables, rows)
+    return windows if windows and leaves_zero_gradients_alone(cfg) else {}
+
+
+def carried_params(module, cfg: TrainConfig, variables, rows) -> int:
+    """Parameters a client's local loop carries through its steps: the
+    model's count less the dead taps outside ``carried_windows``, which it
+    leaves at the global model's value."""
+    params = variables["params"]
+    return sum(leaf.size for leaf in jax.tree.leaves(params)) - dead_taps(
+        carried_windows(module, cfg, variables, rows), params)
+
+
+def _at_windows(fn, windows, tree, *rest):
+    """``fn(leaf, *leaves of rest, window)`` at every path of ``tree`` that
+    ``windows`` names (``models/common.py::live_windows``), the last tree's
+    leaf elsewhere, in ``tree``'s own containers."""
+    def one(path, *leaves):
+        window = windows.get(tuple(k.key for k in path))
+        return leaves[-1] if window is None else fn(*leaves, window)
+
+    return jax.tree_util.tree_map_with_path(one, tree, *rest)
+
+
 def make_local_train(module, task: str, cfg: TrainConfig,
                      grad_sync_axes: tuple = ()):
     """Build ``local_train(variables, x, y, mask, rng) -> (variables, stats)``.
@@ -227,6 +275,20 @@ def make_local_train(module, task: str, cfg: TrainConfig,
     exact whenever ``k >= real_batches(mask, cfg)``, because the batches it
     leaves out are the gated no-ops. Without it the loop is the ``scan``
     over all ``n_pad // batch_size`` batches.
+
+    What the loop carries: of every kernel the model declares a live window
+    of at these rows' shape (``models/common.py::live_windows``: a padded
+    convolution on a map smaller than its kernel) the window alone -
+    parameters, optimizer state, gradient, update and the ``has_real``
+    select - and the whole of every other leaf. A tap outside the window
+    only ever meets zero padding, so its gradient is zero by construction
+    and a client leaves it at the global model's value: the returned leaf
+    is the global one with the trained window written into it, once, after
+    the last step (the parent's shapes and values, dead taps to the bit).
+    That is exact only where a zero gradient means a zero update, which is
+    asked of the optimizer itself (``carried_windows``; not true of
+    adam with weight decay): otherwise, and for every model without such a
+    window, whole leaves are carried.
     """
     from fedml_tpu.utils import on_tpu
 
@@ -263,7 +325,14 @@ def make_local_train(module, task: str, cfg: TrainConfig,
         batch_idx, step_keys = make_batch_schedule(n_pad, cfg.epochs, bsz,
                                                    cfg.shuffle, rng,
                                                    mask=mask)
+        # the part of each leaf a step at this row shape can change, as
+        # the model declares it; nothing is computed for the question
+        windows = carried_windows(
+            module, cfg, variables,
+            jax.ShapeDtypeStruct((bsz,) + x.shape[1:], x.dtype))
         params = variables["params"]
+        if windows:
+            params = _at_windows(cut_window, windows, params)
         opt_state = tx.init(params)
         init = (params, {k: v for k, v in variables.items() if k != "params"},
                 opt_state)
@@ -365,6 +434,9 @@ def make_local_train(module, task: str, cfg: TrainConfig,
             (params, colls, _), stats = jax.lax.fori_loop(
                 0, cfg.epochs * n_steps, bounded_step, (init, zeros))
         total = jax.tree.map(lambda s: jnp.sum(s, axis=0), stats)
+        if windows:
+            params = _at_windows(write_window, windows,
+                                 variables["params"], params)
         return {"params": params, **colls}, total
 
     return local_train

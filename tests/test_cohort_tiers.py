@@ -381,6 +381,8 @@ def test_the_driver_counts_what_the_mean_kernel_takes(
     counters = FedAvgAPI(ds, module, config=config).timer.counters
     assert "agg_kernel_params" not in counters  # the CPU's mean is XLA's
     assert counters["conv_dead_tap_params"] == dead  # on any backend
+    # the local loop carries the rest (SGD leaves a zero gradient alone)
+    assert counters["local_carried_params"] == total - dead
     monkeypatch.setattr(utils, "on_tpu", lambda: True)
     counters = FedAvgAPI(ds, module, config=config).timer.counters
     assert counters["conv_dead_tap_params"] == dead

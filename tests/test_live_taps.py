@@ -274,3 +274,236 @@ def test_resnet18_gn_with_no_dead_tap_traces_the_parents_program(
     ours, plain = jaxpr(_resnet(small_images)), jaxpr(
         plain_resnet(small_images))
     assert (ours == plain) == (hw != 32)
+
+
+# -- the local loop carries the windows alone ---------------------------------
+
+class _TinyNet(nn.Module):
+    """A padded 3x3 convolution on the input's map, a stride-2 one on that
+    (2x2 -> 1x1), and a head: at 1x1 rows both have dead taps (one tap of
+    nine live each), at 2x2 rows only the second has (four of nine)."""
+
+    @nn.compact
+    def __call__(self, x, train=False):
+        x = nn.relu(LiveTapConv(6, (3, 3), padding=1, use_bias=False)(x))
+        x = nn.relu(LiveTapConv(8, (3, 3), strides=(2, 2), padding=1,
+                                use_bias=False)(x))
+        return nn.Dense(5)(x.reshape(x.shape[0], -1))
+
+
+LOOPS = {  # TrainConfig fields, on top of epochs=2, batch_size=4, lr=0.1
+    "sgd": {}, "momentum": dict(momentum=0.9),
+    "amsgrad": dict(client_optimizer="adam", lr=0.01),
+    "accum2": dict(accum_steps=2, momentum=0.5),
+    "bf16": dict(compute_dtype="bfloat16"), "decay": dict(lr_decay_round=0.5)}
+
+
+def _local(hw, bounded, **train):
+    """A vmapped cohort of three clients (12, 8 and 5 real rows of 12: a
+    partial batch and a pure-padding one) through ``make_local_train``."""
+    from fedml_tpu.trainer.functional import (TrainConfig, make_local_train,
+                                              round_lr_scale)
+    module = _TinyNet()
+    cfg = TrainConfig(**dict(dict(epochs=2, batch_size=4, lr=0.1), **train))
+    x = jax.random.normal(jax.random.key(1), (3, 12, hw, hw, 3))
+    y = jax.random.randint(jax.random.key(2), (3, 12), 0, 5)
+    mask = (jnp.arange(12)[None, :] < jnp.array([12, 8, 5])[:, None]
+            ).astype(jnp.float32)
+    variables = module.init(jax.random.key(0), x[0, :1])
+    local_train = make_local_train(module, "classification", cfg)
+
+    def cohort(variables, x, y, mask, keys):
+        return jax.vmap(lambda xc, yc, mc, kc: local_train(
+            variables, xc, yc, mc, kc, lr_scale=round_lr_scale(cfg, 3),
+            n_steps=jnp.int32(2) if bounded else None))(x, y, mask, keys)
+
+    return cohort, (variables, x, y, mask,
+                    jax.random.split(jax.random.key(3), 3))
+
+
+@pytest.fixture
+def whole_leaves(monkeypatch):
+    """The same loop with the model's windows unasked: the parent's."""
+    from fedml_tpu.trainer import functional
+
+    def patch():
+        monkeypatch.setattr(functional, "live_windows", lambda *a: {})
+    yield patch
+
+
+@pytest.mark.parametrize("bounded", [False, True])
+@pytest.mark.parametrize("hw", [1, 2])
+@pytest.mark.parametrize("loop", sorted(LOOPS))
+def test_the_loop_on_windows_equals_the_loop_on_whole_leaves(
+        whole_leaves, loop, hw, bounded):
+    cohort, args = _local(hw, bounded, **LOOPS[loop])
+    jaxpr = str(jax.make_jaxpr(cohort)(*args))
+    (got, got_stats) = jax.jit(cohort)(*args)
+    whole_leaves()
+    cohort, _ = _local(hw, bounded, **LOOPS[loop])  # traced anew
+    assert str(jax.make_jaxpr(cohort)(*args)) != jaxpr
+    (want, want_stats) = jax.jit(cohort)(*args)
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for a, b in zip(jax.tree.leaves(got_stats), jax.tree.leaves(want_stats)):
+        np.testing.assert_allclose(a, b, rtol=1e-6)
+    tol = 2e-2 if loop == "bf16" else 1e-6
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        np.testing.assert_allclose(a, b, rtol=tol, atol=tol)
+    # the dead taps of every client are the global model's, to the bit,
+    # on both sides; the live ones have moved
+    live = {"LiveTapConv_0": (slice(1, 2),) * 2 if hw == 1 else None,
+            "LiveTapConv_1": (slice(1, 2),) * 2 if hw == 1
+            else (slice(1, 3),) * 2}
+    for name, window in live.items():
+        start = np.asarray(args[0]["params"][name]["kernel"])
+        for side in (got, want):
+            kernels = np.asarray(side["params"][name]["kernel"])
+            assert kernels.shape == (3,) + start.shape
+            moved = kernels != start[None]
+            dead = np.ones(start.shape, bool)
+            if window is not None:
+                dead[window] = False
+                assert not moved[:, dead].any()
+            assert moved[:, ~dead if window is not None else dead].any()
+
+
+@pytest.mark.parametrize("hw", [1, 2])
+def test_adam_with_weight_decay_carries_whole_leaves(whole_leaves, hw):
+    """``add_decayed_weights`` moves a tap whose gradient is zero: the
+    optimizer is asked, and the loop is the whole-leaf one to the jaxpr."""
+    from fedml_tpu.trainer.functional import (TrainConfig,
+                                              leaves_zero_gradients_alone)
+    cohort, args = _local(hw, False, client_optimizer="adam", wd=0.01)
+    ours = str(jax.make_jaxpr(cohort)(*args))
+    start = np.asarray(args[0]["params"]["LiveTapConv_1"]["kernel"])
+    moved = np.asarray(jax.jit(cohort)(*args)[0]["params"]["LiveTapConv_1"]
+                       ["kernel"]) != start[None]
+    assert moved[:, 0, 0].any()  # a dead tap, decayed
+    whole_leaves()
+    cohort, _ = _local(hw, False, client_optimizer="adam", wd=0.01)
+    assert str(jax.make_jaxpr(cohort)(*args)) == ours
+    for train, alone in [
+            (dict(), True), (dict(momentum=0.9), True),
+            (dict(client_optimizer="adam"), True),
+            (dict(client_optimizer="adam", accum_steps=3), True),
+            (dict(momentum=0.9, accum_steps=2), True),
+            (dict(client_optimizer="adam", wd=1e-4), False),
+            (dict(client_optimizer="adam", wd=1e-4, accum_steps=2), False)]:
+        assert leaves_zero_gradients_alone(TrainConfig(**train)) is alone, \
+            train
+
+
+@pytest.mark.parametrize("bounded", [False, True])
+def test_a_model_without_windows_traces_the_parents_loop(bounded):
+    """A vmapped cohort of the CNN through ``make_local_train``: the digest
+    was recorded on commit cb91b64, before the loop knew of windows."""
+    from fedml_tpu.trainer.functional import TrainConfig, make_local_train
+    module = create_model("cnn", output_dim=62)
+    local_train = make_local_train(
+        module, "classification",
+        TrainConfig(epochs=1, batch_size=4, lr=0.1, momentum=0.9))
+    variables = module.init(jax.random.key(0), jnp.zeros((1, 28, 28, 1)),
+                            train=False)
+    text = str(jax.make_jaxpr(jax.vmap(
+        lambda v, xc, yc, mc, kc: local_train(
+            v, xc, yc, mc, kc, n_steps=jnp.int32(1) if bounded else None),
+        in_axes=(None, 0, 0, 0, 0)))(
+            variables, jnp.zeros((2, 8, 28, 28, 1)),
+            jnp.zeros((2, 8), jnp.int32), jnp.ones((2, 8)),
+            jax.random.split(jax.random.key(0), 2)))
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == {
+        False: "bf3bd686755d1aae", True: "d849df22a4bd28b3"}[bounded]
+
+
+@pytest.mark.parametrize("kernel, accepted", [
+    ((3, 3, 4, 5), True), ((1, 1, 4, 5), True), ((2, 2, 4, 5), False),
+    ((1, 1, 4, 6), False)])
+def test_a_stored_kernel_is_the_declared_one_or_its_window(kernel, accepted):
+    conv, x = _conv(3, 1, 1), jnp.ones((2, 1, 1, 4))
+    variables = {"params": {"kernel": jnp.ones(kernel)}}
+    if not accepted:
+        with pytest.raises(Exception, match="shape"):
+            conv.apply(variables, x)
+        return
+    np.testing.assert_allclose(conv.apply(variables, x), 4.0)
+    text = str(jax.make_jaxpr(conv.apply)(variables, x))
+    assert ("optimization_barrier" in text) == (kernel[0] == 3)
+    assert ("slice" in text) == (kernel[0] == 3)
+
+
+def test_live_windows_of_the_published_resnet():
+    from fedml_tpu.models.common import live_windows, window_shape
+    module = _resnet(False)
+    x = jnp.zeros((1, 24, 24, 3))
+    variables = jax.eval_shape(lambda: module.init(jax.random.key(0), x,
+                                                   train=False))
+    windows = live_windows(module, variables, x)
+    assert windows == {
+        ("GNBasicBlock_6", "Conv_0", "kernel"): ((1, 3), (1, 3)),
+        ("GNBasicBlock_6", "Conv_1", "kernel"): ((1, 2), (1, 2)),
+        ("GNBasicBlock_7", "Conv_0", "kernel"): ((1, 2), (1, 2)),
+        ("GNBasicBlock_7", "Conv_1", "kernel"): ((1, 2), (1, 2))}
+    flat = flatten_dict(variables["params"])
+    carried = sum(int(np.prod(window_shape(windows[p], a.shape)))
+                  if p in windows else a.size for p, a in flat.items())
+    assert carried == 4_280_996 == 11_227_812 - 6_946_816
+    assert live_windows(module, variables, jnp.zeros((1, 64, 64, 3))) == {}
+    assert live_windows(_resnet(True), jax.eval_shape(
+        lambda: _resnet(True).init(jax.random.key(0), x, train=False)),
+        x) == {}
+
+
+@pytest.mark.parametrize("driver, train, carried", [
+    ("sim", dict(), 399), ("mesh", dict(), 399),
+    ("sim", dict(client_optimizer="adam", wd=0.01), 639)])
+def test_both_drivers_count_and_train_what_the_loop_carries(
+        whole_leaves, driver, train, carried):
+    """Two rounds of either driver over 2x2 rows (the first convolution has
+    no dead tap, the second's window is four of nine): the model the
+    windows give is the one whole leaves give, the dead taps of the global
+    model stay where they were under SGD, and ``local_carried_params`` says which
+    loop ran: of 162 + 432 + 45 parameters, 240 are dead taps."""
+    from fedml_tpu.algorithms.fedavg import FedAvgAPI, FedAvgConfig
+    from fedml_tpu.data.base import FederatedDataset
+    from fedml_tpu.parallel.spmd import (DistributedFedAvgAPI,
+                                         DistributedFedAvgConfig, build_mesh)
+    from fedml_tpu.trainer.functional import TrainConfig
+
+    rng = np.random.default_rng(0)
+    clients = {c: (rng.normal(size=(6, 2, 2, 3)).astype(np.float32),
+                   rng.integers(0, 5, 6).astype(np.int32)) for c in range(4)}
+    ds = FederatedDataset.from_client_arrays(clients, clients, class_num=5)
+    kw = dict(comm_round=2, client_num_per_round=4, prefetch_depth=0,
+              train=TrainConfig(**dict(dict(epochs=1, batch_size=3, lr=0.1),
+                                       **train)))
+
+    def run():
+        if driver == "sim":
+            api = FedAvgAPI(ds, _TinyNet(), config=FedAvgConfig(**kw))
+        else:
+            api = DistributedFedAvgAPI(
+                ds, _TinyNet(), mesh=build_mesh({"clients": 2}),
+                config=DistributedFedAvgConfig(**kw))
+        start = jax.tree.map(np.asarray, api.variables)
+        for r in range(2):
+            api.run_round(r)
+        return api, start
+
+    api, start = run()
+    counters = api.timer.counters
+    assert counters["conv_dead_tap_params"] == 240
+    assert counters["local_carried_params"] == carried
+    whole_leaves()
+    want, _ = run()
+    assert want.timer.counters["local_carried_params"] == 639
+    for a, b in zip(jax.tree.leaves(api.variables),
+                    jax.tree.leaves(want.variables)):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+    # a client returns the global model's dead taps to the bit (the tests
+    # above); the mean of equal values rounds them, as it always has
+    kernel = np.asarray(api.variables["params"]["LiveTapConv_1"]["kernel"])
+    moved = ~np.isclose(kernel, start["params"]["LiveTapConv_1"]["kernel"],
+                        rtol=1e-6, atol=0)
+    assert moved[1:, 1:].any()
+    assert moved[0].any() == moved[:, 0].any() == bool(train)

@@ -58,9 +58,13 @@ def test_the_resnet_round_makes_no_pass_over_dead_taps(one_chip):
     map, so its 3x3 kernels are read through their live window
     (``models/common.py::LiveTapConv``) and the program holds no bf16 copy
     and no ``reverse`` of a whole ``[3, 3, 512, 512]`` kernel (the parent's
-    held nine such passes, 490 MB each at the cell's cohort of 104). Stage
-    3's ``[3, 3, 256, 256]`` kernels run on a 2x2 map, every tap live: their
-    re-layouts rightly stay."""
+    held nine such passes, 490 MB each at the cell's cohort of 104). The
+    local loop carries the windows alone (``make_local_train``): the SGD
+    update runs at ``[1, 1, 512, 512]`` a client, nothing adds to, selects
+    or copies the cohort's whole kernels, and each is written once, the
+    trained window into the broadcast global kernel, on its way to the
+    mean. Stage 3's ``[3, 3, 256, 256]`` kernels run on a 2x2 map, every
+    tap live: their re-layouts rightly stay."""
     from fedml_tpu.algorithms.fedavg import make_vmapped_body
     from fedml_tpu.models import create_model
     from fedml_tpu.trainer.functional import TrainConfig, make_local_train
@@ -88,16 +92,27 @@ def test_the_resnet_round_makes_no_pass_over_dead_taps(one_chip):
         arg((cohort,), key.dtype), arg((cohort,), jnp.float32)
     ).compile().as_text()
 
-    def kernels(pattern, width):
-        """Result shapes matching ``pattern`` that hold the cohort's whole
-        3x3 kernels of ``width`` x ``width``, in any order of dimensions."""
+    def kernels(pattern, width, taps=3):
+        """Result shapes matching ``pattern`` that hold the cohort's
+        ``taps`` x ``taps`` kernels of ``width`` x ``width``, in any order
+        of dimensions."""
         return [m for m in re.findall(pattern, text)
                 if sorted(int(d) for d in m.split(","))
-                == [3, 3, cohort, width, width]]
+                == sorted([taps, taps, cohort, width, width])]
+
+    def op(name):
+        return r"= f32\[([0-9,]+)\]\S* %s\(" % name
 
     assert "tpu_custom_call" in text
     reverse = r"= \w+\[([0-9,]+)\]\S* reverse\("
-    assert kernels(r"= f32\[([0-9,]+)\]\S* add\(", 512)  # the update's
+    for passing in ("add", "select", "copy", "multiply", "pad"):
+        assert not kernels(op(passing), 512), passing
+    assert len(kernels(op("add"), 512, taps=1)) == 3  # the update's
+    # the one write of each whole kernel: the vmapped dynamic_update_slice
+    # (a scatter in the jaxpr) over the broadcast global kernel
+    assert len(kernels(op("dynamic-update-slice"), 512)) == 3
+    assert len(kernels(op("broadcast"), 512)) == 3
+    assert not kernels(op("scatter"), 512)
     assert not kernels(reverse, 512)
     assert not kernels(r"bf16\[([0-9,]+)\]", 512)
     assert kernels(reverse, 256)
