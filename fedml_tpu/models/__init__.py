@@ -64,6 +64,15 @@ def create_model(model_name: str, output_dim: int = 10, **kw):
             if name in kw:
                 kw = {**kw, name: tuple(kw[name])}
         return Lfm2MoeLM(vocab_size=output_dim, **kw)
+    if model_name == "granite_hybrid":
+        # granite-4.0-h-micro's hybrid decoder (Mamba-2 layers beside NoPE
+        # grouped-query attention); output_dim is the rows of the (tied)
+        # embedding held here
+        from fedml_tpu.models.granite_hybrid import GraniteHybridLM
+        for name in ("layer_ids", "layer_types"):
+            if name in kw:
+                kw = {**kw, name: tuple(kw[name])}
+        return GraniteHybridLM(vocab_size=output_dim, **kw)
     if model_name in ("vgg11", "vgg13", "vgg16", "vgg19"):
         from fedml_tpu.models.vgg import VGG
         return VGG(arch=model_name, num_classes=output_dim, **kw)

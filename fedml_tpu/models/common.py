@@ -29,6 +29,23 @@ class Leaves(nn.Module):
                 for name, shape, init in self.specs}
 
 
+def uniform_init(bound: float):
+    """Uniform in ``-bound .. bound`` (torch's default for a convolution at
+    ``bound = fan_in ** -0.5``)."""
+    def init(key, shape, dtype=jnp.float32):
+        return jax.random.uniform(key, shape, dtype, -bound, bound)
+    return init
+
+
+def dt_bias_init(key, shape, dtype=jnp.float32):
+    """The inverse softplus of step sizes drawn log-uniformly from
+    1e-3..1e-1, so that ``softplus(bias)`` starts there (Mamba's and
+    Mamba-2's start)."""
+    dt = jnp.exp(jax.random.uniform(key, shape, dtype)
+                 * (math.log(1e-1) - math.log(1e-3)) + math.log(1e-3))
+    return dt + jnp.log(-jnp.expm1(-dt))
+
+
 def rms_norm(x, scale, eps: float):
     """``x / rms(x) * scale`` over the last axis."""
     return x * lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True)

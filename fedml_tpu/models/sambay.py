@@ -48,7 +48,9 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from fedml_tpu.models.common import Leaves as _Leaves, Spec
+from fedml_tpu.models.common import (Leaves as _Leaves, Spec,
+                                     dt_bias_init as _dt_bias,
+                                     uniform_init as _uniform)
 from fedml_tpu.ops.block_attention import causal_attention
 from fedml_tpu.ops.selective_scan import selective_scan
 from fedml_tpu.trainer.tasks import TiedHead
@@ -61,25 +63,11 @@ _zeros = nn.initializers.zeros
 _ones = nn.initializers.ones
 
 
-def _uniform(bound: float):
-    def init(key, shape, dtype=jnp.float32):
-        return jax.random.uniform(key, shape, dtype, -bound, bound)
-    return init
-
-
 def _a_log(key, shape, dtype=jnp.float32):
     """``A = -(1..N)`` in every channel (Mamba's S4D-real start)."""
     del key
     return jnp.broadcast_to(jnp.log(jnp.arange(1, shape[1] + 1, dtype=dtype)),
                             shape)
-
-
-def _dt_bias(key, shape, dtype=jnp.float32):
-    """The inverse softplus of step sizes drawn log-uniformly from
-    1e-3..1e-1, so that ``softplus(bias)`` starts there."""
-    dt = jnp.exp(jax.random.uniform(key, shape, dtype)
-                 * (math.log(1e-1) - math.log(1e-3)) + math.log(1e-3))
-    return dt + jnp.log(-jnp.expm1(-dt))
 
 
 # -- the arithmetic -------------------------------------------------------------

@@ -21,7 +21,9 @@ corresponding device-side primitives are hand-tiled Pallas kernels:
 
 - :mod:`fedml_tpu.ops.moe` — routed gated experts without drops for the
   experts one chip holds (XLA: blocks of sorted rows through ``dot_general``,
-  the backward pass written out); :mod:`fedml_tpu.ops.selective_scan` and
+  the backward pass written out); :mod:`fedml_tpu.ops.selective_scan`
+  (Mamba-1, elementwise), :mod:`fedml_tpu.ops.ssd` (Mamba-2 in its chunked
+  dual form: matrix products inside chunks, a scan over chunk states) and
   :mod:`fedml_tpu.ops.block_attention` are XLA too.
 
 Every kernel has an ``interpret=True`` path so the math is testable on the

@@ -8,6 +8,8 @@ comes from here.
 The topology is described inside a fixture, never at import: only the
 worker that is given this file may load the TPU's library."""
 
+import json
+import os
 import re
 
 import jax
@@ -144,3 +146,38 @@ def test_the_routed_experts_are_xla_and_ragged_dot_would_not_be(one_chip):
         arg(16384, 2048), arg(8, 2048, 1792),
         arg(8, dtype=jnp.int32)).compile().as_text()
     assert "tpu_custom_call" in ragged
+
+
+def test_the_chunked_ssd_scan_is_xla_on_a_v5e(one_chip):
+    """``ops/ssd.py`` at the widths of ``granite_4_0_h_micro_10l.silo4`` (a
+    row of 2,048 tokens, 64 heads of 64, a state of 128, one group, chunks
+    of 256), forward and backward: the TPU compiler takes the scan over the
+    chunks and its products and makes no custom call of them - the benchmark
+    books every ``tpu_custom_call`` of the round as aggregation - and the
+    program holds one chunk's ``[64, 256, 256]`` decay matrices, never the
+    row's eight."""
+    from fedml_tpu.ops.ssd import ssd_scan
+
+    def arg(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    def loss(xs, dt, a, b, c):
+        y = ssd_scan(xs, dt, a, b, c, chunk=256)
+        return jnp.sum(y * y)
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        arg(2048, 64, 64), arg(2048, 64), arg(64), arg(2048, 1, 128),
+        arg(2048, 1, 128)).compile().as_text()
+    assert "tpu_custom_call" not in text
+    assert re.search(r"f32\[64,256,256\]", text)
+    assert re.search(r"f32\[(1,)?64,64,128\]", text)
+    assert not re.search(r"\[8,64,256,256\]", text)
+    # the patterns of benchmark/metrics/ssd_ms.json name these tensors
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmark", "metrics",
+            "ssd_ms.json")) as f:
+        pattern = re.compile(json.load(f)["args"]["pattern"])
+    named = [line for line in text.splitlines()
+             if " = " in line and pattern.search(line)]
+    assert any(" convolution(" in line or " fusion(" in line
+               for line in named)
