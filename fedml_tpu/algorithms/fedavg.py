@@ -35,7 +35,7 @@ import numpy as np
 from fedml_tpu.core import pytree as pt
 from fedml_tpu.core.sampling import (DEVICE_SAMPLE_SENTINEL, eval_subsample,
                                      round_keys, sample_clients)
-from fedml_tpu.data.base import FederatedDataset
+from fedml_tpu.data.base import NATIVE_PACK_FLOOR_BYTES, FederatedDataset
 from fedml_tpu.trainer.functional import (TrainConfig, make_eval,
                                           make_local_train, real_batches,
                                           round_lr_scale)
@@ -295,6 +295,13 @@ class FedAvgAPI:
         # cohort prefetcher (parallel/prefetch.py), built lazily on the
         # first partial-participation round; (prefetcher, dataset-at-build)
         self._prefetch = None
+        # host buffers of packed cohorts, recycled: what the prefetcher's
+        # depth can have in the packer's hands at once, and the round
+        # thread's on a miss
+        from fedml_tpu.parallel.prefetch import (PackBufferPool,
+                                                 resolve_prefetch_depth)
+        self._pack_pool = PackBufferPool(1 + resolve_prefetch_depth(
+            getattr(self.config, "prefetch_depth", 0)))
         # virtualized populations (fedml_tpu/state/) front the per-client
         # shards with a tiered store; binding its counters here puts
         # state_cache_hits/misses/evictions + state_bytes_read/written on
@@ -448,23 +455,54 @@ class FedAvgAPI:
 
     def _pack_cohort(self, idxs, ds):
         """Cache-free pad + pack + upload of one sampled cohort of ``ds``:
-        ``(slots, (x, y, mask, weights))`` (thread-safe: no shared mutable
-        state — the prefetcher worker calls this concurrently with the main
-        thread's dispatch)."""
+        ``(slots, (x, y, mask, weights))`` (thread-safe: the one shared
+        state is the locked buffer pool — the prefetcher worker calls this
+        concurrently with the main thread's dispatch).
+
+        A cohort the native packer serves (``NATIVE_PACK_FLOOR_BYTES``) is
+        packed into a host triple from ``_pack_pool`` where one is free,
+        and its triple goes (back) to the pool once the upload has read
+        it, less any array that a placed one shares memory with; smaller
+        cohorts allocate and enqueue as ever."""
+        from fedml_tpu.parallel.prefetch import aliases_host
         cfg = self.config
         with self.timer.phase("pack"):
             slots, alive = self._pad_round(idxs)
             n_pad = (ds.cohort_padded_len(slots, cfg.train.batch_size)
                      if cfg.pack == "cohort" else self._n_pad)
-            x, y, mask = ds.pack_clients(slots, cfg.train.batch_size,
-                                         n_pad=n_pad)
+            key = (len(slots), n_pad)
+            bufs = self._pack_pool.take(ds, key)
+            try:
+                x, y, mask = ds.pack_clients(slots, cfg.train.batch_size,
+                                             n_pad=n_pad, out=bufs)
+            except ValueError:
+                if bufs is None:
+                    raise
+                # not the buffers this cohort wants (clients of another
+                # dtype), or a bad cohort: the fresh call says which
+                bufs = None
+                x, y, mask = ds.pack_clients(slots, cfg.train.batch_size,
+                                             n_pad=n_pad)
+            self.timer.count("pack_buffers_fresh" if bufs is None
+                             else "pack_buffers_recycled")
             weights = ds.client_weights(slots)
             if alive is not None:  # zero-weight duplicate slots
-                mask = mask * alive[:, None]
+                np.multiply(mask, alive[:, None], out=mask)
                 weights = weights * alive
         with self.timer.phase("upload"):
-            return slots, (self._put(x), self._put(y), self._put(mask),
-                           self._put(weights))
+            placed = (self._put(x), self._put(y), self._put(mask),
+                      self._put(weights))
+            if x.nbytes >= NATIVE_PACK_FLOOR_BYTES:
+                # the triple may be written again once the transfers have
+                # read it: wait for them here, on the packer's thread
+                # ft: allow[FT003] the upload's end, inside its own phase
+                jax.block_until_ready(placed)
+                # an array that a placed one shares memory with (the CPU
+                # backend takes an aligned one as it is) stays that one's
+                self._pack_pool.give(ds, key, tuple(
+                    np.empty_like(host) if aliases_host(host, put) else host
+                    for host, put in zip((x, y, mask), placed)))
+            return slots, placed
 
     def _pack_round(self, round_idx: int):
         """The full host side of one round — seeded sampling, the cohort's
@@ -501,6 +539,8 @@ class FedAvgAPI:
                 if full:
                     self._pack_cache = (ds, cohort,
                                         (slots, (xd, yd, maskd, wd)))
+                    # packed once: its host buffers are not worth keeping
+                    self._pack_pool.clear()
             _, keys, agg_key = round_keys(
                 self._base_key, round_idx,
                 jnp.asarray(np.asarray(slots), dtype=jnp.uint32))
@@ -562,6 +602,7 @@ class FedAvgAPI:
         speculation clamp can't see."""
         for pf in self._prefetchers():
             pf.invalidate()
+        self._pack_pool.clear()
 
     def fused_rounds(self, device_sampling: bool = False) -> "FusedRounds":
         """The fused multi-round driver PAIRED with this API class

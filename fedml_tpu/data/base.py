@@ -23,6 +23,31 @@ import numpy as np
 
 Arrays = Tuple[np.ndarray, np.ndarray]  # (x, y)
 
+#: a packed ``x`` of at least this many bytes goes through the native
+#: packer (``fedml_tpu/native``), and its buffers are worth recycling
+#: (``FedAvgAPI._pack_cohort``); smaller cohorts take the numpy loop
+NATIVE_PACK_FLOOR_BYTES = 1 << 22
+
+
+def pack_buffers(P: int, n_pad: int, x0: np.ndarray, y0: np.ndarray,
+                 out=None, alloc=np.empty):
+    """The ``(x, y, mask)`` a cohort of ``P`` clients like ``(x0, y0)`` is
+    packed into: fresh arrays from ``alloc`` (uninitialised by default),
+    or the caller's ``out`` when it is exactly what would be allocated
+    (else ``ValueError``)."""
+    want = (((P, n_pad) + x0.shape[1:], x0.dtype),
+            ((P, n_pad) + y0.shape[1:], y0.dtype),
+            ((P, n_pad), np.dtype(np.float32)))
+    if out is None:
+        return tuple(alloc(shape, dtype) for shape, dtype in want)
+    for name, a, (shape, dtype) in zip(("x", "y", "mask"), out, want):
+        if (a.shape != shape or a.dtype != dtype
+                or not a.flags.c_contiguous or not a.flags.writeable):
+            raise ValueError(
+                f"out[{name}] must be a writeable C-contiguous "
+                f"{dtype}{shape}; got {a.dtype}{a.shape}")
+    return tuple(out)
+
 
 @dataclasses.dataclass
 class FederatedDataset:
@@ -98,17 +123,27 @@ class FederatedDataset:
         return min(bucket * b, self.padded_len(batch_size))
 
     def pack_clients(self, client_idxs, batch_size: Optional[int] = None,
-                     n_pad: Optional[int] = None):
+                     n_pad: Optional[int] = None, out=None):
         """Gather sampled clients into [P, n_pad, ...] x / [P, n_pad, ...] y /
         [P, n_pad] mask arrays — the device-ready round input. ``n_pad``
         defaults to the dataset-wide static shape so every round compiles
-        once."""
+        once.
+
+        ``out=(x, y, mask)``: write into these arrays and return them
+        instead of allocating (C-contiguous, of exactly the shapes and
+        dtypes this call would allocate, else ``ValueError``). Every byte
+        of them is written - real rows, zeroed tails, the mask - so what
+        they held before does not matter. They are the caller's: the
+        packer keeps no reference, and the caller alone knows when a
+        buffer is free to be written again (``FedAvgAPI`` recycles a
+        cohort's triple once its upload is over, ``_pack_cohort``). A
+        fresh array costs a page fault and a zeroed page for every 4 KB
+        written, two thirds of a large pack; a buffer that is reused has
+        its pages already."""
         n_pad = n_pad or self.padded_len(batch_size)
         x0, y0 = self.train_data_local_dict[int(client_idxs[0])]
         P = len(client_idxs)
-        x = np.empty((P, n_pad) + x0.shape[1:], dtype=x0.dtype)
-        y = np.empty((P, n_pad) + y0.shape[1:], dtype=y0.dtype)
-        mask = np.empty((P, n_pad), dtype=np.float32)
+        x, y, mask = pack_buffers(P, n_pad, x0, y0, out)
         xs = [self.train_data_local_dict[int(c)][0] for c in client_idxs]
         ys = [self.train_data_local_dict[int(c)][1] for c in client_idxs]
         for c, cx, cy in zip(client_idxs, xs, ys):
@@ -118,12 +153,13 @@ class FederatedDataset:
             if len(cx) != len(cy):
                 raise ValueError(
                     f"client {c}: {len(cx)} samples but {len(cy)} labels")
-        # the native packer copies clients in parallel (one thread per
-        # core); on single-core hosts it matches the numpy loop exactly
-        # (both are one memcpy per client), so dispatch costs nothing and
-        # multi-core TPU hosts get the bandwidth win. Small cohorts (or no
-        # toolchain / exotic per-client layouts) take the numpy loop.
-        if x.nbytes >= 1 << 22:
+        # the native packer copies clients in parallel (a thread per 16 MB
+        # of destination, at most 8 and the cores); on single-core hosts it
+        # matches the numpy loop exactly (both are one memcpy per client),
+        # so dispatch costs nothing and multi-core TPU hosts get the
+        # bandwidth win. Small cohorts (or no toolchain / exotic per-client
+        # layouts) take the numpy loop.
+        if x.nbytes >= NATIVE_PACK_FLOOR_BYTES:
             try:
                 from fedml_tpu.native import (NativeUnavailable,
                                               pack_arrays_native)

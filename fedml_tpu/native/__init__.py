@@ -189,6 +189,12 @@ def load_packer() -> ctypes.CDLL:
     return lib
 
 
+#: the most threads one pack call starts. Past 4-8 a pack into a buffer
+#: that has its pages gets slower on the TPU hosts (13 and 30 cores: a
+#: thread's start outweighs its share of the copy; PERF.md, PR 36)
+PACK_THREADS = 8
+
+
 def packer_status() -> str:
     """Which packer has served this process so far: ``"native"`` once the
     C++ library is loaded, ``"python"`` with the reason after a failed
@@ -205,7 +211,10 @@ def pack_arrays_native(srcs, dst, mask=None,
                        n_threads: Optional[int] = None) -> None:
     """Gather ragged per-client arrays into ``dst [P, n_pad, ...]`` with
     parallel memcpy (native/packer.cpp); zero-pads the tail and writes the
-    validity ``mask [P, n_pad]`` when given.
+    validity ``mask [P, n_pad]`` when given. ``dst`` and ``mask`` are the
+    caller's (fresh or recycled: every byte is written). ``n_threads``
+    caps the threads (default: the cores, at most ``PACK_THREADS``); the
+    library takes fewer for a small ``dst``.
 
     ``srcs``: list of P C-contiguous arrays shaped [n_i, ...] with the same
     trailing shape/dtype as ``dst``. Raises :class:`NativeUnavailable` if
@@ -245,7 +254,7 @@ def pack_arrays_native(srcs, dst, mask=None,
         ptrs, counts, P, n_pad, row_bytes,
         dst.ctypes.data_as(ctypes.c_void_p),
         mask.ctypes.data_as(ctypes.c_void_p) if mask is not None else None,
-        n_threads or min(16, os.cpu_count() or 1))
+        n_threads or min(PACK_THREADS, os.cpu_count() or 1))
     if rc != 0:
         raise ValueError("a client has more samples than n_pad")
 

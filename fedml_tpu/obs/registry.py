@@ -44,7 +44,9 @@ METRICS: Dict[str, Dict[str, str]] = {
     "pack": _m(KIND_PHASE, "round pipeline",
                "host-side cohort pack (pad-and-mask shard assembly)"),
     "upload": _m(KIND_PHASE, "round pipeline",
-                 "H2D transfer of the packed cohort"),
+                 "H2D transfer of the packed cohort: its enqueue, and for "
+                 "a cohort whose host buffers are recycled (4 MB and up) "
+                 "the wait until the transfers have read them"),
     "dispatch": _m(KIND_PHASE, "round pipeline",
                    "device round dispatch (async enqueue of the jitted "
                    "round program)"),
@@ -116,6 +118,18 @@ METRICS: Dict[str, Dict[str, str]] = {
     "prefetch_miss": _m(KIND_COUNTER, "prefetch",
                         "round packed inline (cold start / misprediction "
                         "/ dataset swap)"),
+    "pack_buffers_recycled": _m(KIND_COUNTER, "prefetch",
+                                "cohorts packed into host buffers a round "
+                                "before them had used "
+                                "(FedAvgAPI._pack_cohort's pool: no page of "
+                                "them has to be faulted in again)"),
+    "pack_buffers_fresh": _m(KIND_COUNTER, "prefetch",
+                             "cohorts packed into newly allocated host "
+                             "buffers: the pool had none of that shape "
+                             "(first rounds, a new padded length or "
+                             "dataset, after release_prefetch), or the "
+                             "cohort is under the native packer's 4 MB "
+                             "floor"),
     # -- wire accounting (comm backends via launch_federation) -------------
     "comm_bytes_up": _m(KIND_COUNTER, "comm",
                         "client->server wire bytes, actual encoded frame "

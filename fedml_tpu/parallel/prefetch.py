@@ -232,6 +232,76 @@ class RoundPrefetcher:
             return dict(self._stats)
 
 
+class PackBufferPool:
+    """The host buffers packed cohorts are written into, recycled.
+
+    A cohort's ``(x, y, mask)`` triple is ``take``n by the packer, written
+    (every byte: ``pack_clients(out=...)``), uploaded, and ``give``n back
+    by whoever knows the upload is over and that nothing placed shares
+    its arrays' memory (``FedAvgAPI._pack_cohort``). A buffer that is
+    reused has its pages already; a fresh one faults each of them in.
+
+    Triples are kept by ``key`` (the cohort's slots and padded length)
+    for one dataset: a ``take`` or ``give`` for another dataset drops all
+    of them (the ``_pack_cache`` contract), and at most ``capacity``
+    are held, the longest unused going first - so a padded length that no
+    round has any more is not kept. Thread-safe: the prefetch worker and
+    the round thread (a miss) pack at the same time."""
+
+    def __init__(self, capacity: int):
+        self.capacity = max(1, int(capacity))
+        self._lock = threading.Lock()
+        self._dataset = None
+        self._free: list = []  # (key, triple), the longest unused first
+
+    def take(self, dataset, key):
+        """A free triple for ``key``, or None (the caller allocates)."""
+        with self._lock:
+            if dataset is not self._dataset:
+                self._free.clear()
+                self._dataset = dataset
+            for i in range(len(self._free) - 1, -1, -1):
+                if self._free[i][0] == key:
+                    return self._free.pop(i)[1]
+            return None
+
+    def give(self, dataset, key, triple) -> None:
+        """``triple`` is free again (dropped where ``dataset`` is gone)."""
+        with self._lock:
+            if dataset is not self._dataset:
+                return
+            self._free.append((key, triple))
+            del self._free[:-self.capacity]
+
+    def clear(self) -> None:
+        with self._lock:
+            self._free.clear()
+            self._dataset = None
+
+    def __len__(self) -> int:
+        with self._lock:
+            return len(self._free)
+
+
+def aliases_host(host, placed) -> bool:
+    """Whether the placed array's memory lies inside the host array it was
+    made from, read off the buffers' addresses. The CPU backend takes a
+    64-byte-aligned numpy array without copying it (``jnp.asarray`` and
+    ``device_put`` alike), so rewriting the host array would rewrite the
+    "device" array; a chip's memory is apart. Where a backend will not
+    say, the answer is yes."""
+    lo = host.ctypes.data
+    hi = lo + host.nbytes
+    for shard in placed.addressable_shards:
+        try:
+            ptr = shard.data.unsafe_buffer_pointer()
+        except (RuntimeError, NotImplementedError, AttributeError):
+            return True  # this backend will not say: assume it is shared
+        if lo <= ptr < hi:
+            return True
+    return False
+
+
 def bind_prefetcher(slot, dataset, build):
     """Driver-side slot management, ONE definition for every consumer:
     ``slot`` is ``(RoundPrefetcher, dataset-at-bind) | None``. Builds the

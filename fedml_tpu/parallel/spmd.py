@@ -485,7 +485,13 @@ class DistributedFedAvgAPI(FedAvgAPI):
         return list(self.mesh.devices.flat)
 
     def _put(self, a):
-        return jax.device_put(jnp.asarray(a), self._data_sharding)
+        """Each chip's piece of ``a`` straight onto that chip: the slots
+        are contiguous by chip (``_pad_round``, the data axis of
+        ``_data_sharding``), so a host array *is* its pieces, and each is
+        one transfer from the host - not an upload to the first chip and
+        a reshard chip to chip. A device array (the round's keys) is
+        resharded as ever."""
+        return jax.device_put(a, self._data_sharding)
 
     def _pad_round(self, idxs):
         """Pad the sampled-client list to a mesh-size multiple with

@@ -42,6 +42,7 @@ from typing import Callable, Dict, Iterable, Iterator, Optional, Tuple
 
 import numpy as np
 
+from fedml_tpu.data.base import pack_buffers
 from fedml_tpu.state.store import ClientStateStore
 
 _M64 = (1 << 64) - 1
@@ -246,19 +247,22 @@ class VirtualFederatedDataset:
         return x, y
 
     def pack_clients(self, client_idxs, batch_size: Optional[int] = None,
-                     n_pad: Optional[int] = None):
+                     n_pad: Optional[int] = None, out=None):
         """Streaming cohort materialization: fetch each sampled client's
         shard through the store and place it into the padded-and-masked
         ``[P, n_pad, ...]`` round input. Memory: the cohort block plus
-        whatever the LRU holds — never the population."""
+        whatever the LRU holds — never the population. ``out=(x, y,
+        mask)`` as in ``FederatedDataset.pack_clients``: the caller's
+        buffers, every byte of them rewritten."""
         n_pad = n_pad or self.padded_len(batch_size)
         with self.store.pinned("train_x", client_idxs), \
                 self.store.pinned("train_y", client_idxs):
             x0, y0 = self._client_shard(client_idxs[0])
             P = len(client_idxs)
-            x = np.zeros((P, n_pad) + x0.shape[1:], dtype=x0.dtype)
-            y = np.zeros((P, n_pad) + y0.shape[1:], dtype=y0.dtype)
-            mask = np.zeros((P, n_pad), dtype=np.float32)
+            x, y, mask = pack_buffers(P, n_pad, x0, y0, out, alloc=np.zeros)
+            if out is not None:
+                for a in out:
+                    a.fill(0)
             for i, c in enumerate(client_idxs):
                 cx, cy = (x0, y0) if i == 0 else self._client_shard(c)
                 n = len(cx)

@@ -5,9 +5,23 @@
 // (fedml_tpu/data/base.py pack_clients). The reference pays this cost as
 // torch DataLoader iteration + pickle per message
 // (fedml_api/distributed/fedavg/MyModelTrainer.py batch loop); here it is
-// one memcpy/memset pass per client, spread across host cores (a thread
-// pool over clients). On a single-core host this degenerates to exactly
-// the numpy loop's cost; multi-channel hosts get parallel bandwidth.
+// one memcpy/memset pass per client, spread across host cores (threads
+// over clients). On a single-core host this degenerates to exactly the
+// numpy loop's cost; multi-channel hosts get parallel bandwidth.
+//
+// The destination is the caller's, and every byte of it is written (real
+// rows, zeroed tail, mask): pack_clients hands over either arrays it has
+// just allocated or buffers its own caller recycles (out=), and keeps no
+// reference to either. Which of the two matters more than the threads: a
+// fresh destination faults in and zeroes a page for every 4 KB written
+// (104 ms for a 230 MB cohort on a 30-core TPU host with 16 threads; the
+// same copy into a buffer that has its pages 13-17 ms, and 26 ms with one
+// thread: PERF.md, PR 36).
+//
+// Threads are started per call, and a start costs about as much as
+// copying 1-2 MB, so a call takes one thread per kBytesPerThread of
+// destination and never more than the caller allows: a cohort's labels
+// (tens of KB) are copied by the calling thread alone.
 //
 // Layout contract (enforced by the Python wrapper): every client i owns a
 // C-contiguous [counts[i], row_bytes] buffer; dst is C-contiguous
@@ -19,6 +33,10 @@
 #include <cstring>
 #include <thread>
 #include <vector>
+
+namespace {
+constexpr int64_t kBytesPerThread = int64_t{16} << 20;
+}  // namespace
 
 extern "C" {
 
@@ -41,8 +59,9 @@ int fedml_pack_clients(const uint8_t* const* src_ptrs,
       std::fill(m + n, m + n_pad, 0.0f);
     }
   };
-  const int k = static_cast<int>(
-      std::max<int64_t>(1, std::min<int64_t>(n_threads, P)));
+  const int64_t by_size = 1 + P * n_pad * row_bytes / kBytesPerThread;
+  const int k = static_cast<int>(std::max<int64_t>(
+      1, std::min<int64_t>({n_threads, P, by_size})));
   if (k == 1) {
     for (int64_t i = 0; i < P; ++i) work(i);
     return 0;
