@@ -681,9 +681,12 @@ class FedAvgAPI:
             self.timer.count("tokens_dispatched", rows * x.shape[2])
         if getattr(self.config, "fold_clients", False):
             self.timer.count("clients_folded", len(idxs))
+        operands = self._round_operands(args, round_idx)
+        # the program's own map of its device work, on demand
+        # (utils/tracing.py::device_scopes): a lookup a round
+        self.timer.register_program(self._round_fn, self.variables, operands)
         with self.timer.phase("dispatch"):
-            self.variables, stats = self._round_fn(
-                self.variables, *self._round_operands(args, round_idx))
+            self.variables, stats = self._round_fn(self.variables, *operands)
         rec = self.timer.end_round(
             round_idx, extra={"cohort": [int(i) for i in idxs]})
         if self._obs is not None:

@@ -106,15 +106,19 @@ def _attention(p, s, cfg):
     return jnp.swapaxes(out, 1, 2).reshape(rows, length, -1) @ p["o_proj"]
 
 
+@jax.named_scope("fedml.mlp")
+def _mlp(p, s):
+    gate, up = jnp.split(s @ p["ffn_in"], 2, axis=-1)
+    return (jax.nn.silu(gate) * up) @ p["ffn_out"]
+
+
 def _layer(p, x, *, kind: str, cfg):
     """One layer on a batch of rows ``x [B, T, d]``."""
     s = rms_norm(x, p["input_norm_scale"], cfg["eps"])
     mixer = _mamba2 if kind == "mamba" else _attention
     x = x + cfg["residual_multiplier"] * mixer(p, s, cfg)
     s = rms_norm(x, p["post_norm_scale"], cfg["eps"])
-    gate, up = jnp.split(s @ p["ffn_in"], 2, axis=-1)
-    return x + cfg["residual_multiplier"] * (
-        (jax.nn.silu(gate) * up) @ p["ffn_out"])
+    return x + cfg["residual_multiplier"] * _mlp(p, s)
 
 
 class GraniteHybridLM(nn.Module):
@@ -200,7 +204,8 @@ class GraniteHybridLM(nn.Module):
             return TiedHead(jnp.zeros(tokens.shape + (d,), embedding.dtype),
                             embedding)
 
-        x = self.embedding_multiplier * embedding[tokens]
+        with jax.named_scope("fedml.embed"):
+            x = self.embedding_multiplier * embedding[tokens]
         for p, kind in layers:
             x = jax.checkpoint(functools.partial(
                 _layer, kind=kind, cfg=cfg))(p, x)

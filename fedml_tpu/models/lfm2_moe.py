@@ -102,6 +102,11 @@ def _attention(p, s, cfg):
     return jnp.swapaxes(out, 1, 2).reshape(rows, length, -1) @ p["o_proj"]
 
 
+@jax.named_scope("fedml.mlp")
+def _dense_ff(p, s):
+    return (jax.nn.silu(s @ p["ffn_w1"]) * (s @ p["ffn_w3"])) @ p["ffn_w2"]
+
+
 def _layer(p, x, *, kind: str, dense: bool, cfg):
     """One layer on a batch of rows ``x [B, T, d]``; returns ``(x, load)``,
     ``load [B, held]`` the pairs on each held expert (None for a dense
@@ -110,8 +115,7 @@ def _layer(p, x, *, kind: str, dense: bool, cfg):
     x = x + (_short_conv(p, s) if kind == "conv" else _attention(p, s, cfg))
     s = rms_norm(x, p["ffn_norm_scale"], cfg["eps"])
     if dense:
-        return x + (jax.nn.silu(s @ p["ffn_w1"])
-                    * (s @ p["ffn_w3"])) @ p["ffn_w2"], None
+        return x + _dense_ff(p, s), None
     with jax.named_scope("fedml.moe"):
         y, load = routed_experts(
             s, p["router"], p.get("expert_bias"), p["experts_w1"],
@@ -206,7 +210,9 @@ class Lfm2MoeLM(nn.Module):
                 jnp.zeros(tokens.shape + (d,), embedding.dtype), embedding,
                 jnp.zeros((tokens.shape[0], sparse, held), jnp.float32))
 
-        x, loads = embedding[tokens], []
+        with jax.named_scope("fedml.embed"):
+            x = embedding[tokens]
+        loads = []
         for p, layer in layers:
             x, load = jax.checkpoint(functools.partial(
                 _layer, kind=self.layer_types[layer],

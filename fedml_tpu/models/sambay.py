@@ -141,6 +141,12 @@ def _gmu(p, s, memory):
     return (memory * jax.nn.silu(s @ p["gmu_in"])) @ p["gmu_out"]
 
 
+@jax.named_scope("fedml.mlp")
+def _mlp(p, s):
+    gate, up = jnp.split(s @ p["gate_up_proj"], 2, axis=-1)
+    return (jax.nn.silu(gate) * up) @ p["down_proj"]
+
+
 def _layer(p, x, memory, kv, *, kind: str, layer: int, cfg):
     """One layer on one sequence; returns ``(x, memory, kv)`` with the two
     hand-ons replaced where this layer makes them."""
@@ -166,9 +172,7 @@ def _layer(p, x, memory, kv, *, kind: str, layer: int, cfg):
         mix = (_diff_attention(p, q, k, v, layer, window, cfg)
                @ p["o_proj"] + p["o_bias"])
     x = x + mix
-    gate, up = jnp.split(_layer_norm(p, "norm2", x, cfg["eps"])
-                         @ p["gate_up_proj"], 2, axis=-1)
-    return x + (jax.nn.silu(gate) * up) @ p["down_proj"], memory, kv
+    return x + _mlp(p, _layer_norm(p, "norm2", x, cfg["eps"])), memory, kv
 
 
 class SambaYLM(nn.Module):
@@ -271,7 +275,9 @@ class SambaYLM(nn.Module):
                     if self.return_logits else TiedHead(hidden, embedding))
 
         def sequence(ids):
-            x, memory, kv = embedding[ids], None, None
+            with jax.named_scope("fedml.embed"):
+                x = embedding[ids]
+            memory, kv = None, None
             for p, kind, layer in layers:
                 x, memory, kv = jax.checkpoint(functools.partial(
                     _layer, kind=kind, layer=layer, cfg=cfg))(

@@ -104,6 +104,30 @@ def _position_stats(logits, targets):
     return per_tok, (jnp.argmax(logits, -1) == targets).astype(jnp.float32)
 
 
+def _tied_position_stats(hidden, embedding, targets):
+    """``_position_stats`` of a tied head's logits, formed ``LOGIT_BLOCK``
+    positions at a time, each block rematerialised."""
+    rows, length, _ = hidden.shape
+    block = min(LOGIT_BLOCK, length)
+    pad = (-length) % block
+
+    def blocks(a):
+        a = jnp.pad(a, [(0, 0), (0, pad)] + [(0, 0)] * (a.ndim - 2))
+        a = a.reshape((rows, -1, block) + a.shape[2:])
+        return jnp.swapaxes(a, 0, 1)
+
+    @jax.checkpoint
+    def one(inp):
+        h, t = inp
+        return _position_stats(jnp.einsum("btd,vd->btv", h, embedding), t)
+
+    def rejoin(a):
+        return jnp.swapaxes(a, 0, 1).reshape(rows, -1)[:, :length]
+
+    return jax.tree.map(rejoin, jax.lax.map(
+        one, (blocks(hidden), blocks(targets))))
+
+
 def lm_rows_head(out, targets: jnp.ndarray, mask: jnp.ndarray) -> Stats:
     """Language modelling where the accounting unit is the *row*: one packed
     sequence whose ``T`` positions all carry a target (no pad id). Per row
@@ -121,30 +145,10 @@ def lm_rows_head(out, targets: jnp.ndarray, mask: jnp.ndarray) -> Stats:
     if isinstance(out, RoutedTiedHead):
         routing = _routing_stats(out.expert_load, mask)
         out = TiedHead(out.hidden, out.embedding)
-    if isinstance(out, TiedHead):
-        hidden, embedding = out
-        rows, length, _ = hidden.shape
-        block = min(LOGIT_BLOCK, length)
-        pad = (-length) % block
-
-        def blocks(a):
-            a = jnp.pad(a, [(0, 0), (0, pad)] + [(0, 0)] * (a.ndim - 2))
-            a = a.reshape((rows, -1, block) + a.shape[2:])
-            return jnp.swapaxes(a, 0, 1)
-
-        @jax.checkpoint
-        def one(inp):
-            h, t = inp
-            return _position_stats(jnp.einsum("btd,vd->btv", h, embedding),
-                                   t)
-
-        def rejoin(a):
-            return jnp.swapaxes(a, 0, 1).reshape(rows, -1)[:, :length]
-
-        per_tok, correct = jax.tree.map(rejoin, jax.lax.map(
-            one, (blocks(hidden), blocks(targets))))
-    else:
-        per_tok, correct = _position_stats(out, targets)
+    with jax.named_scope("fedml.lm_head"):
+        per_tok, correct = (_tied_position_stats(*out, targets)
+                            if isinstance(out, TiedHead)
+                            else _position_stats(out, targets))
     return {
         "loss_sum": jnp.sum(jnp.mean(per_tok, axis=-1) * mask),
         "count": jnp.sum(mask),

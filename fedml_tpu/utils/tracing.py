@@ -42,6 +42,18 @@ The reference's only tracing is wall-clock log lines
   device ran out of queued work while the host prepared the next round —
   phases ``device_starved`` (lower bound) and ``device_starved_max``, with
   no profiler, in every run.
+- **Device scopes** (``device_scopes``): the drivers name their device work
+  with ``jax.named_scope("fedml.<layer>")``, and the profiler's device
+  events carry only an instruction's name. ``run_round`` hands the timer,
+  the first time it dispatches a given operand shape, the jitted round
+  program and the abstract values of its operands
+  (``RoundTimer.register_program``: one tuple of shapes and a lookup a
+  round, nothing lowered). On demand ``device_scopes()`` lowers each
+  registered program from those abstract values - which finds the
+  lowering the call itself made and on it the executable that ran, no
+  second compilation - reads the optimised HLO and returns, for every
+  instruction, the ``fedml.*`` scopes it ran under: what joins a device
+  trace to the program's layers by instruction name.
 - ``profile`` — context manager around ``jax.profiler.trace`` emitting a
   TensorBoard-loadable trace directory when enabled, a no-op otherwise.
 """
@@ -49,13 +61,19 @@ The reference's only tracing is wall-clock log lines
 from __future__ import annotations
 
 import contextlib
+import gc
 import itertools
+import re
+import sys
 import threading
 import time
 import weakref
 from collections import defaultdict, deque
-from typing import Dict, Iterator, List, Optional, Tuple
+from typing import (Dict, FrozenSet, Iterator, List, NamedTuple, Optional,
+                    Tuple)
 
+import jax
+import numpy as np
 from jax.profiler import TraceAnnotation
 
 #: ``(name, thread name, round index open when the span closed or None,
@@ -67,18 +85,24 @@ Span = Tuple[str, str, Optional[int], int, int]
 #: span ring holds ``ring_capacity`` rounds of them
 SPANS_PER_ROUND = 8
 
-#: the process's live timers, for ``recent_spans``
+#: the ``fedml.*`` scopes an instruction ran under, outermost first
+Chain = Tuple[str, ...]
+
+#: the process's live timers, for ``recent_spans`` and ``device_scopes``
 _timers: "weakref.WeakSet[RoundTimer]" = weakref.WeakSet()
 _timers_lock = threading.Lock()
+
+
+def _live_timers() -> List["RoundTimer"]:
+    with _timers_lock:
+        return list(_timers)
 
 
 def recent_spans() -> List[Span]:
     """The spans still in the ring of every live ``RoundTimer`` of this
     process, by start: the program's host timeline for a reader that has no
     handle on the driver (the benchmark's ``idle_by_program_span``)."""
-    with _timers_lock:
-        timers = list(_timers)
-    return sorted((s for t in timers for s in t.spans()),
+    return sorted((s for t in _live_timers() for s in t.spans()),
                   key=lambda s: s[3])
 
 
@@ -112,6 +136,9 @@ class RoundTimer:
         #: for the open round
         self._open_round = None
         self._flight = None
+        #: the round programs dispatched so far, by jitted function and
+        #: operand shapes (``register_program``)
+        self._programs: Dict[tuple, _Program] = {}
         with _timers_lock:
             _timers.add(self)
 
@@ -163,6 +190,24 @@ class RoundTimer:
             self._close_span("device_starved" if idle_at_open
                              else "device_starved_max", t0, t1)
             self.count("starved_rounds")
+
+    def register_program(self, fn, model, operands: tuple) -> None:
+        """``fn(model, *operands)`` is about to be dispatched: the first
+        time for these operand shapes, keep the jitted ``fn`` and the
+        abstract values (shape, dtype, sharding; never the arrays, the
+        model is donated) of what it is called with, for
+        ``device_scopes``. Every other round costs the tuple of shapes and
+        one lookup; nothing is lowered here."""
+        key = (fn,) + tuple(a.shape for a in operands)
+        if key in self._programs:
+            return
+        with self._lock:
+            self._programs[key] = _Program(fn, _abstract((model,) + operands))
+
+    def programs(self) -> List["_Program"]:
+        """The registered round programs, oldest first."""
+        with self._lock:
+            return list(self._programs.values())
 
     def spans(self) -> List[Span]:
         """The span ring, oldest first."""
@@ -314,6 +359,211 @@ class RoundTimer:
             out += " | " + " | ".join(
                 f"{k}: {v:.1f}" for k, v in sorted(gauges.items()))
         return out
+
+
+# -- device scopes: the compiled round programs' own names for their work ----
+
+class ScopeMap(NamedTuple):
+    """One HLO module's instructions by the ``fedml.*`` scopes they ran
+    under. ``chains[instruction]`` is the scope chain, outermost first and
+    empty where nobody named the work; ``mixed`` names the fusions whose
+    fused instructions carry more than one chain, where the fusion rule of
+    ``parse_hlo_scopes`` decided; ``kinds[instruction]`` is its result type
+    and operation without layouts (``f32[8,128] fusion``), by which a
+    reader tells that an event of that name is this instruction and not
+    another compilation's."""
+
+    chains: Dict[str, Chain]
+    mixed: FrozenSet[str]
+    kinds: Dict[str, str]
+
+
+class _Program:
+    """A registered round program; ``scopes`` is ``(module name, ScopeMap)``
+    once read, None where reading failed."""
+
+    __slots__ = ("fn", "avals", "scopes")
+    _UNREAD = object()
+
+    def __init__(self, fn, avals):
+        self.fn, self.avals, self.scopes = fn, avals, self._UNREAD
+
+
+def _abstract(tree):
+    """The abstract values ``jit`` would see: shape, dtype, weak type, and
+    the sharding of a committed array only - so that lowering them again
+    finds the lowering, and with it the executable, the call itself made
+    (a placement the call never named would be another program)."""
+    def one(a):
+        committed = getattr(a, "committed", False)
+        return jax.ShapeDtypeStruct(
+            np.shape(a), jax.numpy.result_type(a),
+            sharding=a.sharding if committed else None,
+            weak_type=getattr(a, "weak_type", False))
+    return jax.tree.map(one, tree)
+
+
+_SCOPE = re.compile(r"fedml\.\w+")
+_MODULE = re.compile(r"^HloModule\s+([^\s,]+)")
+_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([^\s(]+)\s+\(.*->.*\{\s*$")
+_INSTRUCTION = re.compile(r"^\s+(?:ROOT\s+)?%?([^\s=]+)\s+=\s+(.*)$")
+_LAYOUT = re.compile(r"\{[^{}]*\}")
+_KIND = re.compile(r"^(.*?[\w\-])\(")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_CALLED = re.compile(
+    r"\b(?:calls|body|condition|to_apply|true_computation|"
+    r"false_computation)=%?([^\s,)}]+)"
+    r"|\b(?:branch_computations|called_computations)=\{([^}]*)\}")
+#: the operations whose scope a fusion takes before its root's
+_PRODUCTS = ("dot", "convolution")
+
+
+def scope_chain(op_name: str) -> Chain:
+    """The ``fedml.*`` tokens of an HLO ``op_name`` in order, each once:
+    ``jit(f)/fedml.local_train/transpose(jvp(fedml.mamba2))/fedml.ssd/dot``
+    is ``(fedml.local_train, fedml.mamba2, fedml.ssd)``."""
+    return tuple(dict.fromkeys(_SCOPE.findall(op_name)))
+
+
+def instruction_kind(rest: str) -> str:
+    """Of an instruction as HLO text prints it after its ``=``, the result
+    type and the operation, layouts dropped: what precedes the operands."""
+    found = _KIND.match(_LAYOUT.sub("", rest))
+    return found.group(1) if found else ""
+
+
+def parse_hlo_scopes(text: str) -> Tuple[str, ScopeMap]:
+    """``(module name, ScopeMap)`` of an HLO module as ``as_text()``
+    prints it, metadata included. An instruction's chain is
+    ``scope_chain`` of its own ``op_name``; one without a ``fedml.*`` token
+    of its own (a copy a layout pass inserted, a parameter, a tuple)
+    inherits the chain of the instruction that calls its computation
+    (``while`` body and condition, ``call``, ``conditional``). A fusion
+    takes the chain of the ``dot`` / ``convolution`` inside its fused
+    computation where that has one (a weight gradient with the SGD update
+    fused in has the update as its root), else its own. The fused
+    computations' own instructions are left out: a trace never shows
+    them."""
+    module = ""
+    own: Dict[str, Chain] = {}
+    kinds: Dict[str, str] = {}
+    opcode: Dict[str, str] = {}
+    home: Dict[str, str] = {}  # instruction -> its computation
+    members: Dict[str, List[str]] = defaultdict(list)
+    caller: Dict[str, str] = {}  # computation -> an instruction calling it
+    fused: Dict[str, str] = {}  # fusion instruction -> its computation
+    computation = None
+    for line in text.splitlines():
+        if computation is None:
+            found = _COMPUTATION.match(line)
+            if found:
+                computation = found.group(1)
+            elif not module:
+                found = _MODULE.match(line)
+                module = found.group(1) if found else ""
+            continue
+        if line.startswith("}"):
+            computation = None
+            continue
+        found = _INSTRUCTION.match(line)
+        if not found:
+            continue
+        name, rest = found.groups()
+        named = _OP_NAME.search(rest)
+        own[name] = scope_chain(named.group(1)) if named else ()
+        kinds[name] = instruction_kind(rest)
+        opcode[name] = kinds[name].rpartition(" ")[2]
+        home[name] = computation
+        members[computation].append(name)
+        for one, many in _CALLED.findall(rest):
+            for called in (one,) if one else many.split(","):
+                called = called.strip().lstrip("%")
+                caller.setdefault(called, name)
+                if opcode[name] == "fusion":
+                    fused[name] = called
+
+    resolved: Dict[str, Chain] = {}
+
+    def chain(name: str) -> Chain:
+        if name not in resolved:
+            above = caller.get(home[name])
+            resolved[name] = own[name] or (chain(above) if above else ())
+        return resolved[name]
+
+    inside = set(fused.values())
+    chains, mixed = {}, set()
+    for name, computation in home.items():
+        if computation in inside:
+            continue
+        chains[name] = chain(name)
+        if name in fused:
+            held = [own[m] for m in members[fused[name]] if own[m]]
+            products = [own[m] for m in members[fused[name]]
+                        if own[m] and opcode[m] in _PRODUCTS]
+            if products:
+                chains[name] = products[0]
+            if len(set(held)) > 1:
+                mixed.add(name)
+    return module, ScopeMap(chains, frozenset(mixed),
+                            {name: kinds[name] for name in chains})
+
+
+def _read_scopes(program: _Program) -> Optional[Tuple[str, ScopeMap]]:
+    """Lower ``program`` from its abstract operands - which finds the
+    lowering the call made, and on it the executable that ran - and read
+    its optimised HLO; what it cost goes to standard error. None, with the
+    reason there, on any failure."""
+    t0 = time.perf_counter()
+    try:
+        device = jax.local_devices()[0]
+        before = (device.memory_stats() or {}).get("bytes_in_use")
+        compiled = program.fn.lower(*program.avals).compile()
+        module, scopes = parse_hlo_scopes(compiled.as_text())
+        during = (device.memory_stats() or {}).get("bytes_in_use")
+    except Exception as why:  # noqa: BLE001 - an observer must not raise
+        print(f"[fedml] device_scopes: no map of a round program: "
+              f"{type(why).__name__}: {str(why)[:300]}", file=sys.stderr,
+              flush=True)
+        return None
+    print(f"[fedml] device_scopes: {module} lowered and read in "
+          f"{time.perf_counter() - t0:.2f} s, {len(scopes.chains)} "
+          f"instructions, {len(scopes.mixed)} mixed fusions; device bytes "
+          f"in use {before} -> {during}", file=sys.stderr, flush=True)
+    return module, scopes
+
+
+def device_scopes() -> Dict[str, ScopeMap]:
+    """``{HLO module name: ScopeMap}`` of the round programs the live
+    ``RoundTimer``s of this process have registered (dropped drivers
+    collected first): the join between a device trace, whose events carry
+    an instruction's name and nothing else, and the program's ``fedml.*``
+    scopes (the benchmark's ``scope_ops``). Each program is read once
+    (``_read_scopes``) and kept. Two programs of one module name (a second
+    padded length) are merged; an instruction they disagree on is left
+    out, so a reader's coverage says so. Empty where nothing was
+    registered or nothing could be read."""
+    gc.collect()  # a dropped driver sits in cycles with its prefetcher
+    timers = _live_timers()
+    out: Dict[str, ScopeMap] = {}
+    disagreed: Dict[str, set] = defaultdict(set)
+    for program in (p for timer in timers for p in timer.programs()):
+        if program.scopes is _Program._UNREAD:
+            program.scopes = _read_scopes(program)
+        if program.scopes is None:
+            continue
+        module, scopes = program.scopes
+        chains, mixed, kinds = out.get(module, ScopeMap({}, frozenset(), {}))
+        disagreed[module] |= {
+            name for name, chain in scopes.chains.items()
+            if (chains.setdefault(name, chain),
+                kinds.setdefault(name, scopes.kinds[name]))
+            != (chain, scopes.kinds[name])}
+        out[module] = ScopeMap(chains, mixed | scopes.mixed, kinds)
+    return {module: ScopeMap(
+        {n: c for n, c in chains.items() if n not in disagreed[module]},
+        mixed - disagreed[module],
+        {n: k for n, k in kinds.items() if n not in disagreed[module]})
+        for module, (chains, mixed, kinds) in out.items()}
 
 
 @contextlib.contextmanager
