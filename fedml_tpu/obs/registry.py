@@ -112,6 +112,13 @@ METRICS: Dict[str, Dict[str, str]] = {
                          "clients a folded round (FedAvgConfig.fold_clients) "
                          "trained one after another and folded into the "
                          "running sum, added at every dispatch"),
+    "clients_first_step_out_of_place": _m(
+        KIND_COUNTER, "round pipeline",
+        "clients of a folded round whose first local step read the global "
+        "model's leaves and wrote the client's own (local_train's "
+        "shared_init, which make_folded_body passes), so no leaf was "
+        "copied to start the client; added at every dispatch, absent where "
+        "the cohort trains under a vmap"),
     # -- prefetch counters (parallel/prefetch.py) --------------------------
     "prefetch_hit": _m(KIND_COUNTER, "prefetch",
                        "round consumed a speculatively packed cohort"),

@@ -276,6 +276,18 @@ def make_local_train(module, task: str, cfg: TrainConfig,
     leaves out are the gated no-ops. Without it the loop is the ``scan``
     over all ``n_pad // batch_size`` batches.
 
+    ``local_train(..., shared_init=True)`` (static, like ``n_steps`` the
+    caller's to know) says that ``variables`` start clients that run one
+    after another (``make_folded_body``'s loop over the silos), so they
+    have to outlive this one. A step loop updates its carry in place and
+    XLA would first copy every leaf into it, once a client; instead the
+    first step is taken before the loop - it reads the caller's leaves and
+    its update writes this client's - and the ``scan`` runs the steps that
+    remain (none left: no loop). The same ``step``, batches and keys in the
+    same order, the first step's stats joined to the stacked ones: the
+    arithmetic is the whole-length scan's. Not with ``n_steps``, whose
+    callers train their clients side by side.
+
     What the loop carries: of every kernel the model declares a live window
     of at these rows' shape (``models/common.py::live_windows``: a padded
     convolution on a map smaller than its kernel) the window alone -
@@ -314,7 +326,12 @@ def make_local_train(module, task: str, cfg: TrainConfig,
     # the scope names the trainer's operations in a device trace; it is
     # location metadata and changes no instruction
     @jax.named_scope("fedml.local_train")
-    def local_train(variables, x, y, mask, rng, lr_scale=None, n_steps=None):
+    def local_train(variables, x, y, mask, rng, lr_scale=None, n_steps=None,
+                    shared_init=False):
+        if shared_init and n_steps is not None:
+            raise ValueError(
+                "shared_init takes the first step out of the whole-length "
+                "scan; the n_steps loop has no such step to take out")
         n_pad = x.shape[0]
         bsz = cfg.batch_size or n_pad
         # accum_steps divisibility cannot be checked here: only REAL
@@ -408,7 +425,18 @@ def make_local_train(module, task: str, cfg: TrainConfig,
                         colls)
             return (params, colls, opt_state), stats
 
-        if n_steps is None:
+        if shared_init:
+            # the first step reads the caller's leaves and writes this
+            # client's own; the loop carries those, in place, from there
+            state, first = step(init, (batch_idx[0], step_keys[0]))
+            stats = jax.tree.map(lambda s: s[None], first)
+            if batch_idx.shape[0] > 1:
+                state, rest = jax.lax.scan(
+                    step, state, (batch_idx[1:], step_keys[1:]))
+                stats = jax.tree.map(
+                    lambda a, b: jnp.concatenate([a, b]), stats, rest)
+            params, colls, _ = state
+        elif n_steps is None:
             (params, colls, _), stats = jax.lax.scan(
                 step, init, (batch_idx, step_keys))
         else:

@@ -52,6 +52,15 @@ def _api(dataset, module, task, **config):
         **config))
 
 
+def _in_place(local_train):
+    """``local_train`` deaf to ``shared_init``: under ``make_folded_body``
+    the folded round as it was before the first step left the loop."""
+    def train(*args, shared_init=False, **kwargs):
+        return local_train(*args, **kwargs)
+
+    return train
+
+
 # -- the kernel -------------------------------------------------------------------
 
 @pytest.mark.parametrize("shape", [(8, 128), (24, 384), (520, 256),
@@ -323,19 +332,25 @@ def _digest(fn, *args) -> str:
 
 
 #: the first 16 hex digits of sha256(str(jaxpr)) as commit c3a6b52 (before
-#: the stacked mean went leaf by leaf) traces these under this JAX
+#: the stacked mean went leaf by leaf) traces these under this JAX. Since
+#: PR 38 ``make_folded_body`` starts a silo out of place: a ``local_train``
+#: deaf to ``shared_init`` still traces c3a6b52's round under it, and the
+#: round with the first step before the loop is pinned as PR 38 traces it
 PARENT = {"fold_weighted": "99ab2da55ece564a",
           "tree_fold_pallas": "9d91e92b7d7db19d",
-          "make_folded_body": "628c1bd4a2e0ea6d"}
+          "make_folded_body": "628c1bd4a2e0ea6d",
+          "make_folded_body.shared_init": "3ae6ecbd3044d48c"}
 
 
 @pytest.mark.parametrize("what", sorted(PARENT))
 def test_the_fold_traces_the_parents_program(what, hybrid):
-    if what == "make_folded_body":
+    if what.startswith("make_folded_body"):
         dataset, module = hybrid
         api = _api(dataset, module, "lm_rows")
         _, (x, y, mask, keys, weights, _) = api._pack_round(0)[1:]
-        got = _digest(make_folded_body(api._local_train, interpret=True),
+        train = (api._local_train if what.endswith("shared_init")
+                 else _in_place(api._local_train))
+        got = _digest(make_folded_body(train, interpret=True),
                       api.variables, x, y, mask, keys, weights)
     elif what == "tree_fold_pallas":
         tree = {"w": jnp.zeros((520, 256)), "narrow": jnp.zeros((5120, 16)),
