@@ -73,6 +73,15 @@ def create_model(model_name: str, output_dim: int = 10, **kw):
             if name in kw:
                 kw = {**kw, name: tuple(kw[name])}
         return GraniteHybridLM(vocab_size=output_dim, **kw)
+    if model_name == "deepseek_v3":
+        # kanana-2-30b-a3b-instruct-2601's decoder (latent attention, routed
+        # experts beside shared ones); output_dim is the rows held of the
+        # embedding and of the untied head
+        from fedml_tpu.models.deepseek_v3 import DeepseekV3LM
+        for name in ("layer_ids", "experts_held"):
+            if name in kw:
+                kw = {**kw, name: tuple(kw[name])}
+        return DeepseekV3LM(vocab_size=output_dim, **kw)
     if model_name in ("vgg11", "vgg13", "vgg16", "vgg19"):
         from fedml_tpu.models.vgg import VGG
         return VGG(arch=model_name, num_classes=output_dim, **kw)

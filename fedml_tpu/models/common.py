@@ -46,6 +46,16 @@ def dt_bias_init(key, shape, dtype=jnp.float32):
     return dt + jnp.log(-jnp.expm1(-dt))
 
 
+def expert_bias_init(layer: int):
+    """A router's selection bias: normal(0, 0.1), from a fixed key and the
+    published layer index, so the same for every seed (as a checkpoint's)."""
+    def init(key, shape, dtype=jnp.float32):
+        del key
+        return 0.1 * jax.random.normal(
+            jax.random.fold_in(jax.random.key(0), layer), shape, dtype)
+    return init
+
+
 def rms_norm(x, scale, eps: float):
     """``x / rms(x) * scale`` over the last axis."""
     return x * lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True)

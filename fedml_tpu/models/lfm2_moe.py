@@ -48,7 +48,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from fedml_tpu.models.common import Leaves, Spec, rms_norm, rotary
+from fedml_tpu.models.common import (Leaves, Spec, expert_bias_init,
+                                     rms_norm, rotary)
 from fedml_tpu.ops.block_attention import causal_attention
 from fedml_tpu.ops.moe import routed_experts
 from fedml_tpu.trainer.tasks import RoutedTiedHead
@@ -60,15 +61,6 @@ LFM2_8B_LAYER_TYPES = tuple(
 
 _normal = nn.initializers.normal(0.02)
 _ones = nn.initializers.ones
-
-
-def _expert_bias(layer: int):
-    """normal(0, 0.1), from a fixed key and the published layer index."""
-    def init(key, shape, dtype=jnp.float32):
-        del key
-        return 0.1 * jax.random.normal(
-            jax.random.fold_in(jax.random.key(0), layer), shape, dtype)
-    return init
 
 
 @jax.named_scope("fedml.short_conv")
@@ -177,7 +169,7 @@ class Lfm2MoeLM(nn.Module):
                   ("experts_w2", (held, width, d), _normal))
             if self.use_expert_bias:
                 ff += (("expert_bias", (self.num_experts,),
-                        _expert_bias(layer)),)
+                        expert_bias_init(layer)),)
         return norms + op + ff
 
     @nn.compact

@@ -608,9 +608,11 @@ def manifest():
 
 
 def test_the_twelve_entries_are_appended_and_nothing_else_moved(manifest):
-    assert [m["name"] for m in manifest["per_layer"]][30:] == [
+    assert [m["name"] for m in manifest["per_layer"]][30:43] == [
         "pack_recycled_per_round"] + [name for name, *_ in NEW]
-    assert len(manifest["workloads"]) == 8 and len(manifest["configs"]) == 5
+    # later PRs append cells and configurations (PR 39 a ninth and a sixth)
+    assert [w["name"] for w in manifest["workloads"]][:8] == ALL
+    assert len(manifest["configs"]) >= 5
     assert len(json.dumps(manifest, indent=2)) < 64 * 1024
     layers = {m["layer"] for m in manifest["per_layer"][:31]}
     assert {m["layer"] for m in manifest["per_layer"][31:]} <= layers

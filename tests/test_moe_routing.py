@@ -204,3 +204,109 @@ def test_a_wrong_expert_count_is_refused():
         routed_experts(p["s"], p["router"], p["bias"], p["w1"][:3],
                        p["w3"][:3], p["w2"][:3], top_k=K,
                        experts_held=(0, 4))
+
+
+# -- small loads in large blocks; the epsilon is the caller's (PR 39) --------------
+
+@pytest.mark.parametrize("first, count", [(0, 8), (2, 4)])
+def test_blocks_of_8_and_of_512_give_the_same_values_and_gradients(first,
+                                                                   count):
+    """96 pairs fill a dozen blocks of 8 and a part of one of 512: the
+    regime of many small experts (a few rows in a block of ``BLOCK``)."""
+    p = _weights(seed=10)
+    probe = jax.random.normal(jax.random.key(11), (T, D))
+
+    def through(block):
+        def loss(p):
+            y, load = _share(p, first, count, block=block)
+            return jnp.sum(y * probe), (y, load)
+        return jax.jit(jax.value_and_grad(loss, has_aux=True))(p)
+
+    (_, (small, load_small)), grad_small = through(8)
+    (_, (large, load_large)), grad_large = through(512)
+    want, _ = _dense(p, first, count)
+    assert _rel(small, want) < 1e-5 and _rel(large, want) < 1e-5
+    np.testing.assert_array_equal(load_small, load_large)
+    for name in ("s", "router", "w1", "w3", "w2"):
+        assert _rel(grad_small[name], grad_large[name]) < 1e-5, name
+    assert not np.any(np.asarray(grad_small["bias"]))
+
+
+@pytest.mark.parametrize("block", [8, 128])
+def test_a_load_under_one_block_and_one_of_several(block):
+    """Expert 0 is everybody's choice (48 pairs: six blocks of 8, a part of
+    one of 128), expert 1 is chosen where its score wins the second place
+    (a few pairs: under one block of either size)."""
+    p = _weights(seed=12, bias_scale=0.0)
+    p["bias"] = jnp.zeros(E).at[0].set(10.0)
+    y, load = jax.jit(lambda p: _share(p, 0, 2, block=block))(p)
+    want, chosen = _dense(p, 0, 2)
+    assert int(load[0]) == T and 0 < int(load[1]) < 8
+    assert int(load[1]) == int(jnp.sum(chosen == 1))
+    assert _rel(y, want) < 1e-5
+
+
+def test_the_blocks_the_plan_runs_follow_the_block():
+    chosen = jnp.asarray(np.random.RandomState(0).randint(0, E, (T, K)),
+                         jnp.int32)
+    load = np.bincount(np.asarray(chosen).ravel(), minlength=E)[2:6]
+    for block in (8, 16, 512):
+        with mock.patch.object(moe, "BLOCK", block):
+            plan = moe._plan(chosen, 2, 4)
+        size = min(block, T * K)
+        assert plan.tokens.shape[1] == size
+        assert int(plan.n_run) == int(np.sum(-(-load // size)))
+        assert int(plan.valid.sum()) == int(load.sum())
+
+
+def test_the_epsilon_of_the_normalisation_is_the_callers():
+    p = _weights(seed=13)
+    prob = np.asarray(jax.nn.sigmoid(p["s"] @ p["router"]), np.float64)
+    for eps in (1e-20, 1e-6, 0.5):
+        chosen, weights = route(p["s"], p["router"], p["bias"], top_k=K,
+                                norm_topk=True, scale=2.448, eps=eps)
+        picked = np.take_along_axis(prob, np.asarray(chosen), axis=-1)
+        np.testing.assert_allclose(
+            weights, 2.448 * picked / (picked.sum(-1, keepdims=True) + eps),
+            rtol=1e-5)
+    # left out it is 1e-6, and ``routed_experts`` hands it on
+    _, default = route(p["s"], p["router"], p["bias"], top_k=K,
+                       norm_topk=True, scale=1.0)
+    _, named = route(p["s"], p["router"], p["bias"], top_k=K,
+                     norm_topk=True, scale=1.0, eps=1e-6)
+    np.testing.assert_array_equal(default, named)
+    def whole(eps):
+        return routed_experts(p["s"], p["router"], p["bias"], p["w1"],
+                              p["w3"], p["w2"], top_k=K,
+                              experts_held=(0, E), eps=eps)[0]
+
+    loose, tight = whole(0.5), whole(1e-20)
+    assert _rel(loose, tight) > 0.1
+
+
+#: the first 16 hex digits of sha256(str(jaxpr)) of the layer and of its
+#: five gradients at commit 6105f72 (PR 38, before ``eps`` was a keyword),
+#: for a call shaped like ``models/lfm2_moe.py``'s: rows of tokens, top-4 of
+#: 32 experts, 8 held, no epsilon named
+LFM2_SHAPED = {"layer": "f2d848aaa50d8aa0", "gradients": "c2df61f56eb10cf9"}
+
+
+@pytest.mark.parametrize("what", sorted(LFM2_SHAPED))
+def test_with_the_defaults_the_traced_program_is_the_parents(what):
+    import hashlib
+
+    def layer(s, router, bias, w1, w3, w2):
+        return routed_experts(s, router, bias, w1, w3, w2, top_k=4,
+                              experts_held=(0, 8), norm_topk=True, scale=1.0)
+
+    def gradients(*args):
+        return jax.grad(lambda *a: jnp.sum(layer(*a)[0] ** 2),
+                        argnums=(0, 1, 3, 4, 5))(*args)
+
+    args = [jax.ShapeDtypeStruct(shape, jnp.float32) for shape in (
+        (2, 64, 32), (32, 32), (32,), (8, 32, 48), (8, 32, 48), (8, 48, 32))]
+    fn = {"layer": layer, "gradients": gradients}[what]
+    with jax.default_matmul_precision(None):
+        got = hashlib.sha256(
+            str(jax.make_jaxpr(fn)(*args)).encode()).hexdigest()[:16]
+    assert got == LFM2_SHAPED[what]

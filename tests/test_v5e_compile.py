@@ -181,3 +181,49 @@ def test_the_chunked_ssd_scan_is_xla_on_a_v5e(one_chip):
              if " = " in line and pattern.search(line)]
     assert any(" convolution(" in line or " fusion(" in line
                for line in named)
+
+
+def test_the_latent_attention_and_the_sparse_layer_are_xla_on_a_v5e(one_chip):
+    """One sparse layer of ``models/deepseek_v3.py`` at the widths of
+    ``kanana_2_30b_a3b_ep8.silo4`` (a row of 2,048 tokens; 32 heads of 128 +
+    64 | 128 over a latent of 512; top-6 of 128, 16 experts of 2048 x 768
+    held in blocks of 512 rows, the shared SwiGLU of 1536), forward and
+    backward: the TPU compiler makes no custom call of the latent-attention
+    block, the routed or the shared experts - the benchmark books every
+    ``tpu_custom_call`` of the round as aggregation. The experts' width
+    names their tensors for ``benchmark/metrics/small_expert_ms.json``."""
+    from fedml_tpu.models import create_model, deepseek_v3
+
+    module = create_model("deepseek_v3", output_dim=16032,
+                          experts_held=(0, 16), layer_ids=(1,))
+    cfg = module.cfg()
+    shapes = jax.eval_shape(lambda: module.init(
+        jax.random.key(0), jnp.zeros((1, 8), jnp.int32), train=False))
+    leaves = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        shapes["params"]["layer_01"])
+
+    def loss(p, x):
+        y, load = deepseek_v3._layer(p, x, dense=False, cfg=cfg)
+        return jnp.sum(y * y), load
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)
+                   ).lower(leaves, jax.ShapeDtypeStruct(
+                       (1, 2048, 2048), jnp.float32, sharding=one_chip)
+                   ).compile().as_text()
+    assert "tpu_custom_call" not in text
+    assert "while" in text and re.search(r"f32\[16,2048,768\]", text)
+    # a block of queries against the keys it may see, every head
+    assert re.search(r"\[32,(1,)?512,(512|1024|1536|2048)\]", text)
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmark", "metrics",
+            "small_expert_ms.json")) as f:
+        pattern = re.compile(json.load(f)["args"]["pattern"])
+    named = [line for line in text.splitlines()
+             if " = " in line and pattern.search(line)]
+    assert any(" convolution(" in line or " fusion(" in line
+               for line in named)
+    # the shared experts and the attention's widths are not the pattern's
+    for shape in ("f32[2048,1536]", "f32[2048,576]", "f32[32,2048,192]",
+                  "f32[2048,6144]", "f32[512,8192]", "f32[2048,128]"):
+        assert not pattern.search(shape), shape
