@@ -9,9 +9,14 @@ NCHW). ``create_model`` mirrors the reference's experiment-level factory
 from fedml_tpu.models.lr import LogisticRegression
 from fedml_tpu.models.cnn import CNN_DropOut
 
+# configuration keys a decoder takes as a tuple (a flax field has to hash);
+# a JSON configuration gives them as lists
+TUPLE_KEYS = ("layer_ids", "layer_types", "experts_held")
+
 
 def create_model(model_name: str, output_dim: int = 10, **kw):
     """Model factory with reference naming (main_fedavg.py:229-266)."""
+    kw = {k: tuple(v) if k in TUPLE_KEYS else v for k, v in kw.items()}
     if model_name == "lr":
         return LogisticRegression(num_classes=output_dim)
     if model_name == "cnn":
@@ -52,36 +57,31 @@ def create_model(model_name: str, output_dim: int = 10, **kw):
         # Phi-4-mini-flash-reasoning's hybrid decoder; output_dim is the
         # rows of the (tied) embedding held here
         from fedml_tpu.models.sambay import SambaYLM
-        if "layer_ids" in kw:
-            kw = {**kw, "layer_ids": tuple(kw["layer_ids"])}
         return SambaYLM(vocab_size=output_dim, **kw)
     if model_name == "lfm2_moe":
         # LFM2-8B-A1B's hybrid decoder (short convolutions, grouped-query
         # attention, routed experts); output_dim is the rows of the (tied)
         # embedding held here
         from fedml_tpu.models.lfm2_moe import Lfm2MoeLM
-        for name in ("layer_ids", "layer_types", "experts_held"):
-            if name in kw:
-                kw = {**kw, name: tuple(kw[name])}
         return Lfm2MoeLM(vocab_size=output_dim, **kw)
     if model_name == "granite_hybrid":
         # granite-4.0-h-micro's hybrid decoder (Mamba-2 layers beside NoPE
         # grouped-query attention); output_dim is the rows of the (tied)
         # embedding held here
         from fedml_tpu.models.granite_hybrid import GraniteHybridLM
-        for name in ("layer_ids", "layer_types"):
-            if name in kw:
-                kw = {**kw, name: tuple(kw[name])}
         return GraniteHybridLM(vocab_size=output_dim, **kw)
     if model_name == "deepseek_v3":
         # kanana-2-30b-a3b-instruct-2601's decoder (latent attention, routed
         # experts beside shared ones); output_dim is the rows held of the
         # embedding and of the untied head
         from fedml_tpu.models.deepseek_v3 import DeepseekV3LM
-        for name in ("layer_ids", "experts_held"):
-            if name in kw:
-                kw = {**kw, name: tuple(kw[name])}
         return DeepseekV3LM(vocab_size=output_dim, **kw)
+    if model_name == "qwen3_next":
+        # Qwen3-Next-80B-A3B-Instruct's decoder (Gated DeltaNet beside gated
+        # attention, routed experts beside a gated shared one); output_dim
+        # is the rows held of the embedding and of the untied head
+        from fedml_tpu.models.qwen3_next import Qwen3NextLM
+        return Qwen3NextLM(vocab_size=output_dim, **kw)
     if model_name in ("vgg11", "vgg13", "vgg16", "vgg19"):
         from fedml_tpu.models.vgg import VGG
         return VGG(arch=model_name, num_classes=output_dim, **kw)

@@ -7,10 +7,12 @@ published router, normalises the weights over all ``top_k`` chosen, and adds
 the part of the result its own experts give. What the absent experts would
 add is left out; there is no exchange and nothing stands in for one.
 
-Selection: ``p = sigmoid(s W_g)``, the ``top_k`` largest of ``p + bias``
-(the bias only selects), weights ``p_e / (sum of the chosen p + eps)``
-(``eps`` 1e-6 unless the caller's model publishes another). The
-router's product, the sigmoid and the top-k run in float32 at ``highest``
+Selection: ``p = sigmoid(s W_g)`` (``score="sigmoid"``, the default) or
+``p = softmax(s W_g)`` over all the experts (``score="softmax"``), the
+``top_k`` largest of ``p + bias`` (the bias only selects), weights ``p_e /
+(sum of the chosen p + eps)`` (``eps`` 1e-6 unless the caller's model
+publishes another). The router's product, the scores and the top-k run in
+float32 at ``highest``
 precision whatever the rest of the program uses, so that a selection differs
 from a float32 reference's only where the layer's input already does.
 
@@ -53,13 +55,19 @@ BLOCK = 512
 
 
 def route(s, router, bias, *, top_k: int, norm_topk: bool, scale: float,
-          eps: float = 1e-6):
+          eps: float = 1e-6, score: str = "sigmoid"):
     """``(chosen experts [N, top_k] int32, their weights [N, top_k])`` for
     tokens ``s [N, d]``; float32 at ``highest``. ``bias`` ``[num_experts]``
-    (or None) is added for the selection only, so it has no gradient."""
+    (or None) is added for the selection only, so it has no gradient.
+    ``score`` is ``"sigmoid"`` or ``"softmax"`` (over all the experts)."""
     logits = jnp.dot(s.astype(jnp.float32), router.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
-    p = jax.nn.sigmoid(logits)
+    if score == "sigmoid":
+        p = jax.nn.sigmoid(logits)
+    elif score == "softmax":
+        p = jax.nn.softmax(logits, axis=-1)
+    else:
+        raise ValueError(f"no router score {score!r}")
     _, chosen = jax.lax.top_k(p if bias is None else p + bias, top_k)
     weights = jnp.take_along_axis(p, chosen, axis=-1)
     if norm_topk:
@@ -194,7 +202,8 @@ _grouped.defvjp(_grouped_fwd, _grouped_bwd)
 
 def routed_experts(s, router, bias, w1, w3, w2, *, top_k: int,
                    experts_held: Tuple[int, int], norm_topk: bool = True,
-                   scale: float = 1.0, eps: float = 1e-6):
+                   scale: float = 1.0, eps: float = 1e-6,
+                   score: str = "sigmoid"):
     """The held experts' part of a sparse block: ``(y, load)``.
 
     ``s [..., T, d]`` are the block's inputs, ``router [d, num_experts]``
@@ -204,7 +213,7 @@ def routed_experts(s, router, bias, w1, w3, w2, *, top_k: int,
     ``s``'s shape; ``load`` ``[..., count]`` counts, for every leading index,
     the (token, choice) pairs of its ``T`` tokens that landed on each held
     expert. All the tokens share one set of grouped products; ``eps`` is
-    the normalisation's."""
+    the normalisation's, ``score`` the router's (``route``)."""
     first, count = experts_held
     if w1.shape[0] != count:
         raise ValueError(f"{w1.shape[0]} experts given, experts_held says "
@@ -212,7 +221,8 @@ def routed_experts(s, router, bias, w1, w3, w2, *, top_k: int,
     lead, (length, width) = s.shape[:-2], s.shape[-2:]
     flat = s.reshape(-1, width)
     chosen, weights = route(flat, router, bias, top_k=top_k,
-                            norm_topk=norm_topk, scale=scale, eps=eps)
+                            norm_topk=norm_topk, scale=scale, eps=eps,
+                            score=score)
     chosen = jax.lax.stop_gradient(chosen)
     plan = jax.tree.map(jax.lax.stop_gradient, _plan(chosen, first, count))
     y = _grouped(flat, weights, w1, w3, w2, plan)

@@ -310,3 +310,80 @@ def test_with_the_defaults_the_traced_program_is_the_parents(what):
         got = hashlib.sha256(
             str(jax.make_jaxpr(fn)(*args)).encode()).hexdigest()[:16]
     assert got == LFM2_SHAPED[what]
+
+
+# -- a softmax router beside the sigmoid one ----------------------------------------
+
+def _dense_softmax(p, first, count, top_k=K):
+    """Every held expert on every token, weighted by the softmax over all
+    the experts renormalised over the chosen (no bias, no epsilon)."""
+    prob = jax.nn.softmax(p["s"] @ p["router"], axis=-1)
+    picked, chosen = jax.lax.top_k(prob, top_k)
+    weights = picked / picked.sum(-1, keepdims=True)
+    out = jnp.zeros_like(p["s"])
+    for e in range(first, first + count):
+        w_e = jnp.where(chosen == e, weights, 0.0).sum(-1)
+        out = out + w_e[:, None] * ((jax.nn.silu(p["s"] @ p["w1"][e])
+                                     * (p["s"] @ p["w3"][e])) @ p["w2"][e])
+    return out, chosen
+
+
+@pytest.mark.parametrize("top_k", [2, 3])
+def test_softmax_scores_choose_the_top_k_of_the_softmax(top_k):
+    p = _weights(seed=14)
+    chosen, weights = route(p["s"], p["router"], None, top_k=top_k,
+                            norm_topk=True, scale=1.0, eps=0.0,
+                            score="softmax")
+    prob = np.asarray(jax.nn.softmax(p["s"] @ p["router"], axis=-1))
+    plain = np.argsort(-prob, axis=-1, kind="stable")[:, :top_k]
+    np.testing.assert_array_equal(np.sort(chosen, -1), np.sort(plain, -1))
+    picked = np.take_along_axis(prob, np.asarray(chosen), axis=-1)
+    np.testing.assert_allclose(
+        weights, picked / picked.sum(-1, keepdims=True), rtol=1e-6)
+    # unnormalised, the weights are the softmax over all the experts
+    _, raw = route(p["s"], p["router"], None, top_k=top_k, norm_topk=False,
+                   scale=1.0, score="softmax")
+    np.testing.assert_allclose(raw, picked, rtol=1e-6)
+    assert float(np.max(np.sum(raw, -1))) < 1.0
+
+
+@pytest.mark.parametrize("first, count", [(0, 8), (2, 4)])
+def test_a_softmax_share_and_its_gradients_equal_the_masked_dense_sum(
+        first, count):
+    p = _weights(seed=15)
+    probe = jax.random.normal(jax.random.key(16), (T, D))
+
+    def share(p):
+        held = slice(first, first + count)
+        with mock.patch.object(moe, "BLOCK", 8):
+            return routed_experts(
+                p["s"], p["router"], None, p["w1"][held], p["w3"][held],
+                p["w2"][held], top_k=K, experts_held=(first, count),
+                eps=0.0, score="softmax")
+
+    y, load = jax.jit(share)(p)
+    want, chosen = _dense_softmax(p, first, count)
+    assert _rel(y, want) < 1e-5
+    np.testing.assert_array_equal(load, [
+        int(jnp.sum(chosen == e)) for e in range(first, first + count)])
+    got = jax.jit(jax.grad(lambda p: jnp.sum(share(p)[0] * probe)))(p)
+    ref = jax.jit(jax.grad(lambda p: jnp.sum(
+        _dense_softmax(p, first, count)[0] * probe)))(p)
+    for name in ("s", "router", "w1", "w3", "w2"):
+        assert _rel(got[name], ref[name]) < 1e-5, name
+    # not the sigmoid's weights
+    sigmoid, _ = _dense(p, first, count)
+    assert _rel(sigmoid, want) > 1e-3
+
+
+def test_the_sigmoid_is_the_default_and_another_score_is_refused():
+    p = _weights(seed=17)
+    args = (p["s"], p["router"], p["bias"])
+    kw = dict(top_k=K, norm_topk=True, scale=1.0)
+    default, named = route(*args, **kw), route(*args, **kw, score="sigmoid")
+    for a, b in zip(default, named):
+        np.testing.assert_array_equal(a, b)
+    assert str(jax.make_jaxpr(lambda *a: route(*a, **kw))(*args)) == str(
+        jax.make_jaxpr(lambda *a: route(*a, **kw, score="sigmoid"))(*args))
+    with pytest.raises(ValueError, match="no router score"):
+        route(*args, **kw, score="relu")

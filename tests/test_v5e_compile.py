@@ -253,3 +253,91 @@ def test_the_latent_attention_core_is_the_kernel_and_the_experts_xla(
     for shape in ("f32[2048,1536]", "f32[2048,576]", "f32[32,2048,192]",
                   "f32[2048,6144]", "f32[512,8192]", "f32[2048,128]"):
         assert not pattern.search(shape), shape
+
+
+def test_the_qwen3_next_layers_are_xla_and_name_their_scopes(one_chip):
+    """A Gated DeltaNet layer and the gated full-attention layer of
+    ``models/qwen3_next.py`` at the widths of
+    ``qwen3_next_80b_a3b_ep16.silo4`` (a row of 512 tokens; 16 key heads
+    and 32 value heads of 128, chunks of 64; 16 query heads and 2 KV heads
+    of 256; top-10 of 512 by softmax, 32 experts of 2048 x 512 held, the
+    gated shared expert), forward and backward: the TPU compiler takes the
+    chunked delta rule - its unrolled scan, the triangular inverse's
+    products - and makes no custom call of it; the recurrence's call, its
+    block and the full-attention block are named in the instructions'
+    ``op_name`` chains, which ``gated_delta_core_ms``, ``gated_delta_ms``
+    and ``gated_attention_ms`` read through the scope map; no chunk's
+    output is written into a stack of the row's chunks (``ops/ssd.py``'s
+    lesson: unrolled, the scan concatenates)."""
+    from fedml_tpu.models import create_model, qwen3_next
+    from fedml_tpu.utils.tracing import parse_hlo_scopes
+
+    module = create_model("qwen3_next", output_dim=18992,
+                          experts_held=(0, 32), layer_ids=(2, 3))
+    cfg = module.cfg()
+    shapes = jax.eval_shape(lambda: module.init(
+        jax.random.key(0), jnp.zeros((1, 8), jnp.int32), train=False))
+    leaves = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        {name: shapes["params"][name] for name in ("layer_02", "layer_03")})
+
+    def loss(p, x):
+        x, _ = qwen3_next._layer(p["layer_02"], x, full=False, cfg=cfg)
+        x, _ = qwen3_next._layer(p["layer_03"], x, full=True, cfg=cfg)
+        return jnp.sum(x * x)
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        leaves, jax.ShapeDtypeStruct((1, 512, 2048), jnp.float32,
+                                     sharding=one_chip)).compile().as_text()
+    assert "tpu_custom_call" not in text
+    _, scopes = parse_hlo_scopes(text)
+    chains = set(scopes.chains.values())
+    assert ("fedml.gated_delta", "fedml.gated_delta_core") in chains
+    for scope in ("fedml.gated_attention", "fedml.moe",
+                  "fedml.shared_experts"):
+        assert any(scope in chain for chain in chains), scope
+    # a chunk's decay matrices and inverse, 16 key heads x 2 value heads
+    assert re.search(r"f32\[16,2,64,64\]", text)
+    assert not re.search(r"\[8,64,4096\][^ ]* dynamic-update-slice", text)
+
+
+@pytest.mark.slow
+def test_the_qwen3_next_round_fits_and_names_its_scopes(one_chip):
+    """The round of ``qwen3_next_80b_a3b_ep16.silo4`` as ``FedAvgAPI``
+    folds it on a TPU (``benchmark/tools/compile_fold_v5e.py::lower_round``:
+    4 silos, 2 steps of a 2,048-token row, the Pallas fold; published
+    widths, layers 0-3, experts 0-31 of 512, 18,992 rows): it fits beside
+    the two copies of the model the process also holds (the global model
+    and the harness's initial one: 16.9 GB less 2 x 2.50), the delta rule is
+    XLA - the only custom calls are the fold's - and the chunked recurrence
+    and the full-attention block are named in the instructions' ``op_name``
+    chains, which ``gated_delta_core_ms`` and ``gated_attention_ms`` read
+    through the scope map. Seven minutes of compilation on the CPU: not
+    tier-1."""
+    import types
+
+    from benchmark.harness import spec
+    from fedml_tpu.utils.tracing import parse_hlo_scopes
+
+    compile_fold_v5e = spec.load_module(os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "benchmark", "tools", "compile_fold_v5e.py"))
+    cell = spec.load_cell("qwen3_next_80b_a3b_ep16.silo4")
+    lowered, params = compile_fold_v5e.lower_round(
+        cell, types.SimpleNamespace(devices=list(one_chip.device_set)))
+    compiled = lowered.compile()
+    assert params == 625_667_136
+    peak = compiled.memory_analysis().peak_memory_in_bytes
+    assert peak < 16.9e9 - 2 * 4 * params
+    text = compiled.as_text()
+    _, scopes = parse_hlo_scopes(text)
+    chains = set(scopes.chains.values())
+    for scope in ("fedml.gated_delta_core", "fedml.gated_attention",
+                  "fedml.gated_delta", "fedml.shared_experts", "fedml.moe"):
+        assert any(scope in chain for chain in chains), scope
+    assert any(chain[-2:] == ("fedml.gated_delta", "fedml.gated_delta_core")
+               for chain in chains)
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line
+             and " = " in line]
+    assert calls and all("fedml.fold" in line for line in calls)
