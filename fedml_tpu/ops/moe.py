@@ -21,21 +21,32 @@ No token is dropped at any load, and no shape depends on the routing. The
 each expert's rows are one segment, and the three products run segment by
 segment in blocks of ``BLOCK`` rows: one loop over the blocks in use, each
 block one expert's (gather its rows, ``[BLOCK, d] x [d, w]`` twice, the
-gate, ``[BLOCK, w] x [w, d]``). The loop's trip count is the number of
-blocks the routing needs - ``sum_e ceil(n_e / BLOCK)``, at most ``rows /
-BLOCK + count`` for the static worst case of ``tokens x min(top_k, count)``
-rows - so the work, and with it the device time, follows the load while
-every buffer has its worst-case size. A block costs its expert's three
-matrices read before it costs its rows: experts of 2048 x 768 at loads
-from 0 to 900 rows ran 0.8 % faster at 512 than at 256 and 3.0 % faster
-than at 128 (PR 39), so the block is one constant and no caller's choice.
+gate, ``[BLOCK, w] x [w, d]``, each row times its pair's routing weight
+added into the ``[tokens, d]`` output at its token). The loop's trip count
+is the number of blocks the routing needs - ``sum_e ceil(n_e / BLOCK)``, at
+most ``rows / BLOCK + count`` for the static worst case of ``tokens x
+min(top_k, count)`` rows - so the work, and with it the device time,
+follows the load. A block costs its expert's three matrices read before it
+costs its rows: experts of 2048 x 768 at loads from 0 to 900 rows ran 0.8 %
+faster at 512 than at 256 and 3.0 % faster than at 128 on a TPU v5e, so the
+block is one constant and no caller's choice.
+
+The combine is that add, one row scatter a block: nothing of the static
+worst case is materialised and gathered back a pair at a time. A block's
+real rows come first, their tokens distinct (a token chooses an expert
+once) and ascending (the stable sort keeps pair order); each padding row
+goes past the end of the output on a lane of its own and is dropped, so the
+indices are sorted and unique (spare rows sliced off read within 1.3 % on a
+TPU v5e). The scatter costs a block's width, not its load: 83 us for 512
+rows of 2048 floats.
 
 A loop with a data-dependent trip count has no reverse-mode rule, so the
 backward pass is written out (``jax.custom_vjp``): the same loop again, each
-block recomputing its two hidden products, the weight gradients accumulated
-expert by expert in place. It works under ``jax.checkpoint``, inside
-``lax.scan`` and - at the cost of running every lane to the longest trip
-count - under ``vmap``.
+block recomputing its two hidden products and adding its rows' input
+gradient at their tokens as the forward adds (the routing weights' at their
+pairs), the weight gradients accumulated expert by expert in place. It works
+under ``jax.checkpoint``, inside ``lax.scan`` and - at the cost of running
+every lane to the longest trip count - under ``vmap``.
 
 ``jax.lax.ragged_dot`` would be the three products in three lines, but the
 TPU compiler lowers it to a ``tpu_custom_call`` (compiled for the described
@@ -84,8 +95,6 @@ class _Plan(NamedTuple):
     valid: jnp.ndarray     # [blocks, block] the row is a real pair
     pairs: jnp.ndarray     # [blocks, block] its pair (token * top_k + choice)
     expert: jnp.ndarray    # [blocks] the held expert a block belongs to
-    slot: jnp.ndarray      # [N, top_k] a pair's row in the layout (0 if none)
-    held: jnp.ndarray      # [N, top_k] the pair landed on a held expert
     n_run: jnp.ndarray     # [] blocks the loops run
 
 
@@ -96,8 +105,7 @@ def _plan(chosen, first: int, count: int) -> _Plan:
     rows_max = n_tokens * min(top_k, count)
     blocks_max = rows_max // block + count
     local = chosen.reshape(-1) - first
-    held = (local >= 0) & (local < count)
-    key = jnp.where(held, local, count)
+    key = jnp.where((local >= 0) & (local < count), local, count)
     load = jnp.sum(key[:, None] == jnp.arange(count)[None, :], axis=0,
                    dtype=jnp.int32)
     order = jnp.argsort(key, stable=True).astype(jnp.int32)
@@ -117,15 +125,7 @@ def _plan(chosen, first: int, count: int) -> _Plan:
     rows = jnp.minimum(seg_start[expert][:, None] + offset[:, None]
                        + lane[None, :], n_pairs - 1)
     pairs = jnp.where(valid, order[rows], 0)
-    # a pair's row in the layout: its expert's first block, then its rank
-    # in the segment
-    rank = jnp.zeros((n_pairs,), jnp.int32).at[order].set(
-        jnp.arange(n_pairs, dtype=jnp.int32))
-    at = jnp.minimum(key, count - 1)
-    slot = jnp.where(held, block_start[at] * block + rank - seg_start[at], 0)
     return _Plan(pairs // top_k, valid, pairs, expert,
-                 slot.reshape(n_tokens, top_k),
-                 held.reshape(n_tokens, top_k),
                  block_end[-1].astype(jnp.int32))
 
 
@@ -134,20 +134,24 @@ def _hidden(x, w1, w3):
     return h1, h3, jax.nn.silu(h1) * h3
 
 
+def _add_rows(acc, at, valid, rows):
+    """``acc[at] += rows`` for one block's rows, each padding row dropped
+    past the end on a lane of its own: sorted, unique indices."""
+    lane = jnp.arange(at.shape[0], dtype=at.dtype)
+    return acc.at[jnp.where(valid, at, acc.shape[0] + lane)].add(
+        rows, mode="drop", indices_are_sorted=True, unique_indices=True)
+
+
 def _forward(s, weights, w1, w3, w2, plan: _Plan):
-    block, width = plan.tokens.shape[1], w2.shape[-1]
+    flat_w = weights.reshape(-1).astype(s.dtype)
 
-    def one(j, out):
-        e = plan.expert[j]
-        _, _, act = _hidden(s[plan.tokens[j]], w1[e], w3[e])
-        y = jnp.where(plan.valid[j][:, None], act @ w2[e], 0)
-        return jax.lax.dynamic_update_slice(out, y, (j * block, 0))
+    def one(j, y):
+        e, rows = plan.expert[j], plan.tokens[j]
+        _, _, act = _hidden(s[rows], w1[e], w3[e])
+        return _add_rows(y, rows, plan.valid[j],
+                         (act @ w2[e]) * flat_w[plan.pairs[j]][:, None])
 
-    out = jax.lax.fori_loop(
-        0, plan.n_run, one,
-        jnp.zeros((plan.tokens.shape[0] * block, width), s.dtype))
-    weights = jnp.where(plan.held, weights, 0).astype(s.dtype)
-    return jnp.einsum("nk,nkd->nd", weights, out[plan.slot])
+    return jax.lax.fori_loop(0, plan.n_run, one, jnp.zeros_like(s))
 
 
 @jax.custom_vjp
@@ -163,15 +167,14 @@ def _grouped_fwd(s, weights, w1, w3, w2, plan):
 
 def _grouped_bwd(res, dy):
     s, weights, w1, w3, w2, plan = res
-    blocks, block = plan.tokens.shape
-    flat_w = jnp.where(plan.held, weights, 0).reshape(-1).astype(s.dtype)
+    flat_w = weights.reshape(-1).astype(s.dtype)
 
     def one(j, carry):
-        dx, dw, g1, g3, g2 = carry
-        e, ok = plan.expert[j], plan.valid[j][:, None]
-        x = s[plan.tokens[j]]
+        ds, dw, g1, g3, g2 = carry
+        e, rows, ok = plan.expert[j], plan.tokens[j], plan.valid[j]
+        x = s[rows]
         h1, h3, act = _hidden(x, w1[e], w3[e])
-        dy_rows = jnp.where(ok, dy[plan.tokens[j]], 0)
+        dy_rows = jnp.where(ok[:, None], dy[rows], 0)
         pair_w = flat_w[plan.pairs[j]][:, None]
         # the activation's gradient before the row's routing weight: its
         # dot with the activation is that weight's gradient
@@ -180,21 +183,17 @@ def _grouped_bwd(res, dy):
         sig = jax.nn.sigmoid(h1)
         dh1 = dact * h3 * sig * (1 + h1 * (1 - sig))
         dh3 = dact * h1 * sig
-        dw = jax.lax.dynamic_update_slice(
-            dw, jnp.sum(g * act, axis=-1), (j * block,))
-        dx = jax.lax.dynamic_update_slice(
-            dx, dh1 @ w1[e].T + dh3 @ w3[e].T, (j * block, 0))
-        return (dx, dw, g1.at[e].add(x.T @ dh1), g3.at[e].add(x.T @ dh3),
+        ds = _add_rows(ds, rows, ok, dh1 @ w1[e].T + dh3 @ w3[e].T)
+        dw = _add_rows(dw, plan.pairs[j], ok, jnp.sum(g * act, axis=-1))
+        return (ds, dw, g1.at[e].add(x.T @ dh1), g3.at[e].add(x.T @ dh3),
                 g2.at[e].add(act.T @ (dy_rows * pair_w)))
 
-    dx, dw, g1, g3, g2 = jax.lax.fori_loop(
+    ds, dw, g1, g3, g2 = jax.lax.fori_loop(
         0, plan.n_run, one,
-        (jnp.zeros((blocks * block, s.shape[-1]), s.dtype),
-         jnp.zeros((blocks * block,), s.dtype),
-         jnp.zeros_like(w1), jnp.zeros_like(w3), jnp.zeros_like(w2)))
-    held = plan.held.astype(s.dtype)
-    ds = jnp.einsum("nk,nkd->nd", held, dx[plan.slot])
-    return ds, (dw[plan.slot] * held).astype(weights.dtype), g1, g3, g2, None
+        (jnp.zeros_like(s), jnp.zeros_like(flat_w), jnp.zeros_like(w1),
+         jnp.zeros_like(w3), jnp.zeros_like(w2)))
+    dw = dw.reshape(weights.shape).astype(weights.dtype)
+    return ds, dw, g1, g3, g2, None
 
 
 _grouped.defvjp(_grouped_fwd, _grouped_bwd)

@@ -129,10 +129,42 @@ def test_weights_without_normalisation_are_scaled_scores():
         prob, np.asarray(chosen), axis=-1), rtol=1e-6)
 
 
+def _layout(p, first, count, block):
+    """What the plan's blocks hold for the share: padding rows, a token whose
+    held choices fall in different blocks, an expert whose rows span several
+    blocks with a part-filled last one."""
+    chosen, _ = route(p["s"], p["router"], p["bias"], top_k=K,
+                      norm_topk=True, scale=1.0)
+    with mock.patch.object(moe, "BLOCK", block):
+        plan = moe._plan(chosen, first, count)
+    n_run = int(plan.n_run)
+    valid = np.asarray(plan.valid[:n_run])
+    tokens = np.asarray(plan.tokens[:n_run])
+    expert = np.asarray(plan.expert[:n_run])
+    blocks_of = {}
+    for j, lane in zip(*np.nonzero(valid)):
+        blocks_of.setdefault(tokens[j, lane], set()).add(j)
+    real = valid.sum(axis=1)
+    return {"padding": not valid.all(),
+            "split_token": any(len(b) > 1 for b in blocks_of.values()),
+            "spanning_expert": any(
+                np.sum(expert == e) > 1
+                and real[np.nonzero(expert == e)[0][-1]] < valid.shape[1]
+                for e in range(count))}
+
+
 @pytest.mark.parametrize("first, count", [(0, 8), (2, 4)])
-@pytest.mark.parametrize("block", [8, 16])
+@pytest.mark.parametrize("block", [8, 16, 512])
 def test_every_gradient_equals_the_dense_sums(first, count, block):
+    """The value and all five gradients, over blocks that hold padding rows
+    and tokens whose held choices fall in different blocks, and, in blocks
+    of 8, experts that span several blocks with a part-filled last one."""
     p = _weights(seed=5)
+    layout = _layout(p, first, count, block)
+    assert layout["padding"] and layout["split_token"]
+    assert layout["spanning_expert"] or block > 8
+    y = jax.jit(lambda p: _share(p, first, count, block=block)[0])(p)
+    assert _rel(y, _dense(p, first, count)[0]) < 1e-5
     probe = jax.random.normal(jax.random.key(9), (T, D))
 
     def through(fn):
@@ -285,16 +317,17 @@ def test_the_epsilon_of_the_normalisation_is_the_callers():
 
 
 #: the first 16 hex digits of sha256(str(jaxpr)) of the layer and of its
-#: five gradients at commit 6105f72 (PR 38, before ``eps`` was a keyword),
-#: for a call shaped like ``models/lfm2_moe.py``'s: rows of tokens, top-4 of
-#: 32 experts, 8 held, no epsilon named
-LFM2_SHAPED = {"layer": "f2d848aaa50d8aa0", "gradients": "c2df61f56eb10cf9"}
+#: five gradients for a call shaped like ``models/lfm2_moe.py``'s: rows of
+#: tokens, top-4 of 32 experts, 8 held, no epsilon named. The program changed
+#: when the combine moved inside the block loop (each block's rows added
+#: into the output at their tokens, no worst-case buffer gathered back): the
+#: digests pin that program
+LFM2_SHAPED = {"layer": "7309c5cd7aed81a8", "gradients": "433816f2faf46532"}
 
 
-@pytest.mark.parametrize("what", sorted(LFM2_SHAPED))
-def test_with_the_defaults_the_traced_program_is_the_parents(what):
-    import hashlib
-
+def _lfm2_shaped(what):
+    """The layer or its five gradients at ``LFM2_SHAPED``'s call, and the
+    abstract arguments: 128 tokens in two rows, d 32, experts of 48."""
     def layer(s, router, bias, w1, w3, w2):
         return routed_experts(s, router, bias, w1, w3, w2, top_k=4,
                               experts_held=(0, 8), norm_topk=True, scale=1.0)
@@ -305,11 +338,50 @@ def test_with_the_defaults_the_traced_program_is_the_parents(what):
 
     args = [jax.ShapeDtypeStruct(shape, jnp.float32) for shape in (
         (2, 64, 32), (32, 32), (32,), (8, 32, 48), (8, 32, 48), (8, 48, 32))]
-    fn = {"layer": layer, "gradients": gradients}[what]
+    return {"layer": layer, "gradients": gradients}[what], args
+
+
+@pytest.mark.parametrize("what", sorted(LFM2_SHAPED))
+def test_with_the_defaults_the_traced_program_is_the_parents(what):
+    import hashlib
+
+    fn, args = _lfm2_shaped(what)
     with jax.default_matmul_precision(None):
         got = hashlib.sha256(
             str(jax.make_jaxpr(fn)(*args)).encode()).hexdigest()[:16]
     assert got == LFM2_SHAPED[what]
+
+
+def _avals(jaxpr):
+    """Every value the equations of ``jaxpr`` and of the jaxprs inside them
+    make."""
+    for eqn in jaxpr.eqns:
+        yield from (v.aval for v in eqn.outvars)
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (tuple, list)) else (param,):
+                if isinstance(sub, jax.extend.core.ClosedJaxpr):
+                    yield from _avals(sub.jaxpr)
+                elif isinstance(sub, jax.extend.core.Jaxpr):
+                    yield from _avals(sub)
+
+
+@pytest.mark.parametrize("what", sorted(LFM2_SHAPED))
+@pytest.mark.parametrize("block", [16, moe.BLOCK])
+def test_the_combine_holds_no_worst_case_rows_nor_a_row_a_choice(what,
+                                                                 block):
+    """Each block's rows go into the output inside the loop: neither the
+    layer nor its gradients hold an array of the plan's static worst case,
+    ``blocks_max x block`` rows, nor one of ``[tokens, top_k, d]``."""
+    tokens, top_k, width, held = 128, 4, 32, 8
+    size = min(block, tokens * top_k)
+    worst = (tokens * min(top_k, held) // size + held) * size
+    fn, args = _lfm2_shaped(what)
+    with mock.patch.object(moe, "BLOCK", block):
+        shapes = {tuple(a.shape) for a in _avals(jax.make_jaxpr(fn)(
+            *args).jaxpr) if hasattr(a, "shape")}
+    assert (tokens, top_k, width) not in shapes
+    assert not [shape for shape in shapes if shape and shape[0] == worst]
+    assert (tokens, width) in shapes  # the output the blocks add into
 
 
 # -- a softmax router beside the sigmoid one ----------------------------------------
