@@ -129,21 +129,23 @@ def _shared_experts(p, s):
 
 
 def _layer(p, x, *, dense: bool, cfg):
-    """One layer on a batch of rows ``x [B, T, d]``; returns ``(x, load)``,
-    ``load [B, held]`` the pairs on each held expert (None for a dense
+    """One layer on a batch of rows ``x [B, T, d]``; returns ``(x, load,
+    rows)``, ``load [B, held]`` the pairs on each held expert and ``rows``
+    the rows the grouped products' block loops ran (both None for a dense
     layer)."""
     x = x + _mla(p, rms_norm(x, p["input_norm_scale"], cfg["eps"]), cfg)
     s = rms_norm(x, p["post_attention_norm_scale"], cfg["eps"])
     if dense:
         with jax.named_scope("fedml.mlp"):
-            return x + _swiglu(s, p["ffn_w1"], p["ffn_w3"], p["ffn_w2"]), None
+            return (x + _swiglu(s, p["ffn_w1"], p["ffn_w3"], p["ffn_w2"]),
+                    None, None)
     with jax.named_scope("fedml.moe"):
-        y, load = routed_experts(
+        y, load, rows = routed_experts(
             s, p["router"], p["expert_bias"], p["experts_w1"],
             p["experts_w3"], p["experts_w2"], top_k=cfg["top_k"],
             experts_held=cfg["experts_held"], norm_topk=cfg["norm_topk"],
             scale=cfg["scale"], eps=1e-20)
-    return x + y + _shared_experts(p, s), load
+    return x + y + _shared_experts(p, s), load, rows
 
 
 class DeepseekV3LM(nn.Module):
@@ -241,19 +243,23 @@ class DeepseekV3LM(nn.Module):
                 return jnp.zeros(tokens.shape + (self.vocab_size,))
             return RoutedTiedHead(
                 jnp.zeros(tokens.shape + (d,), embedding.dtype), lm_head,
-                jnp.zeros((tokens.shape[0], sparse, held), jnp.float32))
+                jnp.zeros((tokens.shape[0], sparse, held), jnp.float32),
+                jnp.zeros((sparse,), jnp.float32))
 
         with jax.named_scope("fedml.embed"):
             x = embedding[tokens]
-        loads = []
+        loads, block_rows = [], []
         for p, layer in layers:
-            x, load = jax.checkpoint(functools.partial(
+            x, load, rows = jax.checkpoint(functools.partial(
                 _layer, dense=layer < self.num_dense_layers, cfg=cfg))(p, x)
             if load is not None:
                 loads.append(load.astype(jnp.float32))
+                block_rows.append(rows.astype(jnp.float32))
         hidden = rms_norm(x, final["norm_scale"], cfg["eps"])
         if self.return_logits:
             return jnp.einsum("btd,vd->btv", hidden, lm_head)
         loads = (jnp.stack(loads, axis=1) if loads else
                  jnp.zeros((tokens.shape[0], 0, held), jnp.float32))
-        return RoutedTiedHead(hidden, lm_head, loads)
+        block_rows = (jnp.stack(block_rows) if block_rows else
+                      jnp.zeros((0,), jnp.float32))
+        return RoutedTiedHead(hidden, lm_head, loads, block_rows)

@@ -34,9 +34,10 @@ config does not give, and none is invented here).
 
 Layers are pure functions of a parameter tree, each rematerialised whole
 (``jax.checkpoint``). The output is a :class:`~fedml_tpu.trainer.tasks
-.RoutedTiedHead` for the ``lm_rows`` head: hidden states, the embedding, and
+.RoutedTiedHead` for the ``lm_rows`` head: hidden states, the embedding,
 per row and sparse layer the (token, choice) pairs that landed on each held
-expert - or, with ``return_logits``, the logits.
+expert, and per sparse layer the rows the grouped products ran - or, with
+``return_logits``, the logits.
 """
 
 from __future__ import annotations
@@ -100,21 +101,22 @@ def _dense_ff(p, s):
 
 
 def _layer(p, x, *, kind: str, dense: bool, cfg):
-    """One layer on a batch of rows ``x [B, T, d]``; returns ``(x, load)``,
-    ``load [B, held]`` the pairs on each held expert (None for a dense
+    """One layer on a batch of rows ``x [B, T, d]``; returns ``(x, load,
+    rows)``, ``load [B, held]`` the pairs on each held expert and ``rows``
+    the rows the grouped products' block loops ran (both None for a dense
     layer)."""
     s = rms_norm(x, p["operator_norm_scale"], cfg["eps"])
     x = x + (_short_conv(p, s) if kind == "conv" else _attention(p, s, cfg))
     s = rms_norm(x, p["ffn_norm_scale"], cfg["eps"])
     if dense:
-        return x + _dense_ff(p, s), None
+        return x + _dense_ff(p, s), None, None
     with jax.named_scope("fedml.moe"):
-        y, load = routed_experts(
+        y, load, rows = routed_experts(
             s, p["router"], p.get("expert_bias"), p["experts_w1"],
             p["experts_w3"], p["experts_w2"], top_k=cfg["top_k"],
             experts_held=cfg["experts_held"], norm_topk=cfg["norm_topk"],
             scale=cfg["scale"])
-    return x + y, load
+    return x + y, load, rows
 
 
 class Lfm2MoeLM(nn.Module):
@@ -200,20 +202,24 @@ class Lfm2MoeLM(nn.Module):
                 return jnp.zeros(tokens.shape + (self.vocab_size,))
             return RoutedTiedHead(
                 jnp.zeros(tokens.shape + (d,), embedding.dtype), embedding,
-                jnp.zeros((tokens.shape[0], sparse, held), jnp.float32))
+                jnp.zeros((tokens.shape[0], sparse, held), jnp.float32),
+                jnp.zeros((sparse,), jnp.float32))
 
         with jax.named_scope("fedml.embed"):
             x = embedding[tokens]
-        loads = []
+        loads, block_rows = [], []
         for p, layer in layers:
-            x, load = jax.checkpoint(functools.partial(
+            x, load, rows = jax.checkpoint(functools.partial(
                 _layer, kind=self.layer_types[layer],
                 dense=layer < self.num_dense_layers, cfg=cfg))(p, x)
             if load is not None:
                 loads.append(load.astype(jnp.float32))
+                block_rows.append(rows.astype(jnp.float32))
         hidden = rms_norm(x, final["norm_scale"], cfg["eps"])
         if self.return_logits:
             return jnp.einsum("btd,vd->btv", hidden, embedding)
         loads = (jnp.stack(loads, axis=1) if loads else
                  jnp.zeros((tokens.shape[0], 0, held), jnp.float32))
-        return RoutedTiedHead(hidden, embedding, loads)
+        block_rows = (jnp.stack(block_rows) if block_rows else
+                      jnp.zeros((0,), jnp.float32))
+        return RoutedTiedHead(hidden, embedding, loads, block_rows)

@@ -165,18 +165,19 @@ def _shared_expert(p, s):
 
 
 def _layer(p, x, *, full: bool, cfg):
-    """One layer on a batch of rows ``x [B, T, d]``; returns ``(x, load)``,
-    ``load [B, held]`` the pairs on each held expert."""
+    """One layer on a batch of rows ``x [B, T, d]``; returns ``(x, load,
+    rows)``, ``load [B, held]`` the pairs on each held expert and ``rows``
+    the rows the grouped products' block loops ran."""
     mixer = _gated_attention if full else _gated_delta
     x = x + mixer(p, norm0(x, p["input_norm_scale"], cfg["eps"]), cfg)
     s = norm0(x, p["post_attention_norm_scale"], cfg["eps"])
     with jax.named_scope("fedml.moe"):
-        y, load = routed_experts(
+        y, load, rows = routed_experts(
             s, p["router"], None, p["experts_w1"], p["experts_w3"],
             p["experts_w2"], top_k=cfg["top_k"],
             experts_held=cfg["experts_held"], norm_topk=cfg["norm_topk"],
             eps=0.0, score="softmax")
-    return x + y + _shared_expert(p, s), load
+    return x + y + _shared_expert(p, s), load, rows
 
 
 class Qwen3NextLM(nn.Module):
@@ -283,16 +284,19 @@ class Qwen3NextLM(nn.Module):
             return RoutedTiedHead(
                 jnp.zeros(tokens.shape + (d,), embedding.dtype), lm_head,
                 jnp.zeros((tokens.shape[0], len(self.layer_ids), held),
-                          jnp.float32))
+                          jnp.float32),
+                jnp.zeros((len(self.layer_ids),), jnp.float32))
 
         with jax.named_scope("fedml.embed"):
             x = embedding[tokens]
-        loads = []
+        loads, block_rows = [], []
         for p, layer in layers:
-            x, load = jax.checkpoint(functools.partial(
+            x, load, rows = jax.checkpoint(functools.partial(
                 _layer, full=self.is_full(layer), cfg=cfg))(p, x)
             loads.append(load.astype(jnp.float32))
+            block_rows.append(rows.astype(jnp.float32))
         hidden = norm0(x, final["norm_scale"], cfg["eps"])
         if self.return_logits:
             return jnp.einsum("btd,vd->btv", hidden, lm_head)
-        return RoutedTiedHead(hidden, lm_head, jnp.stack(loads, axis=1))
+        return RoutedTiedHead(hidden, lm_head, jnp.stack(loads, axis=1),
+                              jnp.stack(block_rows))

@@ -19,17 +19,28 @@ from a float32 reference's only where the layer's input already does.
 No token is dropped at any load, and no shape depends on the routing. The
 (token, choice) pairs that landed on a held expert are sorted by expert, so
 each expert's rows are one segment, and the three products run segment by
-segment in blocks of ``BLOCK`` rows: one loop over the blocks in use, each
-block one expert's (gather its rows, ``[BLOCK, d] x [d, w]`` twice, the
-gate, ``[BLOCK, w] x [w, d]``, each row times its pair's routing weight
+segment in blocks of ``block`` rows: one loop over the blocks in use, each
+block one expert's (gather its rows, ``[block, d] x [d, w]`` twice, the
+gate, ``[block, w] x [w, d]``, each row times its pair's routing weight
 added into the ``[tokens, d]`` output at its token). The loop's trip count
-is the number of blocks the routing needs - ``sum_e ceil(n_e / BLOCK)``, at
-most ``rows / BLOCK + count`` for the static worst case of ``tokens x
+is the number of blocks the routing needs - ``sum_e ceil(n_e / block)``, at
+most ``rows / block + count`` for the static worst case of ``tokens x
 min(top_k, count)`` rows - so the work, and with it the device time,
-follows the load. A block costs its expert's three matrices read before it
-costs its rows: experts of 2048 x 768 at loads from 0 to 900 rows ran 0.8 %
-faster at 512 than at 256 and 3.0 % faster than at 128 on a TPU v5e, so the
-block is one constant and no caller's choice.
+follows the load.
+
+The block follows the call's expected load per held expert, ``tokens x
+top_k / num_experts`` (``num_experts`` the router's width): the smallest
+power of two of at least 1.5 times it, no fewer than 64 rows and no more
+than ``BLOCK`` or the call's pairs (``_block``). Every row-sized operation
+of a block - the gathers, the products, the scatters - costs the block's
+width whatever its load, while an expert's three matrices are read once a
+block: so a block about as wide as an expert's load at a layer's peak (1.2
+to 1.5 times the mean, read at a random initialisation) keeps one block an
+expert and pads little. Qwen3-Next's 2,048 tokens x top-10 of 512 take
+blocks of 64, kanana's top-6 of 128 blocks of 256 (at its uneven loads, one
+held expert with 1,322 of 1,489 pairs, the layer ran 9 % faster than in
+blocks of 512 on a TPU v5e), LFM2's 4,096 x top-4 of 32 the ceiling of 512.
+It is a property of the call's static shapes, not a caller's choice.
 
 The combine is that add, one row scatter a block: nothing of the static
 worst case is materialised and gathered back a pair at a time. A block's
@@ -37,8 +48,11 @@ real rows come first, their tokens distinct (a token chooses an expert
 once) and ascending (the stable sort keeps pair order); each padding row
 goes past the end of the output on a lane of its own and is dropped, so the
 indices are sorted and unique (spare rows sliced off read within 1.3 % on a
-TPU v5e). The scatter costs a block's width, not its load: 83 us for 512
-rows of 2048 floats.
+TPU v5e). The scatter costs a block's width, not its load: on a TPU v5e,
+into ``f32[2048, 2048]``, 35 us for a block of 64 rows of 2048 floats, 43
+for 128 and 147 for 512 (about 30 us a call, then the rows), and the
+layer's forward and backward at Qwen3-Next's call 7.6 ms in blocks of 64
+against 13.5 in blocks of 512.
 
 A loop with a data-dependent trip count has no reverse-mode rule, so the
 backward pass is written out (``jax.custom_vjp``): the same loop again, each
@@ -61,8 +75,10 @@ from typing import NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 
-#: rows of one expert a block of the grouped products holds
+#: the most rows of one expert a block of the grouped products holds
 BLOCK = 512
+#: the fewest (``_block``)
+MIN_BLOCK = 64
 
 
 def route(s, router, bias, *, top_k: int, norm_topk: bool, scale: float,
@@ -98,10 +114,20 @@ class _Plan(NamedTuple):
     n_run: jnp.ndarray     # [] blocks the loops run
 
 
-def _plan(chosen, first: int, count: int) -> _Plan:
+def _block(n_tokens: int, top_k: int, num_experts: int) -> int:
+    """Rows a block of the grouped products holds for a call of
+    ``n_tokens`` tokens routed ``top_k`` of ``num_experts``: the smallest
+    power of two of at least 1.5 x the expected pairs a held expert gets,
+    between ``MIN_BLOCK`` and ``BLOCK``, and no more than the pairs."""
+    n_pairs = n_tokens * top_k
+    peak = -(-3 * n_pairs // (2 * num_experts))
+    return min(BLOCK, n_pairs, max(MIN_BLOCK, 1 << (peak - 1).bit_length()))
+
+
+def _plan(chosen, first: int, count: int, num_experts: int) -> _Plan:
     n_tokens, top_k = chosen.shape
     n_pairs = n_tokens * top_k
-    block = min(BLOCK, n_pairs)
+    block = _block(n_tokens, top_k, num_experts)
     rows_max = n_tokens * min(top_k, count)
     blocks_max = rows_max // block + count
     local = chosen.reshape(-1) - first
@@ -203,7 +229,7 @@ def routed_experts(s, router, bias, w1, w3, w2, *, top_k: int,
                    experts_held: Tuple[int, int], norm_topk: bool = True,
                    scale: float = 1.0, eps: float = 1e-6,
                    score: str = "sigmoid"):
-    """The held experts' part of a sparse block: ``(y, load)``.
+    """The held experts' part of a sparse block: ``(y, load, rows)``.
 
     ``s [..., T, d]`` are the block's inputs, ``router [d, num_experts]``
     and ``bias [num_experts]`` (or None) the published router, ``w1``, ``w3``
@@ -211,8 +237,10 @@ def routed_experts(s, router, bias, w1, w3, w2, *, top_k: int,
     first + count - 1`` (``experts_held = (first, count)``). ``y`` has
     ``s``'s shape; ``load`` ``[..., count]`` counts, for every leading index,
     the (token, choice) pairs of its ``T`` tokens that landed on each held
-    expert. All the tokens share one set of grouped products; ``eps`` is
-    the normalisation's, ``score`` the router's (``route``)."""
+    expert. All the tokens share one set of grouped products; ``rows``
+    (int32) is the rows their block loops ran, padding included: the blocks
+    in use times the block's rows. ``eps`` is the normalisation's,
+    ``score`` the router's (``route``)."""
     first, count = experts_held
     if w1.shape[0] != count:
         raise ValueError(f"{w1.shape[0]} experts given, experts_held says "
@@ -223,9 +251,12 @@ def routed_experts(s, router, bias, w1, w3, w2, *, top_k: int,
                             norm_topk=norm_topk, scale=scale, eps=eps,
                             score=score)
     chosen = jax.lax.stop_gradient(chosen)
-    plan = jax.tree.map(jax.lax.stop_gradient, _plan(chosen, first, count))
+    num_experts = router.shape[-1]
+    plan = jax.tree.map(jax.lax.stop_gradient,
+                        _plan(chosen, first, count, num_experts))
     y = _grouped(flat, weights, w1, w3, w2, plan)
     local = chosen.reshape(lead + (length * top_k,)) - first
     load = jnp.sum(local[..., None] == jnp.arange(count), axis=-2,
                    dtype=jnp.int32)
-    return y.reshape(s.shape), load
+    rows = plan.n_run * plan.tokens.shape[-1]
+    return y.reshape(s.shape), load, rows

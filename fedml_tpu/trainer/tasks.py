@@ -73,24 +73,29 @@ class RoutedTiedHead(NamedTuple):
     """A :class:`TiedHead` of a model with routed experts: beside the hidden
     states and the embedding, ``expert_load [B, sparse layers, experts
     held]`` - per row and sparse layer the (token, choice) pairs that landed
-    on each expert held here. ``lm_rows_head`` turns it into two stat sums
-    over the real rows."""
+    on each expert held here - and ``block_rows [sparse layers]``, the rows
+    each layer's grouped products ran over all the rows (padding included).
+    ``lm_rows_head`` turns them into three stat sums."""
 
     hidden: jnp.ndarray
     embedding: jnp.ndarray
     expert_load: jnp.ndarray
+    block_rows: jnp.ndarray
 
 
-def _routing_stats(expert_load, mask) -> Stats:
-    """``moe_assignments``: the pairs that landed on held experts, all sparse
-    layers; ``moe_top_expert_assignments``: per sparse layer the most loaded
-    held expert's pairs, summed. Sums like every stat, so ``held x top /
-    assignments`` over any span of steps is the load-weighted peak-to-mean
-    ratio, 1.0 when balanced."""
+def _routing_stats(expert_load, block_rows, mask) -> Stats:
+    """``moe_assignments``: the pairs of the real rows that landed on held
+    experts, all sparse layers; ``moe_top_expert_assignments``: per sparse
+    layer the most loaded held expert's pairs, summed; ``moe_block_rows``:
+    the rows the grouped products ran, all sparse layers. Sums like every
+    stat, so ``held x top / assignments`` over any span of steps is the
+    load-weighted peak-to-mean ratio, 1.0 when balanced, and ``assignments /
+    block rows`` the share of the rows run that were real pairs."""
     load = jnp.einsum("b,ble->le", mask, jax.lax.stop_gradient(expert_load))
     return {"moe_assignments": jnp.sum(load),
             "moe_top_expert_assignments": jnp.sum(jnp.max(load, axis=-1,
-                                                          initial=0.0))}
+                                                          initial=0.0)),
+            "moe_block_rows": jnp.sum(jax.lax.stop_gradient(block_rows))}
 
 
 #: positions whose logits ``lm_rows_head`` holds at a time
@@ -139,11 +144,11 @@ def lm_rows_head(out, targets: jnp.ndarray, mask: jnp.ndarray) -> Stats:
     ``out`` is ``[B, T, V]`` logits or a :class:`TiedHead`, for which the
     logits are formed ``LOGIT_BLOCK`` positions at a time, each block
     rematerialised, so neither pass holds more than one block of them. A
-    :class:`RoutedTiedHead` adds the two routing sums of
+    :class:`RoutedTiedHead` adds the three routing sums of
     ``_routing_stats``; any other output gets the three keys alone."""
     routing = {}
     if isinstance(out, RoutedTiedHead):
-        routing = _routing_stats(out.expert_load, mask)
+        routing = _routing_stats(out.expert_load, out.block_rows, mask)
         out = TiedHead(out.hidden, out.embedding)
     with jax.named_scope("fedml.lm_head"):
         per_tok, correct = (_tied_position_stats(*out, targets)

@@ -337,6 +337,36 @@ def test_expert_load_peak_is_held_times_top_over_assignments():
     assert read(_ctx(stats, {}), **args) is None
 
 
+@pytest.mark.parametrize("stats, fill", [
+    # qwen3's shape: 1,280 held pairs a layer pass in 35 blocks of 64
+    ([{"moe_assignments": 1280.0, "moe_block_rows": 35 * 64.0},
+      {"moe_assignments": 1260.0, "moe_block_rows": 34 * 64.0}],
+     2540.0 / (69 * 64.0)),
+    # every block full
+    ([{"moe_assignments": 512.0, "moe_block_rows": 512.0}], 1.0),
+    # the parent's rounds carry no block rows: nothing, and no error
+    ([{"loss_sum": 1.0, "moe_assignments": 1280.0}], None),
+    ([], None)])
+def test_moe_block_fill_is_assignments_over_the_rows_the_blocks_ran(stats,
+                                                                    fill):
+    entry = _load("benchmark", "metrics", "moe_block_fill.json")
+    assert entry["reader"] == "stat_ratio"
+    assert entry["args"] == {"numerator": "moe_assignments",
+                             "denominator": "moe_block_rows"}
+    read = _module("readers", "stat_ratio.py").read
+    assert read(_ctx(stats, {"experts_held": [0, 32]}),
+                **entry["args"]) == fill
+    manifest = _load("BENCHMARK.json")
+    metric = {m["name"]: m for m in manifest["per_layer"]}["moe_block_fill"]
+    assert metric == {
+        "name": "moe_block_fill", "unit": "ratio", "better": "higher",
+        "source": "program_counter", "layer": "trainer",
+        "moves": "rounds_per_s", "workloads": [
+            "qwen3_next_80b_a3b_ep16.silo4", "kanana_2_30b_a3b_ep8.silo4",
+            CELL]}
+    assert manifest["per_layer"][-1] == metric
+
+
 def test_the_roofline_reader_reads_nothing_without_a_trace_or_the_stats():
     read = _module("readers", "moe_roofline.py").read
     assert read(_ctx([{"moe_assignments": 1.0}], {"experts_held": [0, 8]}),

@@ -22,7 +22,7 @@ from fedml_tpu.models import create_model, deepseek_v3
 from fedml_tpu.models.common import rotary
 from fedml_tpu.ops import moe
 from fedml_tpu.ops.flash_attention import flash_attention_heads
-from fedml_tpu.trainer.functional import TrainConfig
+from fedml_tpu.trainer.functional import TrainConfig, make_local_train
 from fedml_tpu.trainer.tasks import RoutedTiedHead, lm_rows_head
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -97,6 +97,27 @@ def _loss(module, params, x, y, mask):
 
 def _rel(a, b):
     return float(jnp.max(jnp.abs(a - b)) / (jnp.max(jnp.abs(b)) + 1e-12))
+
+
+def _block_rows(out, tokens, top_k, num_experts):
+    """Per sparse layer the rows one forward's block loops ran: the blocks
+    each held expert's pairs over all the rows need, times the block."""
+    block = moe._block(tokens, top_k, num_experts)
+    load = np.asarray(out.expert_load).sum(0)
+    return block * np.sum(-(-load // block), axis=-1)
+
+
+def _block_rows_over_steps(module, variables, x, y, top_k, num_experts):
+    """``moe_block_rows`` of a local epoch of one row a step at lr 0 (every
+    step sees the same weights), and the sum of each row's forward."""
+    local_train = make_local_train(module, "lm_rows", TrainConfig(
+        epochs=1, batch_size=1, lr=0.0))
+    _, stats = jax.jit(local_train)(variables, x, y, jnp.ones(len(x)),
+                                    jax.random.key(0))
+    apply = jax.jit(module.apply)
+    want = sum(_block_rows(apply(variables, x[i:i + 1]), x.shape[1], top_k,
+                           num_experts).sum() for i in range(len(x)))
+    return stats, want
 
 
 # -- the model against the reference -------------------------------------------
@@ -205,7 +226,7 @@ def test_the_eight_shares_and_the_shared_experts_once_are_the_uncut_layer(
         share = module.clone(experts_held=(2 * i, 2))
         mine = {**p, **{name: p[name][2 * i:2 * i + 2] for name in
                         ("experts_w1", "experts_w3", "experts_w2")}}
-        out, load = jax.jit(lambda q, h, share=share: deepseek_v3._layer(
+        out, load, _ = jax.jit(lambda q, h, share=share: deepseek_v3._layer(
             q, h, dense=False, cfg=share.cfg()))(mine, h)
         assert load.shape == (2, 2)
         total = total + out
@@ -351,9 +372,22 @@ def test_the_output_is_a_routed_head_with_the_untied_leaf(small):
                                   variables["params"]["lm_head"])
     stats = lm_rows_head(out, y, jnp.ones(2))
     assert set(stats) == {"loss_sum", "count", "correct_sum",
-                          "moe_assignments", "moe_top_expert_assignments"}
+                          "moe_assignments", "moe_top_expert_assignments",
+                          "moe_block_rows"}
     assert 0 < float(stats["moe_assignments"]) <= 2 * 2 * x.shape[1] * 3
     assert float(stats["moe_assignments"]) == float(out.expert_load.sum())
+    # the rows the loops ran: blocks in use x block, both sparse layers
+    np.testing.assert_array_equal(out.block_rows,
+                                  _block_rows(out, x.size, 3, 16))
+    assert float(stats["moe_block_rows"]) == float(out.block_rows.sum())
+    assert float(stats["moe_assignments"]) <= float(stats["moe_block_rows"])
+
+
+def test_the_block_rows_are_what_the_loops_ran_step_by_step(small):
+    module, variables, x, y = small
+    stats, want = _block_rows_over_steps(module, variables, x, y, 3, 16)
+    assert float(stats["moe_block_rows"]) == want > 0
+    assert float(stats["moe_assignments"]) <= want
 
 
 # -- the folded round against the reference's ---------------------------------------
@@ -422,6 +456,9 @@ def test_the_folded_round_equals_the_references_round(folded):
     assert float(stats["count"]) == rows
     # 2 sparse layers, 24 tokens x 3 choices a row, 8 of 16 experts held
     assert 0 < float(stats["moe_assignments"]) <= rows * 2 * 24 * 3
+    # in blocks of 8 rows, each held pair in one
+    assert float(stats["moe_assignments"]) <= float(stats["moe_block_rows"])
+    assert float(stats["moe_block_rows"]) % 8 == 0
     np.testing.assert_allclose(got["params"]["layer_01"]["expert_bias"],
                                init["params"]["layer_01"]["expert_bias"],
                                rtol=1e-6)

@@ -311,7 +311,9 @@ def test_two_programs_of_one_name_keep_what_they_agree_on(monkeypatch):
 #: commit (01b1198, before the scopes) traces it at these sizes under the
 #: tests' settings (matmul precision ``highest``, tests/conftest.py);
 #: ``lfm2_moe``'s as traced since the routed experts' combine moved inside
-#: their block loop (``ops/moe.py``)
+#: their block loop (``ops/moe.py``) and the routing stats gained
+#: ``moe_block_rows``, the rows those loops ran (the block at these sizes is
+#: the call's 48 pairs, as it was)
 LANGUAGE_MODELS = {
     "sambay": (dict(hidden_size=128, num_heads=4, num_kv_heads=2,
                     intermediate_size=128, sliding_window=8,
@@ -321,7 +323,7 @@ LANGUAGE_MODELS = {
                       intermediate_size=128, moe_intermediate_size=32,
                       num_experts=4, num_experts_per_tok=2,
                       experts_held=(0, 2), layer_ids=(1, 2, 3),
-                      attn_block=8), "4a9740787e3d27de"),
+                      attn_block=8), "0b7f1e03f36b7646"),
     "granite_hybrid": (dict(hidden_size=64, num_heads=4, num_kv_heads=2,
                             shared_intermediate_size=128, layer_ids=(4, 5),
                             mamba_n_heads=4, mamba_d_head=32,

@@ -19,7 +19,7 @@ from fedml_tpu.models import create_model
 from fedml_tpu.models.common import rms_norm, rotary
 from fedml_tpu.models.lfm2_moe import LFM2_8B_LAYER_TYPES
 from fedml_tpu.ops import moe
-from fedml_tpu.trainer.functional import TrainConfig
+from fedml_tpu.trainer.functional import TrainConfig, make_local_train
 from fedml_tpu.trainer.tasks import RoutedTiedHead, TiedHead, lm_rows_head
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -67,6 +67,27 @@ def _seeded(module, tokens, seed=1, noise=0.05):
     return jax.tree.unflatten(treedef, [
         leaf + noise * jax.random.normal(k, leaf.shape)
         for leaf, k in zip(leaves, keys)])
+
+
+def _block_rows(out, tokens, top_k, num_experts):
+    """Per sparse layer the rows one forward's block loops ran: the blocks
+    each held expert's pairs over all the rows need, times the block."""
+    block = moe._block(tokens, top_k, num_experts)
+    load = np.asarray(out.expert_load).sum(0)
+    return block * np.sum(-(-load // block), axis=-1)
+
+
+def _block_rows_over_steps(module, variables, x, y, top_k, num_experts):
+    """``moe_block_rows`` of a local epoch of one row a step at lr 0 (every
+    step sees the same weights), and the sum of each row's forward."""
+    local_train = make_local_train(module, "lm_rows", TrainConfig(
+        epochs=1, batch_size=1, lr=0.0))
+    _, stats = jax.jit(local_train)(variables, x, y, jnp.ones(len(x)),
+                                    jax.random.key(0))
+    apply = jax.jit(module.apply)
+    want = sum(_block_rows(apply(variables, x[i:i + 1]), x.shape[1], top_k,
+                           num_experts).sum() for i in range(len(x)))
+    return stats, want
 
 
 @pytest.fixture(scope="module")
@@ -300,17 +321,30 @@ def test_the_head_sums_the_routing_of_the_real_rows(small, reference):
         stats = lm_rows_head(out, y, jnp.asarray(mask))
         assert set(stats) == {"loss_sum", "count", "correct_sum",
                               "moe_assignments",
-                              "moe_top_expert_assignments"}
+                              "moe_top_expert_assignments", "moe_block_rows"}
         kept = np.einsum("b,ble->le", np.asarray(mask), load)
         assert float(stats["moe_assignments"]) == kept.sum()
         assert float(stats["moe_top_expert_assignments"]) == \
             kept.max(-1).sum()
+        # the rows the loops ran, padding rows' pairs included
+        assert float(stats["moe_block_rows"]) == float(out.block_rows.sum())
+        assert float(stats["moe_assignments"]) <= float(
+            stats["moe_block_rows"])
+    np.testing.assert_array_equal(out.block_rows,
+                                  _block_rows(out, x.size, 2, 8))
     # an output without routing counts gets the keys it got before
     plain = lm_rows_head(TiedHead(out.hidden, out.embedding), y, jnp.ones(2))
     assert set(plain) == {"loss_sum", "count", "correct_sum"}
     whole = lm_rows_head(out, y, jnp.ones(2))
     for key in plain:
         np.testing.assert_array_equal(plain[key], whole[key])
+
+
+def test_the_block_rows_are_what_the_loops_ran_step_by_step(small):
+    module, variables, x, y = small
+    stats, want = _block_rows_over_steps(module, variables, x, y, 2, 8)
+    assert float(stats["moe_block_rows"]) == want > 0
+    assert float(stats["moe_assignments"]) <= want
 
 
 # -- the folded round against the reference's ---------------------------------------
@@ -380,6 +414,9 @@ def test_the_folded_round_equals_the_references_round(folded):
     assert float(stats["moe_assignments"]) / 4 <= float(
         stats["moe_top_expert_assignments"]) <= float(
             stats["moe_assignments"])
+    # in blocks of 16 rows, each held pair in one
+    assert float(stats["moe_assignments"]) <= float(stats["moe_block_rows"])
+    assert float(stats["moe_block_rows"]) % 16 == 0
     # the bias is nobody's to move: the mean of four equal values, to
     # float32 rounding of shares that are sevenths
     np.testing.assert_allclose(got["params"]["layer_02"]["expert_bias"],

@@ -22,9 +22,9 @@ def highest_precision():
         yield
 
 
-def _weights(seed=0, experts=E, bias_scale=0.1):
+def _weights(seed=0, experts=E, bias_scale=0.1, tokens=T):
     ks = jax.random.split(jax.random.key(seed), 6)
-    return {"s": jax.random.normal(ks[0], (T, D)),
+    return {"s": jax.random.normal(ks[0], (tokens, D)),
             "router": 0.3 * jax.random.normal(ks[1], (D, experts)),
             "bias": bias_scale * jax.random.normal(ks[2], (experts,)),
             "w1": 0.2 * jax.random.normal(ks[3], (experts, D, W)),
@@ -32,15 +32,17 @@ def _weights(seed=0, experts=E, bias_scale=0.1):
             "w2": 0.2 * jax.random.normal(ks[5], (experts, W, D))}
 
 
-def _share(p, first, count, block=moe.BLOCK):
+def _share(p, first, count, block=moe.BLOCK, rows=False):
     """The program's layer on the share ``first .. first + count - 1``, in
-    blocks of ``block`` rows (the layer reads ``BLOCK`` as it is traced), so
-    that 48 tokens fill several blocks an expert."""
+    blocks of at most ``block`` rows (the layer reads ``BLOCK`` as it is
+    traced), so that 48 tokens fill several blocks an expert: ``(y,
+    load)``, and the rows the loops ran after them if ``rows``."""
     held = slice(first, first + count)
     with mock.patch.object(moe, "BLOCK", block):
-        return routed_experts(p["s"], p["router"], p["bias"], p["w1"][held],
-                              p["w3"][held], p["w2"][held], top_k=K,
-                              experts_held=(first, count))
+        out = routed_experts(p["s"], p["router"], p["bias"], p["w1"][held],
+                             p["w3"][held], p["w2"][held], top_k=K,
+                             experts_held=(first, count))
+    return out if rows else out[:2]
 
 
 def _dense(p, first, count, top_k=K):
@@ -136,7 +138,7 @@ def _layout(p, first, count, block):
     chosen, _ = route(p["s"], p["router"], p["bias"], top_k=K,
                       norm_topk=True, scale=1.0)
     with mock.patch.object(moe, "BLOCK", block):
-        plan = moe._plan(chosen, first, count)
+        plan = moe._plan(chosen, first, count, E)
     n_run = int(plan.n_run)
     valid = np.asarray(plan.valid[:n_run])
     tokens = np.asarray(plan.tokens[:n_run])
@@ -243,8 +245,9 @@ def test_a_wrong_expert_count_is_refused():
 @pytest.mark.parametrize("first, count", [(0, 8), (2, 4)])
 def test_blocks_of_8_and_of_512_give_the_same_values_and_gradients(first,
                                                                    count):
-    """96 pairs fill a dozen blocks of 8 and a part of one of 512: the
-    regime of many small experts (a few rows in a block of ``BLOCK``)."""
+    """96 pairs fill a dozen blocks of 8 and a part of one of the rule's 64
+    (``BLOCK`` 512): the regime of many small experts, a few rows a
+    block."""
     p = _weights(seed=10)
     probe = jax.random.normal(jax.random.key(11), (T, D))
 
@@ -282,10 +285,10 @@ def test_the_blocks_the_plan_runs_follow_the_block():
     chosen = jnp.asarray(np.random.RandomState(0).randint(0, E, (T, K)),
                          jnp.int32)
     load = np.bincount(np.asarray(chosen).ravel(), minlength=E)[2:6]
-    for block in (8, 16, 512):
+    # 96 pairs over 8 experts: the rule's floor of 64 under ``BLOCK`` 512
+    for block, size in ((8, 8), (16, 16), (512, 64)):
         with mock.patch.object(moe, "BLOCK", block):
-            plan = moe._plan(chosen, 2, 4)
-        size = min(block, T * K)
+            plan = moe._plan(chosen, 2, 4, E)
         assert plan.tokens.shape[1] == size
         assert int(plan.n_run) == int(np.sum(-(-load // size)))
         assert int(plan.valid.sum()) == int(load.sum())
@@ -316,40 +319,58 @@ def test_the_epsilon_of_the_normalisation_is_the_callers():
     assert _rel(loose, tight) > 0.1
 
 
-#: the first 16 hex digits of sha256(str(jaxpr)) of the layer and of its
-#: five gradients for a call shaped like ``models/lfm2_moe.py``'s: rows of
-#: tokens, top-4 of 32 experts, 8 held, no epsilon named. The program changed
-#: when the combine moved inside the block loop (each block's rows added
-#: into the output at their tokens, no worst-case buffer gathered back): the
-#: digests pin that program
-LFM2_SHAPED = {"layer": "7309c5cd7aed81a8", "gradients": "433816f2faf46532"}
+#: the first 16 hex digits of sha256(str(jaxpr)) of the layer's ``(y,
+#: load)`` and of its five gradients for a call shaped like
+#: ``models/lfm2_moe.py``'s - top-4 of 32 experts, 8 held, no epsilon named -
+#: each jaxpr cut to the equations its outputs need (the rows the loops ran
+#: are a third output, dead here). At the published call (4,096 tokens, d
+#: 2,048, experts of 1,792) the block is the ceiling of 512, and the digests
+#: are the parent commit's: lfm2 runs the parent's grouped products. At the
+#: toy call (128 tokens, d 32, experts of 48) the block follows the expected
+#: load down to the floor of 64 where the parent's was 512, so the digests
+#: pin that program
+LFM2_SHAPED = {
+    ("published", "layer"): "b64499fde627febe",
+    ("published", "gradients"): "960a3de73aef4a79",
+    ("toy", "layer"): "66fdf26ac789dc7d",
+    ("toy", "gradients"): "69a0e10573efc392"}
+LFM2_CALLS = {
+    "toy": ((2, 64, 32), (32, 32), (32,), (8, 32, 48), (8, 32, 48),
+            (8, 48, 32)),
+    "published": ((1, 4096, 2048), (2048, 32), (32,), (8, 2048, 1792),
+                  (8, 2048, 1792), (8, 1792, 2048))}
 
 
-def _lfm2_shaped(what):
-    """The layer or its five gradients at ``LFM2_SHAPED``'s call, and the
-    abstract arguments: 128 tokens in two rows, d 32, experts of 48."""
+def _lfm2_shaped(what, call="toy"):
+    """The layer's ``(y, load)`` or its five gradients at ``LFM2_SHAPED``'s
+    call, and the abstract arguments: nothing is computed."""
     def layer(s, router, bias, w1, w3, w2):
         return routed_experts(s, router, bias, w1, w3, w2, top_k=4,
-                              experts_held=(0, 8), norm_topk=True, scale=1.0)
+                              experts_held=(0, 8), norm_topk=True,
+                              scale=1.0)[:2]
 
     def gradients(*args):
         return jax.grad(lambda *a: jnp.sum(layer(*a)[0] ** 2),
                         argnums=(0, 1, 3, 4, 5))(*args)
 
-    args = [jax.ShapeDtypeStruct(shape, jnp.float32) for shape in (
-        (2, 64, 32), (32, 32), (32,), (8, 32, 48), (8, 32, 48), (8, 48, 32))]
+    args = [jax.ShapeDtypeStruct(shape, jnp.float32)
+            for shape in LFM2_CALLS[call]]
     return {"layer": layer, "gradients": gradients}[what], args
 
 
-@pytest.mark.parametrize("what", sorted(LFM2_SHAPED))
-def test_with_the_defaults_the_traced_program_is_the_parents(what):
+@pytest.mark.parametrize("call, what", sorted(LFM2_SHAPED))
+def test_with_the_defaults_the_traced_program_is_the_parents(call, what):
     import hashlib
 
-    fn, args = _lfm2_shaped(what)
+    from jax._src.interpreters import partial_eval as pe
+
+    fn, args = _lfm2_shaped(what, call)
     with jax.default_matmul_precision(None):
-        got = hashlib.sha256(
-            str(jax.make_jaxpr(fn)(*args)).encode()).hexdigest()[:16]
-    assert got == LFM2_SHAPED[what]
+        closed = jax.make_jaxpr(fn)(*args)
+    needed, _ = pe.dce_jaxpr(closed.jaxpr, [True] * len(closed.out_avals),
+                             instantiate=True)
+    got = hashlib.sha256(str(needed).encode()).hexdigest()[:16]
+    assert got == LFM2_SHAPED[call, what]
 
 
 def _avals(jaxpr):
@@ -365,7 +386,7 @@ def _avals(jaxpr):
                     yield from _avals(sub)
 
 
-@pytest.mark.parametrize("what", sorted(LFM2_SHAPED))
+@pytest.mark.parametrize("what", ["gradients", "layer"])
 @pytest.mark.parametrize("block", [16, moe.BLOCK])
 def test_the_combine_holds_no_worst_case_rows_nor_a_row_a_choice(what,
                                                                  block):
@@ -373,10 +394,10 @@ def test_the_combine_holds_no_worst_case_rows_nor_a_row_a_choice(what,
     layer nor its gradients hold an array of the plan's static worst case,
     ``blocks_max x block`` rows, nor one of ``[tokens, top_k, d]``."""
     tokens, top_k, width, held = 128, 4, 32, 8
-    size = min(block, tokens * top_k)
-    worst = (tokens * min(top_k, held) // size + held) * size
     fn, args = _lfm2_shaped(what)
     with mock.patch.object(moe, "BLOCK", block):
+        size = moe._block(tokens, top_k, 32)
+        worst = (tokens * min(top_k, held) // size + held) * size
         shapes = {tuple(a.shape) for a in _avals(jax.make_jaxpr(fn)(
             *args).jaxpr) if hasattr(a, "shape")}
     assert (tokens, top_k, width) not in shapes
@@ -433,7 +454,7 @@ def test_a_softmax_share_and_its_gradients_equal_the_masked_dense_sum(
                 p["w2"][held], top_k=K, experts_held=(first, count),
                 eps=0.0, score="softmax")
 
-    y, load = jax.jit(share)(p)
+    y, load, _ = jax.jit(share)(p)
     want, chosen = _dense_softmax(p, first, count)
     assert _rel(y, want) < 1e-5
     np.testing.assert_array_equal(load, [
@@ -459,3 +480,74 @@ def test_the_sigmoid_is_the_default_and_another_score_is_refused():
         jax.make_jaxpr(lambda *a: route(*a, **kw, score="sigmoid"))(*args))
     with pytest.raises(ValueError, match="no router score"):
         route(*args, **kw, score="relu")
+
+
+# -- the block follows the call's expected load per held expert --------------------
+
+@pytest.mark.parametrize("tokens, top_k, experts, ceiling, block", [
+    (2048, 10, 512, 512, 64),    # Qwen3-Next: 40 pairs an expert, 60 at peak
+    (2048, 6, 128, 512, 256),    # kanana: 96, 144
+    (4096, 4, 32, 512, 512),     # LFM2: 512, 768 - the ceiling
+    (48, 2, 8, 512, 64),         # 12, 18: the floor
+    (16, 2, 8, 512, 32),         # the floor is more than the call's 32 pairs
+    (256, 1, 3, 512, 128),       # 1.5 x expected is 128 exactly
+    (258, 1, 3, 512, 256),       # and 129
+    (2048, 10, 512, 16, 16),     # a patched ``BLOCK`` is still the ceiling
+    (4096, 4, 32, 8, 8)])
+def test_the_block_is_the_power_of_two_over_one_and_a_half_loads(
+        tokens, top_k, experts, ceiling, block):
+    with mock.patch.object(moe, "BLOCK", ceiling):
+        assert moe._block(tokens, top_k, experts) == block
+
+
+def _small_loads(first, count, score):
+    """96 tokens, top-2 of 8: 24 pairs a held expert expected, so the rule
+    takes blocks of 64. Expert 0 is everybody's choice (96 pairs: one full
+    block and a part of a second), expert 3 nobody's."""
+    p = _weights(seed=18, bias_scale=0.0, tokens=96)
+    if score == "softmax":  # every token leans on u, which expert 0 reads
+        u = jax.random.normal(jax.random.key(20), (D,))
+        u = u / jnp.linalg.norm(u)
+        p["s"] = p["s"] + 5.0 * u
+        p["router"] = p["router"].at[:, 0].set(3.0 * u).at[:, 3].set(-3.0 * u)
+        p["bias"] = None
+    else:
+        p["bias"] = jnp.zeros(E).at[0].set(10.0).at[3].set(-10.0)
+    held = slice(first, first + count)
+
+    def share(p):
+        return routed_experts(
+            p["s"], p["router"], p["bias"], p["w1"][held], p["w3"][held],
+            p["w2"][held], top_k=K, experts_held=(first, count),
+            eps=0.0 if score == "softmax" else 1e-6, score=score)
+
+    return p, share
+
+
+@pytest.mark.parametrize("score", ["sigmoid", "softmax"])
+@pytest.mark.parametrize("first, count", [(0, 4), (0, 8)])
+def test_blocks_of_64_under_small_loads_equal_the_masked_dense_sum(
+        first, count, score):
+    """The forward and all five gradients where the expected load is far
+    under the block, with one expert spilling into a second block and one
+    held expert without a row; ``rows`` is what the loops ran."""
+    p, share = _small_loads(first, count, score)
+    assert moe._block(96, K, E) == 64
+    dense = _dense_softmax if score == "softmax" else _dense
+    y, load, rows = jax.jit(share)(p)
+    want, chosen = dense(p, first, count)
+    load = np.asarray(load)
+    assert load[0] == 96 and load[3] == 0
+    np.testing.assert_array_equal(load, [
+        int(jnp.sum(chosen == e)) for e in range(first, first + count)])
+    assert int(rows) == 64 * int(np.sum(-(-load // 64))) == 64 * (
+        2 + int(np.sum(load[1:] > 0)))
+    assert _rel(y, want) < 1e-5
+    probe = jax.random.normal(jax.random.key(19), p["s"].shape)
+    got = jax.jit(jax.grad(lambda p: jnp.sum(share(p)[0] * probe)))(p)
+    ref = jax.jit(jax.grad(lambda p: jnp.sum(
+        dense(p, first, count)[0] * probe)))(p)
+    for name in ("s", "router", "w1", "w3", "w2"):
+        assert _rel(got[name], ref[name]) < 1e-5, name
+    # the held expert without a row gets no weight gradient
+    assert not np.any(np.asarray(got["w1"][3]))

@@ -132,8 +132,8 @@ def test_the_routed_experts_are_xla_and_ragged_dot_would_not_be(one_chip):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     def loss(s, router, bias, w1, w3, w2):
-        y, load = routed_experts(s, router, bias, w1, w3, w2, top_k=4,
-                                 experts_held=(0, 8))
+        y, load, _ = routed_experts(s, router, bias, w1, w3, w2, top_k=4,
+                                    experts_held=(0, 8))
         return jnp.sum(y * y), load
 
     text = jax.jit(jax.value_and_grad(
@@ -211,7 +211,7 @@ def test_the_latent_attention_core_is_the_kernel_and_the_experts_xla(
         shapes["params"]["layer_01"])
 
     def loss(p, x):
-        y, load = deepseek_v3._layer(p, x, dense=False, cfg=cfg)
+        y, load, _ = deepseek_v3._layer(p, x, dense=False, cfg=cfg)
         return jnp.sum(y * y), load
 
     text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)
@@ -282,8 +282,8 @@ def test_the_qwen3_next_layers_are_xla_and_name_their_scopes(one_chip):
         {name: shapes["params"][name] for name in ("layer_02", "layer_03")})
 
     def loss(p, x):
-        x, _ = qwen3_next._layer(p["layer_02"], x, full=False, cfg=cfg)
-        x, _ = qwen3_next._layer(p["layer_03"], x, full=True, cfg=cfg)
+        x, _, _ = qwen3_next._layer(p["layer_02"], x, full=False, cfg=cfg)
+        x, _, _ = qwen3_next._layer(p["layer_03"], x, full=True, cfg=cfg)
         return jnp.sum(x * x)
 
     text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
