@@ -18,8 +18,8 @@ Spec = Tuple[Tuple[str, Tuple[int, ...], Callable], ...]
 
 class Leaves(nn.Module):
     """One named group of parameters, declared from ``specs`` and returned
-    as a dictionary: for models written as pure functions of a parameter
-    tree (``models/sambay.py``, ``models/lfm2_moe.py``)."""
+    as a dictionary: a layer's parameter tree in the decoder stack
+    (``models/decoder.py``)."""
 
     specs: Spec
 

@@ -43,10 +43,8 @@ and the layer's published index (``common.expert_bias_init``): the rule that
 balances the published model's bias is not in its config, and none is
 invented.
 
-Layers are pure functions of a parameter tree, each rematerialised whole
-(``jax.checkpoint``). The output is a :class:`~fedml_tpu.trainer.tasks
-.RoutedTiedHead` for the ``lm_rows`` head with the head's own leaf in the
-field that scores the hidden states - or, with ``return_logits``, the logits.
+The model runs through the decoder stack of ``models/decoder.py``, with the
+head's own leaf scoring the hidden states.
 """
 
 from __future__ import annotations
@@ -58,12 +56,11 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from fedml_tpu.models.common import (Leaves, Spec, expert_bias_init,
-                                     rms_norm, rotary)
+from fedml_tpu.models import decoder
+from fedml_tpu.models.common import Spec, expert_bias_init, rms_norm, rotary
 from fedml_tpu.ops.block_attention import causal_attention
 from fedml_tpu.ops.flash_attention import flash_attention_heads
-from fedml_tpu.ops.moe import routed_experts
-from fedml_tpu.trainer.tasks import RoutedTiedHead
+from fedml_tpu.ops.moe import held_slice, routed_experts
 from fedml_tpu.utils import on_tpu
 
 _normal = nn.initializers.normal(0.02)
@@ -211,55 +208,30 @@ class DeepseekV3LM(nn.Module):
 
     def cfg(self) -> dict:
         """What a layer's function reads of the module."""
-        first, held = self.experts_held
-        if not (0 <= first and held >= 1
-                and first + held <= self.n_routed_experts):
-            raise ValueError(f"experts_held {self.experts_held} is no slice "
-                             f"of {self.n_routed_experts} experts")
         return dict(nope=self.qk_nope_head_dim, rope=self.qk_rope_head_dim,
                     v_dim=self.v_head_dim, kv_rank=self.kv_lora_rank,
                     eps=self.rms_norm_eps, rope_theta=self.rope_theta,
                     top_k=self.num_experts_per_tok,
-                    experts_held=(first, held),
+                    experts_held=held_slice(self.experts_held,
+                                            self.n_routed_experts),
                     norm_topk=self.norm_topk_prob,
                     scale=self.routed_scaling_factor)
 
     @nn.compact
     def __call__(self, tokens, train: bool = False):
         del train  # no dropout
-        d, held, cfg = self.hidden_size, self.experts_held[1], self.cfg()
-        embedding = self.param("embedding", _normal, (self.vocab_size, d))
-        layers = [(Leaves(self._specs(layer), name=f"layer_{layer:02d}")(),
-                   layer) for layer in self.layer_ids]
-        final = Leaves((("norm_scale", (d,), _ones),), name="final_norm")()
-        lm_head = self.param("lm_head", _normal, (self.vocab_size, d))
+        cfg = self.cfg()
+
+        def forward(embedding, layers, final):
+            x, routing = decoder.run(
+                layers, decoder.embed(embedding, tokens), routes=True,
+                step=lambda p, x, layer: _layer(
+                    p, x, dense=layer < self.num_dense_layers, cfg=cfg))
+            return rms_norm(x, final["norm_scale"], cfg["eps"]), routing
+
         sparse = sum(layer >= self.num_dense_layers
                      for layer in self.layer_ids)
-
-        if self.is_initializing():
-            # the parameters are declared; their shapes do not depend on
-            # the tokens, so ``init`` need not run the layers eagerly
-            if self.return_logits:
-                return jnp.zeros(tokens.shape + (self.vocab_size,))
-            return RoutedTiedHead(
-                jnp.zeros(tokens.shape + (d,), embedding.dtype), lm_head,
-                jnp.zeros((tokens.shape[0], sparse, held), jnp.float32),
-                jnp.zeros((sparse,), jnp.float32))
-
-        with jax.named_scope("fedml.embed"):
-            x = embedding[tokens]
-        loads, block_rows = [], []
-        for p, layer in layers:
-            x, load, rows = jax.checkpoint(functools.partial(
-                _layer, dense=layer < self.num_dense_layers, cfg=cfg))(p, x)
-            if load is not None:
-                loads.append(load.astype(jnp.float32))
-                block_rows.append(rows.astype(jnp.float32))
-        hidden = rms_norm(x, final["norm_scale"], cfg["eps"])
-        if self.return_logits:
-            return jnp.einsum("btd,vd->btv", hidden, lm_head)
-        loads = (jnp.stack(loads, axis=1) if loads else
-                 jnp.zeros((tokens.shape[0], 0, held), jnp.float32))
-        block_rows = (jnp.stack(block_rows) if block_rows else
-                      jnp.zeros((0,), jnp.float32))
-        return RoutedTiedHead(hidden, lm_head, loads, block_rows)
+        return decoder.decode(
+            self, tokens, forward, specs=self._specs,
+            final=(("norm_scale", (self.hidden_size,), _ones),), untied=True,
+            experts=(sparse, cfg["experts_held"][1]))

@@ -29,11 +29,9 @@ kind)::
   on ``dt``.
 
 ``vocab_size`` is the rows of the tied embedding held (a vocabulary-
-parallel share is a smaller vocabulary). Layers are pure functions of a
-parameter tree, each rematerialised whole (``jax.checkpoint``). The output
-is a :class:`~fedml_tpu.trainer.tasks.TiedHead` for the ``lm_rows`` head -
-the final hidden states already divided by ``logits_scaling``, and the
-embedding - or, with ``return_logits``, the logits.
+parallel share is a smaller vocabulary). The model runs through the decoder
+stack of ``models/decoder.py``; its hidden states reach the head already
+divided by ``logits_scaling``.
 """
 
 from __future__ import annotations
@@ -45,11 +43,10 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from fedml_tpu.models.common import (Leaves, Spec, dt_bias_init, rms_norm,
-                                     uniform_init)
+from fedml_tpu.models import decoder
+from fedml_tpu.models.common import Spec, dt_bias_init, rms_norm, uniform_init
 from fedml_tpu.ops.block_attention import causal_attention
 from fedml_tpu.ops.ssd import ssd_scan
-from fedml_tpu.trainer.tasks import TiedHead
 
 #: granite-4.0-h-micro's published pattern: attention at 5, 15, 25, 35
 GRANITE_H_MICRO_LAYER_TYPES = tuple(
@@ -191,26 +188,15 @@ class GraniteHybridLM(nn.Module):
                    mamba_groups=self.mamba_n_groups,
                    mamba_state=self.mamba_d_state,
                    mamba_chunk=self.mamba_chunk_size)
-        embedding = self.param("embedding", _normal, (self.vocab_size, d))
-        layers = [(Leaves(self._specs(layer), name=f"layer_{layer:02d}")(),
-                   kind) for layer, kind in zip(self.layer_ids, kinds)]
-        final = Leaves((("norm_scale", (d,), _ones),), name="final_norm")()
 
-        if self.is_initializing():
-            # the parameters are declared; their shapes do not depend on
-            # the tokens, so ``init`` need not run the layers eagerly
-            if self.return_logits:
-                return jnp.zeros(tokens.shape + (self.vocab_size,))
-            return TiedHead(jnp.zeros(tokens.shape + (d,), embedding.dtype),
-                            embedding)
+        def forward(embedding, layers, final):
+            x, routing = decoder.run(
+                layers, decoder.embed(embedding, tokens,
+                                      self.embedding_multiplier),
+                step=lambda p, x, layer: _layer(
+                    p, x, kind=self.layer_types[layer], cfg=cfg))
+            return (rms_norm(x, final["norm_scale"], cfg["eps"])
+                    / self.logits_scaling, routing)
 
-        with jax.named_scope("fedml.embed"):
-            x = self.embedding_multiplier * embedding[tokens]
-        for p, kind in layers:
-            x = jax.checkpoint(functools.partial(
-                _layer, kind=kind, cfg=cfg))(p, x)
-        hidden = rms_norm(x, final["norm_scale"], cfg["eps"]) \
-            / self.logits_scaling
-        if self.return_logits:
-            return jnp.einsum("btd,vd->btv", hidden, embedding)
-        return TiedHead(hidden, embedding)
+        return decoder.decode(self, tokens, forward, specs=self._specs,
+                              final=(("norm_scale", (d,), _ones),))

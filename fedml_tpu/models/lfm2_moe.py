@@ -32,12 +32,10 @@ once, from a fixed key and the layer's published index, the same in every
 run (the published checkpoint's is the outcome of a balancing rule its
 config does not give, and none is invented here).
 
-Layers are pure functions of a parameter tree, each rematerialised whole
-(``jax.checkpoint``). The output is a :class:`~fedml_tpu.trainer.tasks
-.RoutedTiedHead` for the ``lm_rows`` head: hidden states, the embedding,
-per row and sparse layer the (token, choice) pairs that landed on each held
-expert, and per sparse layer the rows the grouped products ran - or, with
-``return_logits``, the logits.
+The model runs through the decoder stack of ``models/decoder.py``, whose
+routed output carries, per row and sparse layer, the (token, choice) pairs
+that landed on each held expert and, per sparse layer, the rows the grouped
+products ran.
 """
 
 from __future__ import annotations
@@ -49,11 +47,10 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from fedml_tpu.models.common import (Leaves, Spec, expert_bias_init,
-                                     rms_norm, rotary)
+from fedml_tpu.models import decoder
+from fedml_tpu.models.common import Spec, expert_bias_init, rms_norm, rotary
 from fedml_tpu.ops.block_attention import causal_attention
-from fedml_tpu.ops.moe import routed_experts
-from fedml_tpu.trainer.tasks import RoutedTiedHead
+from fedml_tpu.ops.moe import held_slice, routed_experts
 
 #: LFM2-8B-A1B's published pattern: full attention at 2, 6, 10, 14, 18, 21
 LFM2_8B_LAYER_TYPES = tuple(
@@ -178,48 +175,24 @@ class Lfm2MoeLM(nn.Module):
     def __call__(self, tokens, train: bool = False):
         del train  # no dropout
         d = self.hidden_size
-        first, held = self.experts_held
-        if not (0 <= first and held >= 1
-                and first + held <= self.num_experts):
-            raise ValueError(f"experts_held {self.experts_held} is no slice "
-                             f"of {self.num_experts} experts")
         cfg = dict(head_dim=d // self.num_heads, eps=self.norm_eps,
                    rope_theta=self.rope_theta, attn_block=self.attn_block,
                    top_k=self.num_experts_per_tok,
-                   experts_held=(first, held), norm_topk=self.norm_topk_prob,
+                   experts_held=held_slice(self.experts_held,
+                                           self.num_experts),
+                   norm_topk=self.norm_topk_prob,
                    scale=self.routed_scaling_factor)
-        embedding = self.param("embedding", _normal, (self.vocab_size, d))
-        layers = [(Leaves(self._specs(layer), name=f"layer_{layer:02d}")(),
-                   layer) for layer in self.layer_ids]
-        final = Leaves((("norm_scale", (d,), _ones),), name="final_norm")()
+
+        def forward(embedding, layers, final):
+            x, routing = decoder.run(
+                layers, decoder.embed(embedding, tokens), routes=True,
+                step=lambda p, x, layer: _layer(
+                    p, x, kind=self.layer_types[layer],
+                    dense=layer < self.num_dense_layers, cfg=cfg))
+            return rms_norm(x, final["norm_scale"], cfg["eps"]), routing
+
         sparse = sum(layer >= self.num_dense_layers
                      for layer in self.layer_ids)
-
-        if self.is_initializing():
-            # the parameters are declared; their shapes do not depend on
-            # the tokens, so ``init`` need not run the layers eagerly
-            if self.return_logits:
-                return jnp.zeros(tokens.shape + (self.vocab_size,))
-            return RoutedTiedHead(
-                jnp.zeros(tokens.shape + (d,), embedding.dtype), embedding,
-                jnp.zeros((tokens.shape[0], sparse, held), jnp.float32),
-                jnp.zeros((sparse,), jnp.float32))
-
-        with jax.named_scope("fedml.embed"):
-            x = embedding[tokens]
-        loads, block_rows = [], []
-        for p, layer in layers:
-            x, load, rows = jax.checkpoint(functools.partial(
-                _layer, kind=self.layer_types[layer],
-                dense=layer < self.num_dense_layers, cfg=cfg))(p, x)
-            if load is not None:
-                loads.append(load.astype(jnp.float32))
-                block_rows.append(rows.astype(jnp.float32))
-        hidden = rms_norm(x, final["norm_scale"], cfg["eps"])
-        if self.return_logits:
-            return jnp.einsum("btd,vd->btv", hidden, embedding)
-        loads = (jnp.stack(loads, axis=1) if loads else
-                 jnp.zeros((tokens.shape[0], 0, held), jnp.float32))
-        block_rows = (jnp.stack(block_rows) if block_rows else
-                      jnp.zeros((0,), jnp.float32))
-        return RoutedTiedHead(hidden, embedding, loads, block_rows)
+        return decoder.decode(self, tokens, forward, specs=self._specs,
+                              final=(("norm_scale", (d,), _ones),),
+                              experts=(sparse, cfg["experts_held"][1]))

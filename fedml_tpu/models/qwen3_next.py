@@ -42,10 +42,8 @@ chip. ``vocab_size`` is the rows held of the embedding and of the *untied*
 head. The multi-token-prediction module the family describes is not here: no
 key of the config names it and the causal-LM loss does not run it.
 
-Layers are pure functions of a parameter tree, each rematerialised whole
-(``jax.checkpoint``). The output is a :class:`~fedml_tpu.trainer.tasks
-.RoutedTiedHead` for the ``lm_rows`` head with the head's own leaf in the
-field that scores the hidden states - or, with ``return_logits``, the logits.
+The model runs through the decoder stack of ``models/decoder.py``, with the
+head's own leaf scoring the hidden states.
 """
 
 from __future__ import annotations
@@ -57,12 +55,11 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from fedml_tpu.models.common import (Leaves, Spec, rms_norm, rotary,
-                                     uniform_init)
+from fedml_tpu.models import decoder
+from fedml_tpu.models.common import Spec, rms_norm, rotary, uniform_init
 from fedml_tpu.ops.block_attention import causal_attention
 from fedml_tpu.ops.gated_delta import gated_delta_rule
-from fedml_tpu.ops.moe import routed_experts
-from fedml_tpu.trainer.tasks import RoutedTiedHead
+from fedml_tpu.ops.moe import held_slice, routed_experts
 
 _normal = nn.initializers.normal(0.02)
 _ones = nn.initializers.ones
@@ -251,11 +248,6 @@ class Qwen3NextLM(nn.Module):
 
     def cfg(self) -> dict:
         """What a layer's function reads of the module."""
-        first, held = self.experts_held
-        if not (0 <= first and held >= 1
-                and first + held <= self.num_experts):
-            raise ValueError(f"experts_held {self.experts_held} is no slice "
-                             f"of {self.num_experts} experts")
         return dict(key_heads=self.linear_num_key_heads,
                     value_heads=self.linear_num_value_heads,
                     dk=self.linear_key_head_dim,
@@ -263,40 +255,23 @@ class Qwen3NextLM(nn.Module):
                     rotary_dim=int(self.head_dim * self.partial_rotary_factor),
                     rope_theta=self.rope_theta, eps=self.rms_norm_eps,
                     top_k=self.num_experts_per_tok,
-                    experts_held=(first, held),
+                    experts_held=held_slice(self.experts_held,
+                                            self.num_experts),
                     norm_topk=self.norm_topk_prob)
 
     @nn.compact
     def __call__(self, tokens, train: bool = False):
         del train  # no dropout
-        d, held, cfg = self.hidden_size, self.experts_held[1], self.cfg()
-        embedding = self.param("embedding", _normal, (self.vocab_size, d))
-        layers = [(Leaves(self._specs(layer), name=f"layer_{layer:02d}")(),
-                   layer) for layer in self.layer_ids]
-        final = Leaves((("norm_scale", (d,), _zeros),), name="final_norm")()
-        lm_head = self.param("lm_head", _normal, (self.vocab_size, d))
+        cfg = self.cfg()
 
-        if self.is_initializing():
-            # the parameters are declared; their shapes do not depend on
-            # the tokens, so ``init`` need not run the layers eagerly
-            if self.return_logits:
-                return jnp.zeros(tokens.shape + (self.vocab_size,))
-            return RoutedTiedHead(
-                jnp.zeros(tokens.shape + (d,), embedding.dtype), lm_head,
-                jnp.zeros((tokens.shape[0], len(self.layer_ids), held),
-                          jnp.float32),
-                jnp.zeros((len(self.layer_ids),), jnp.float32))
+        def forward(embedding, layers, final):
+            x, routing = decoder.run(
+                layers, decoder.embed(embedding, tokens), routes=True,
+                step=lambda p, x, layer: _layer(
+                    p, x, full=self.is_full(layer), cfg=cfg))
+            return norm0(x, final["norm_scale"], cfg["eps"]), routing
 
-        with jax.named_scope("fedml.embed"):
-            x = embedding[tokens]
-        loads, block_rows = [], []
-        for p, layer in layers:
-            x, load, rows = jax.checkpoint(functools.partial(
-                _layer, full=self.is_full(layer), cfg=cfg))(p, x)
-            loads.append(load.astype(jnp.float32))
-            block_rows.append(rows.astype(jnp.float32))
-        hidden = norm0(x, final["norm_scale"], cfg["eps"])
-        if self.return_logits:
-            return jnp.einsum("btd,vd->btv", hidden, lm_head)
-        return RoutedTiedHead(hidden, lm_head, jnp.stack(loads, axis=1),
-                              jnp.stack(block_rows))
+        return decoder.decode(
+            self, tokens, forward, specs=self._specs,
+            final=(("norm_scale", (self.hidden_size,), _zeros),), untied=True,
+            experts=(len(self.layer_ids), cfg["experts_held"][1]))

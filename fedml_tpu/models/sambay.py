@@ -28,19 +28,13 @@ depth keeps the published indices, which set each attention layer's
 vocabulary-parallel share is a smaller vocabulary: ids, logits and loss are
 over the rows held).
 
-The arithmetic is written as pure functions over dictionaries of
-parameters so that each layer can be rematerialised whole
-(``jax.checkpoint``): at 2,048 positions and the published widths the
-backward pass then holds one layer's activations. The scan is
-``ops/selective_scan.py``, the attention ``ops/block_attention.py``; the
-output is a :class:`~fedml_tpu.trainer.tasks.TiedHead` (hidden states and
-the embedding) for the ``lm_rows`` task head, which forms the logits in
-blocks - or, with ``return_logits``, the logits themselves.
+The model runs through the decoder stack of ``models/decoder.py``, one row
+at a time, with the two hand-ons carried from layer to layer. The scan is
+``ops/selective_scan.py``, the attention ``ops/block_attention.py``.
 """
 
 from __future__ import annotations
 
-import functools
 import math
 from typing import Optional, Tuple
 
@@ -48,12 +42,11 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from fedml_tpu.models.common import (Leaves as _Leaves, Spec,
-                                     dt_bias_init as _dt_bias,
+from fedml_tpu.models import decoder
+from fedml_tpu.models.common import (Spec, dt_bias_init as _dt_bias,
                                      uniform_init as _uniform)
 from fedml_tpu.ops.block_attention import causal_attention
 from fedml_tpu.ops.selective_scan import selective_scan
-from fedml_tpu.trainer.tasks import TiedHead
 
 
 # -- initialisers ---------------------------------------------------------------
@@ -207,7 +200,8 @@ class SambaYLM(nn.Module):
             return "window"
         return "full" if layer == half + 1 else "cross"
 
-    def _specs(self, kind: str) -> Spec:
+    def _specs(self, layer: int) -> Spec:
+        kind = self.kind(layer)
         d, inner = self.hidden_size, self.expand * self.hidden_size
         head_dim = d // self.num_heads
         kv = self.num_kv_heads * head_dim
@@ -261,30 +255,18 @@ class SambaYLM(nn.Module):
                    boundary=half, eps=self.layer_norm_eps,
                    scan_chunk=self.scan_chunk, scan_lanes=self.scan_lanes,
                    attn_block=self.attn_block)
-        embedding = self.param("embedding", _normal, (self.vocab_size, d))
-        layers = [(_Leaves(self._specs(kind), name=f"layer_{layer:02d}")(),
-                   kind, layer) for kind, layer in zip(kinds, self.layer_ids)]
-        final = _Leaves((("norm1_scale", (d,), _ones),
-                         ("norm1_bias", (d,), _zeros)), name="final_norm")()
 
-        if self.is_initializing():
-            # the parameters are declared; their shapes do not depend on
-            # the tokens, so ``init`` need not run 2,048 positions eagerly
-            hidden = jnp.zeros(tokens.shape + (d,), embedding.dtype)
-            return (jnp.zeros(tokens.shape + (self.vocab_size,))
-                    if self.return_logits else TiedHead(hidden, embedding))
+        def forward(embedding, layers, final):
+            def sequence(ids):  # (x, memory, kv) from layer to layer
+                (x, _, _), routing = decoder.run(
+                    layers, (decoder.embed(embedding, ids), None, None),
+                    step=lambda p, state, layer: _layer(
+                        p, *state, kind=self.kind(layer), layer=layer,
+                        cfg=cfg))
+                return _layer_norm(final, "norm1", x, cfg["eps"]), routing
 
-        def sequence(ids):
-            with jax.named_scope("fedml.embed"):
-                x = embedding[ids]
-            memory, kv = None, None
-            for p, kind, layer in layers:
-                x, memory, kv = jax.checkpoint(functools.partial(
-                    _layer, kind=kind, layer=layer, cfg=cfg))(
-                        p, x, memory, kv)
-            return _layer_norm(final, "norm1", x, cfg["eps"])
+            return jax.vmap(sequence)(tokens)
 
-        hidden = jax.vmap(sequence)(tokens)
-        if self.return_logits:
-            return jnp.einsum("btd,vd->btv", hidden, embedding)
-        return TiedHead(hidden, embedding)
+        return decoder.decode(self, tokens, forward, specs=self._specs,
+                              final=(("norm1_scale", (d,), _ones),
+                                     ("norm1_bias", (d,), _zeros)))

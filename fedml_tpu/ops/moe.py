@@ -225,6 +225,17 @@ def _grouped_bwd(res, dy):
 _grouped.defvjp(_grouped_fwd, _grouped_bwd)
 
 
+def held_slice(experts_held: Tuple[int, int],
+               num_experts: int) -> Tuple[int, int]:
+    """``experts_held`` as ``(first, count)``, refused unless it is a slice
+    of the router's ``num_experts`` experts."""
+    first, count = experts_held
+    if not (0 <= first and count >= 1 and first + count <= num_experts):
+        raise ValueError(f"experts_held {experts_held} is no slice of "
+                         f"{num_experts} experts")
+    return first, count
+
+
 def routed_experts(s, router, bias, w1, w3, w2, *, top_k: int,
                    experts_held: Tuple[int, int], norm_topk: bool = True,
                    scale: float = 1.0, eps: float = 1e-6,

@@ -16,11 +16,13 @@ torch's reduction='mean' over the real examples in the batch.
 
 from __future__ import annotations
 
-from typing import Callable, Dict, NamedTuple
+from typing import Callable, Dict
 
 import jax
 import jax.numpy as jnp
 import optax
+
+from fedml_tpu.models.decoder import RoutedTiedHead, TiedHead
 
 Stats = Dict[str, jnp.ndarray]
 TaskHead = Callable[[jnp.ndarray, jnp.ndarray, jnp.ndarray], Stats]
@@ -56,31 +58,6 @@ def nwp_head(logits: jnp.ndarray, targets: jnp.ndarray,
         "count": jnp.sum(tok_mask),
         "correct_sum": jnp.sum(correct * tok_mask),
     }
-
-
-class TiedHead(NamedTuple):
-    """What a language model with a tied output head hands its task head in
-    place of logits: the final hidden states ``[B, T, d]`` and the embedding
-    ``[V, d]`` whose rows score them. The head then forms the logits
-    ``hidden @ embedding.T`` in blocks of positions and never holds
-    ``[B, T, V]`` at once."""
-
-    hidden: jnp.ndarray
-    embedding: jnp.ndarray
-
-
-class RoutedTiedHead(NamedTuple):
-    """A :class:`TiedHead` of a model with routed experts: beside the hidden
-    states and the embedding, ``expert_load [B, sparse layers, experts
-    held]`` - per row and sparse layer the (token, choice) pairs that landed
-    on each expert held here - and ``block_rows [sparse layers]``, the rows
-    each layer's grouped products ran over all the rows (padding included).
-    ``lm_rows_head`` turns them into three stat sums."""
-
-    hidden: jnp.ndarray
-    embedding: jnp.ndarray
-    expert_load: jnp.ndarray
-    block_rows: jnp.ndarray
 
 
 def _routing_stats(expert_load, block_rows, mask) -> Stats:

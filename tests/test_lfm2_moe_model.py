@@ -245,13 +245,6 @@ def test_the_expert_bias_is_one_draw_for_every_seed():
         jax.random.key(0), tokens, train=False)["params"]["layer_02"]
 
 
-def test_an_expert_share_outside_the_experts_is_refused():
-    module = create_model("lfm2_moe", output_dim=8, **{
-        **SMALL, "experts_held": (6, 4)})
-    with pytest.raises(ValueError, match="no slice"):
-        module.init(jax.random.key(0), jnp.zeros((1, 4), jnp.int32))
-
-
 # -- the helpers ------------------------------------------------------------------
 
 def test_rotary_is_a_rotation_by_position_times_frequency():

@@ -351,13 +351,6 @@ def test_the_initial_leaves_are_the_familys(small):
     assert np.any(np.asarray(p["lm_head"]) != np.asarray(p["embedding"]))
 
 
-def test_an_expert_share_outside_the_experts_is_refused():
-    module = create_model("qwen3_next", output_dim=8, **{
-        **SMALL, "experts_held": (12, 8)})
-    with pytest.raises(ValueError, match="no slice"):
-        module.init(jax.random.key(0), jnp.zeros((1, 4), jnp.int32))
-
-
 def test_the_output_is_a_routed_head_with_the_untied_leaf(small):
     module, variables, x, y = small
     out = jax.jit(module.apply)(variables, x)
